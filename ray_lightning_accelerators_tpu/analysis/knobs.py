@@ -86,10 +86,6 @@ _register(Knob("RLA_TPU_AGENT_TOKEN", "str", "",
 _register(Knob("RLA_TPU_ALLOW_TOKENLESS_BIND", "bool", False,
                "allow an agent to bind without RLA_TPU_AGENT_TOKEN "
                "(loopback/dev only; runtime/agent.py)"))
-_register(Knob("RLA_TPU_BENCH_CHILD", "flag", False,
-               "marks a bench.py isolation child so mid-run death "
-               "fallbacks emit once, in the parent (bench.py)",
-               scope="scripts"))
 _register(Knob("RLA_TPU_CHAOS", "str", "",
                "deterministic fault-injection spec, e.g. "
                "'hang@rank1:step2' (testing/chaos.py; conftest guards "

@@ -23,6 +23,7 @@ zoo beside the conv family (models/resnet.py) and the LM flagship
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -155,16 +156,29 @@ class ViT(TpuModule):
                 x, self.mesh, jax.sharding.PartitionSpec(*spec))
         return x
 
+    def _rms_norm(self, h, scale):
+        # per-shard on a multi-device mesh: the Pallas kernel behind
+        # rms_norm cannot be partitioned automatically (shard_local)
+        rows = ("batch", None, None)
+        return sharding_lib.shard_local(
+            rms_norm, self.mesh, (rows, (None,)), rows)(h, scale)
+
+    def _attention(self, q, k, v):
+        qkv = ("batch", "heads", None, None)
+        return sharding_lib.shard_local(
+            functools.partial(flash_attention, causal=False),
+            self.mesh, (qkv, qkv, qkv), qkv)(q, k, v)
+
     def _block(self, h, lp):
         dt = self.compute_dtype
         a = lp["attn"]
-        x = rms_norm(h, lp["ln1"])
+        x = self._rms_norm(h, lp["ln1"])
         q = jnp.einsum("bsd,dhk->bhsk", x, a["wq"].astype(dt))
         k = jnp.einsum("bsd,dhk->bhsk", x, a["wk"].astype(dt))
         v = jnp.einsum("bsd,dhk->bhsk", x, a["wv"].astype(dt))
-        attn = flash_attention(q, k, v, causal=False)
+        attn = self._attention(q, k, v)
         h = h + jnp.einsum("bhsk,hkd->bsd", attn, a["wo"].astype(dt))
-        x = rms_norm(h, lp["ln2"])
+        x = self._rms_norm(h, lp["ln2"])
         m = lp["mlp"]
         up = jax.nn.gelu(jnp.einsum("bsd,df->bsf", x, m["wi"].astype(dt)))
         up = self._constrain(up, mesh_lib.BATCH_AXES, None,
@@ -186,7 +200,7 @@ class ViT(TpuModule):
         if self.cfg.remat:
             block = jax.checkpoint(block)
         h, _ = jax.lax.scan(block, h, params["layers"])
-        h = rms_norm(h, params["ln_f"])
+        h = self._rms_norm(h, params["ln_f"])
         pooled = jnp.mean(h, axis=1)
         return (pooled @ params["head"].astype(dt)).astype(jnp.float32)
 

@@ -10,13 +10,15 @@ core for object movement, torch's C++ DataLoader workers for input
   bit-identical to the pure-Python path; the engine parallelizes the gather.
 
 The shared library is built on demand with ``g++`` (baked into the image)
-and cached beside the sources; import degrades gracefully when no toolchain
-is present (`available()` returns False and callers fall back to Python).
+and cached beside the sources under a name keyed on a hash of them; import
+degrades gracefully when no toolchain is present (`available()` returns
+False and callers fall back to Python).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -38,11 +40,26 @@ def _sources():
     return sorted(f for f in os.listdir(_DIR) if f.endswith(".cc"))
 
 
+def _source_digest() -> str:
+    """Hash of the ``.cc`` sources (names + contents): the cached
+    library's identity.  A ``.so`` copied along with a tree (it is
+    git-ignored, so nothing says where it came from) is only ever loaded
+    when it was built from exactly these sources."""
+    h = hashlib.sha256()
+    for name in _sources():
+        h.update(name.encode() + b"\0")
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    return h.hexdigest()[:16]
+
+
 def _out_path() -> str:
+    name = f"_rla_native.{_source_digest()}.so"
     if os.access(_DIR, os.W_OK):
-        return os.path.join(_DIR, "_rla_native.so")
+        return os.path.join(_DIR, name)
     return os.path.join(tempfile.gettempdir(),  # read-only install
-                        f"_rla_native_{os.getuid()}.so")
+                        f"{os.getuid()}{name}")
 
 
 def _compile(out: str) -> None:
@@ -66,17 +83,15 @@ def _load() -> Optional[ctypes.CDLL]:
     with _LOCK:
         if _LIB is not None or _BUILD_ERROR is not None:
             return _LIB
-        out = _out_path()
-        srcs = [os.path.join(_DIR, f) for f in _sources()]
         try:
-            stale = not os.path.exists(out) or any(
-                os.path.getmtime(out) < os.path.getmtime(s) for s in srcs)
-            if stale:
+            out = _out_path()
+            built = not os.path.exists(out)
+            if built:
                 _compile(out)
             try:
                 lib = ctypes.CDLL(out)
             except OSError:
-                if stale:
+                if built:
                     raise
                 _compile(out)  # cached .so unloadable (wrong arch): rebuild
                 lib = ctypes.CDLL(out)

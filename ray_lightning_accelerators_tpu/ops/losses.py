@@ -212,9 +212,9 @@ def fused_linear_cross_entropy(h: jax.Array, w: jax.Array,
     shard_map.
     """
     if mesh is not None and _batch_axes_in(mesh):
-        from ..parallel.sharding import _manual_axes_active
+        from ..parallel.sharding import manual_axes
         axes = _batch_axes_in(mesh)
-        if _manual_axes_active():
+        if set(axes) <= manual_axes():
             return _streamed_psum_mean(h, w, targets, chunk_rows, axes,
                                        label_smoothing, z_loss)
         return _fused_sharded(h, w, targets, chunk_rows, mesh,
@@ -234,9 +234,7 @@ def _streamed_psum_mean(h_l, w_r, t_l, chunk_rows, axes, label_smoothing,
                                label_smoothing, z_loss)
     ls = jax.lax.psum(ls, axes)
     # accuracy and the valid-row count are not differentiated (only
-    # mean_loss is, per the public contract); jax 0.4.x's shard_map
-    # cannot transpose a psum of a symbolic-Zero cotangent, so cut the
-    # dead AD paths explicitly
+    # mean_loss is, per the public contract): cut the dead AD paths
     cs = jax.lax.psum(jax.lax.stop_gradient(cs), axes)
     n = jnp.maximum(jax.lax.psum(jax.lax.stop_gradient(n), axes), 1.0)
     return ls / n, cs / n
@@ -244,7 +242,6 @@ def _streamed_psum_mean(h_l, w_r, t_l, chunk_rows, axes, label_smoothing,
 
 def _fused_sharded(h, w, targets, chunk_rows, mesh, label_smoothing=0.0,
                    z_loss=0.0):
-    from ..parallel.sharding import shard_map_compat
     axes = _batch_axes_in(mesh)
     P = jax.sharding.PartitionSpec
 
@@ -252,7 +249,7 @@ def _fused_sharded(h, w, targets, chunk_rows, mesh, label_smoothing=0.0,
         return _streamed_psum_mean(h_l, w_r, t_l, chunk_rows, axes,
                                    label_smoothing, z_loss)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         # graftlint: ok(sharding-inventory) — fused-loss shard_map specs
         in_specs=(P(axes, None), P(None, None), P(axes)),

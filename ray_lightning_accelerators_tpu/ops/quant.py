@@ -39,10 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _pick_block
 
-# jax 0.4.x names it TPUCompilerParams; 0.5+ renamed to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 # the kernels take the whole M dimension per grid cell: the f32
 # accumulator scratch [M, bn] + the [M, bk] input block must fit VMEM
 # (~16 MB/core) with room for double-buffered weight blocks.  Decode
@@ -158,7 +154,7 @@ def int8_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((m, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, wq, s2)
@@ -189,7 +185,7 @@ def int8_matmul_nt(x: jax.Array, wq: jax.Array,
         out_specs=pl.BlockSpec((m, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, wq)

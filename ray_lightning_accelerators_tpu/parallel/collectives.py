@@ -69,7 +69,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import mesh as mesh_lib
@@ -294,8 +293,8 @@ def build_exchange(mesh: Mesh, cfg: ExchangeConfig):
         return grads, new_res
 
     lead = P(mesh_lib.BATCH_AXES)
-    return shard_map(body, mesh=mesh, in_specs=(lead, lead),
-                     out_specs=(P(), lead), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(lead, lead),
+                     out_specs=(P(), lead), check_vma=False)
 
 
 def build_local_grads(mesh: Mesh, value_and_grad_fn, batch_spec,
@@ -324,9 +323,9 @@ def build_local_grads(mesh: Mesh, value_and_grad_fn, batch_spec,
         stacked = jax.tree.map(lambda g: g[None], grads)
         return metrics, stacked
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(), batch_spec, P()),
-        out_specs=(P(), P(mesh_lib.BATCH_AXES)), check_rep=False)
+        out_specs=(P(), P(mesh_lib.BATCH_AXES)), check_vma=False)
 
 
 # --------------------------------------------------------------------- #
@@ -559,8 +558,8 @@ def build_fsdp_exchange(mesh: Mesh, cfg: ExchangeConfig, param_shardings):
     lead = P(mesh_lib.BATCH_AXES)
     out_grad_specs = sh_treedef.unflatten([s.spec for s in flat_sh])
     # graftlint: ok(retrace) — builder runs once at compile; reused
-    return shard_map(body, mesh=mesh, in_specs=(lead, lead),
-                     out_specs=(out_grad_specs, lead), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(lead, lead),
+                     out_specs=(out_grad_specs, lead), check_vma=False)
 
 
 # dtype crossing the wire in the param all-gather: bf16 halves the
@@ -595,8 +594,8 @@ def build_param_gather(mesh: Mesh, param_shardings):
         return treedef.unflatten(outs)
 
     # graftlint: ok(retrace) — builder runs once at compile; reused
-    return shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                     out_specs=P(), check_vma=False)
 
 
 # --------------------------------------------------------------------- #
@@ -791,9 +790,9 @@ def build_scan_local_grads(mesh: Mesh, value_and_grad_fn, batch_spec,
         return metrics, g_treedef.unflatten(outs)
 
     # graftlint: ok(retrace) — builder runs once at compile; reused
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(param_in_specs, batch_spec, P()),
-        out_specs=(P(), grad_out_specs), check_rep=False)
+        out_specs=(P(), grad_out_specs), check_vma=False)
 
 
 # --------------------------------------------------------------------- #

@@ -27,51 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import mesh as mesh_lib
-from . import sharding as sharding_lib
-
-
-class PipelineCompatError(RuntimeError):
-    """SPMD pipeline composition rejected on this jax version.
-
-    jax 0.4.x XLA rejects the dp>1 x pp>1 composition: the pipeline's
-    partial-manual shard_map lowers a PartitionId instruction that 0.4.x
-    SPMD partitioning cannot place ("UNIMPLEMENTED: PartitionId
-    instruction is not supported for SPMD partitioning").  Raised eagerly
-    so callers get a typed, actionable refusal instead of a deep XLA
-    crash mid-compile.
-    """
-
-
-def _jax_version() -> tuple:
-    try:
-        return tuple(int(x) for x in jax.__version__.split(".")[:2])
-    except (ValueError, AttributeError):  # dev builds: assume new enough
-        return (999, 0)
-
-
-def check_pipeline_compat(mesh: Mesh) -> None:
-    """Refuse SPMD pipeline composition known to crash this jax's XLA.
-
-    dp/fsdp extent > 1 combined with pipeline > 1 on jax 0.4.x lowers an
-    unsupported PartitionId instruction (see PipelineCompatError).  Raises
-    PipelineCompatError with the supported alternatives; no-op otherwise.
-    """
-    S = mesh_lib.mesh_axis_size(mesh, mesh_lib.PIPELINE_AXIS)
-    if S <= 1 or _jax_version() >= (0, 5):
-        return
-    other = mesh.devices.size // S
-    if other <= 1:
-        return
-    raise PipelineCompatError(
-        f"SPMD pipeline (pipeline={S}) combined with {other} data/fsdp-"
-        f"parallel devices is not supported on jax {jax.__version__}: "
-        "0.4.x XLA rejects the PartitionId instruction this composition "
-        "lowers ('UNIMPLEMENTED: PartitionId instruction is not supported "
-        "for SPMD partitioning'). Options: (a) upgrade to jax >= 0.5, "
-        "(b) drop to MeshConfig(data=1, fsdp=1) for a pure-pipeline mesh, "
-        "or (c) use MPMD pipeline parallelism -- "
-        "Trainer(pipeline_stages=...) -- which composes with data "
-        "parallelism on any jax version.")
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -87,7 +42,6 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     S = mesh_lib.mesh_axis_size(mesh, mesh_lib.PIPELINE_AXIS)
     if S == 1:
         return stage_fn(stage_params, x)
-    check_pipeline_compat(mesh)
     M = num_microbatches
     b = x.shape[0]
     if b % M != 0:
@@ -129,7 +83,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
             jnp.where(stage == S - 1, outbuf, jnp.zeros_like(outbuf)), axis)
         return outbuf.reshape(b, *x_full.shape[1:])
 
-    return sharding_lib.shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, axis_names={axis},
         in_specs=(P(axis), P()),   # stage dim manual; rest auto-propagated
         out_specs=P(), check_vma=False)(stage_params, x)

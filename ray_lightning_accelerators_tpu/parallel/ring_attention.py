@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import mesh as mesh_lib
-from . import sharding as sharding_lib
 
 _NEG_INF = -1e30
 
@@ -102,6 +101,6 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     body = functools.partial(ring_attention,
                              axis_name=mesh_lib.SEQUENCE_AXIS,
                              causal=causal, scale=scale)
-    return sharding_lib.shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)(q, k, v)

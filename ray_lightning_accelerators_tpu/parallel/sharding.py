@@ -97,70 +97,74 @@ def validate_shardings(params, shardings, mesh: Mesh) -> None:
     jax.tree_util.tree_map_with_path(check, params, shardings)
 
 
-def shard_map_compat(*args, **kwargs):
-    """``jax.shard_map`` where it exists (0.5+), the experimental import
-    on 0.4.x — one spelling for every call site.  The replication-check
-    kwarg renamed across that boundary too (``check_rep`` ->
-    ``check_vma``); translate whichever the caller used."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            # partial-manual spelling flipped: new jax names the MANUAL
-            # axes, 0.4.x names the AUTO remainder
-            manual = frozenset(kwargs.pop("axis_names"))
-            kwargs["auto"] = (frozenset(kwargs["mesh"].axis_names)
-                              - manual)
-    elif "check_rep" in kwargs:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return fn(*args, **kwargs)
+def manual_axes() -> frozenset:
+    """Mesh axes that are Manual in the current trace: non-empty only
+    inside a ``jax.shard_map`` body."""
+    ambient = jax.sharding.get_abstract_mesh()
+    return frozenset() if ambient.empty else frozenset(ambient.manual_axes)
 
 
-def _manual_axes_active() -> bool:
-    """True while tracing inside a shard_map body (manual mesh axes).
-
-    Newer jax exposes the ambient abstract mesh; 0.4.x has neither
-    ``get_abstract_mesh`` nor bare-spec constraints, but a shard_map
-    body there extends the axis env — any bound axis name means manual
-    context."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        ambient = get()
-        # `_any_axis_manual` is private jax API (0.9.x); degrade to the
-        # plain-jit path if a future jax renames it rather than crashing
-        # every forward
-        return (not ambient.empty) and getattr(ambient,
-                                               "_any_axis_manual", False)
-    try:
-        from jax._src import core as _core
-        return bool(_core.unsafe_get_axis_names())
-    except Exception:
-        return False
+def _drop_axes(spec: P, axes: frozenset) -> P:
+    """``spec`` with every mention of ``axes`` removed."""
+    entries = []
+    for entry in spec:
+        if isinstance(entry, tuple):
+            kept = tuple(a for a in entry if a not in axes)
+            entries.append(kept or None)
+        else:
+            entries.append(None if entry in axes else entry)
+    return P(*entries)
 
 
 def shard_constraint(x, mesh: Mesh, spec: P):
     """with_sharding_constraint that adapts to the tracing context.
 
-    Under plain jit a concrete NamedSharding is valid; inside a
-    (partial-manual) shard_map body the ambient abstract mesh carries Manual
-    axis types and only a bare PartitionSpec resolves correctly -- a
-    NamedSharding over the concrete mesh is accepted at trace time there but
-    fails at lowering.  Context is detected explicitly so genuinely broken
-    specs still raise instead of silently no-op'ing.
+    Three call contexts exist in this package:
 
-    jax 0.4.x: there is no abstract mesh and bare-spec constraints are
-    rejected outright ("requires a non-empty mesh"); inside a manual body
-    the values are device-local and GSPMD constraints carry no meaning
-    there, so the manual branch degrades to identity instead of a
-    guaranteed lowering error.
+    - plain jit (every forward, eval, decode): a concrete NamedSharding.
+    - a FULL-manual ``shard_map`` body (the compressed gradient exchange,
+      parallel/collectives.py ``build_local_grads`` /
+      ``build_scan_local_grads``, runs the whole model in one): values
+      are device-local and a sharding constraint has no meaning --
+      identity.
+    - a PARTIAL-manual body (parallel/pipeline.py: only ``pipeline`` is
+      manual): the remaining Auto axes still propagate, and jax accepts
+      only a bare PartitionSpec over them -- the Manual axes are
+      dropped from the spec.
     """
-    if _manual_axes_active():
-        if getattr(jax.sharding, "get_abstract_mesh", None) is not None:
-            return jax.lax.with_sharding_constraint(x, spec)
+    ambient = jax.sharding.get_abstract_mesh()
+    if ambient.empty or not ambient.manual_axes:
+        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    if ambient.are_all_axes_manual:
         return x
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(
+        x, _drop_axes(spec, frozenset(ambient.manual_axes)))
+
+
+def shard_local(fn, mesh: Optional[Mesh], in_axes, out_axes):
+    """``fn`` applied to each device's shard of its operands, whose
+    layouts are given as tuples of LOGICAL axis names (one per operand /
+    for the single result), translated by ``logical_to_spec``.
+
+    Pallas (Mosaic) kernels carry no GSPMD partitioning rule: under
+    plain jit on a multi-device mesh the TPU compiler refuses them
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map").  Ops that may dispatch to such a kernel
+    (ops/attention.py, ops/norms.py) are row- or head-local, so models
+    run them through this wrapper with the layout their operands already
+    have; replicated operands (norm scales) get their cotangents summed
+    over the unmentioned axes by shard_map's transpose.
+
+    Returns ``fn`` itself where nothing is partitioned: no mesh, a
+    one-device mesh, or a trace already inside a shard_map body (the
+    compressed gradient exchange and ring/ulysses attention run the op
+    on device-local values)."""
+    if mesh is None or mesh.size == 1 or manual_axes():
+        return fn
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(logical_to_spec(axes) for axes in in_axes),
+        out_specs=logical_to_spec(out_axes), check_vma=False)
 
 
 def replicate_tree(tree, mesh: Mesh):

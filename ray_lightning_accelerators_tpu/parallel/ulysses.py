@@ -27,7 +27,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import mesh as mesh_lib
-from . import sharding as sharding_lib
 from ..ops.attention import flash_attention
 
 
@@ -84,6 +83,6 @@ def ulysses_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     body = functools.partial(ulysses_attention,
                              axis_name=mesh_lib.SEQUENCE_AXIS,
                              causal=causal, scale=scale)
-    return sharding_lib.shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)(q, k, v)

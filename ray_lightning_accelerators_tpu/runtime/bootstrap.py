@@ -53,18 +53,8 @@ def initialize_worker(coordinator_address: str, num_processes: int,
         if platform == "cpu":
             jax.config.update("jax_cpu_collectives_implementation", "gloo")
             if cpu_devices_per_process:
-                try:
-                    jax.config.update("jax_num_cpu_devices",
-                                      cpu_devices_per_process)
-                except AttributeError:
-                    # pre-0.5 jax: the XLA flag is the only spelling; this
-                    # runs before the worker's backend initializes, so the
-                    # env route still takes effect
-                    flags = os.environ.get("XLA_FLAGS", "")
-                    if "xla_force_host_platform_device_count" not in flags:
-                        os.environ["XLA_FLAGS"] = (
-                            flags + " --xla_force_host_platform_device_"
-                            f"count={cpu_devices_per_process}").strip()
+                jax.config.update("jax_num_cpu_devices",
+                                  cpu_devices_per_process)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

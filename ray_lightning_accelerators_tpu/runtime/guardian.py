@@ -25,7 +25,7 @@ in three layers:
   the compressed exchange enabled → exchange-induced; a trip that does
   not reproduce at all → suspected SDC.  The verdict ships as a typed
   ``NumericAnomaly`` (wire-registered like ``WorkerWedged``) carrying
-  the offending step, the batch index range, and the blame taxonomy.
+  the offending step, the batch index range, and the blame classes.
 - **Recovery (ElasticRunner)**: rewind to the newest *verified*
   checkpoint (``latest_checkpoint``'s digest walk — a truncated newest
   file is skipped, never restored), quarantine the blamed data window

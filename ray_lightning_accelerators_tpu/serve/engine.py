@@ -292,6 +292,8 @@ class ServeEngine:
                  chunked_prefill: bool = True):
         import jax
 
+        from ..utils import compile_cache
+        compile_cache.enable()  # before this engine's first compile
         if model.cfg.sliding_window is not None:
             raise ValueError(
                 "the serve engine needs linear cache slots; "

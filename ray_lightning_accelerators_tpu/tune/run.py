@@ -483,8 +483,8 @@ def _run_trials_in_processes(trainable, trials, scheduler,
 
 
 # default train-step autotuning space: the three step-shape knobs the
-# MFU ladder (BASELINE.md / scripts/mfu_sweep.py) showed move step time
-# on real hardware — what the rematerialized backward may keep, the
+# MFU ladder (scripts/mfu_sweep.py) showed move step time on real
+# hardware — what the rematerialized backward may keep, the
 # flash-attention tile shape, and (new) how the FSDP compute view is
 # assembled (whole-tree up-front vs overlapped layer-wise in the scan)
 def default_step_space() -> Dict[str, Any]:

@@ -1,11 +1,10 @@
 """Compressed-FSDP exchange microbench on a forced-host-platform CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax (jax 0.4.37 has no ``jax_num_cpu_devices``; the
-XLA_FLAGS override must land before backend init), so it produces a real
-number on any machine — including one whose TPU backend is wedged, which
-is exactly when bench.py falls back to it.  The numbers are honest about
-what they are: CPU "collectives" are memcpys, so the headlines are the
+BEFORE importing jax (the XLA_FLAGS override must land before backend
+init), so it runs the same way on any machine and never touches the
+chip.  The numbers are honest about what they are: CPU "collectives"
+are memcpys, so the headlines are the
 analytic BYTES-ON-WIRE reduction of the int8 reduce-scatter + bf16
 param all-gather regime vs the fp32 allreduce (the quantity that
 transfers to real interconnects) and the MEASURED per-shard peak state

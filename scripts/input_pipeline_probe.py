@@ -1,9 +1,8 @@
 """Input-pipeline microbench on a forced-host-platform CPU mesh.
 
 Self-contained (the gradexchange_probe.py pattern): forces
-``JAX_PLATFORMS=cpu`` with 8 virtual devices BEFORE importing jax, so it
-produces a real number on any machine — including one whose accelerator
-backend is wedged, which is exactly when bench.py falls back to it.
+``JAX_PLATFORMS=cpu`` with 8 virtual devices BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 What it measures: steps/s through the full Trainer fit loop on a
 synthetic INPUT-BOUND loader (a collate_fn that sleeps a configurable

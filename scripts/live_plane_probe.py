@@ -1,9 +1,8 @@
 """Live-telemetry-plane probe on a forced-host-platform 8-device CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax, so it produces a real number on any machine —
-including one whose accelerator backend is wedged, which is exactly when
-bench.py falls back to it.
+BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 Four measurements, one run (telemetry/live.py + serve/slo.py):
 

@@ -1,9 +1,8 @@
 """Overlap-aware FSDP gather + step-autotune probe on a forced CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax, so it produces a real number on any machine —
-including one whose accelerator backend is wedged, which is exactly when
-bench.py falls back to it.
+BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 Two claims, both measured through scripts/mfu_sweep.py's variant
 machinery (bench-honesty: the same ``_bench_gpt`` timed-window / sync

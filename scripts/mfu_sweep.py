@@ -1,8 +1,8 @@
 """MFU sweep harness for the GPT flagship bench config.
 
 Runs one bench-shaped GPT training measurement per requested variant and
-prints a JSON line each, so the BASELINE.md tuned ladder can be
-re-measured (and extended) on hardware in one command:
+prints a JSON line each, so the tuned ladder can be re-measured (and
+extended) on hardware in one command:
 
     python scripts/mfu_sweep.py tuned remat-dots gather-scan
 
@@ -15,8 +15,7 @@ loss_chunk 2048, 24-step epochs, per-chip batch 16, seq 1024):
 - ``remat-dots``    + per-layer jax.checkpoint, dots_saveable: keeps
                     matmul outputs, recomputes elementwise (norm/rope/
                     gelu) in the backward -- trades recompute VPU time
-                    for the residual-stacking HBM traffic the XPlane
-                    trace prices at ~30 ms/step (BASELINE.md)
+                    for the residual-stacking HBM traffic
 - ``remat-dots-nbd``+ dots_with_no_batch_dims_saveable (keeps only
                     batch-free dots; more recompute, less traffic)
 - ``b20`` / ``b24`` per-chip batch 20 / 24 (b24 OOMed by 0.85 GB on the

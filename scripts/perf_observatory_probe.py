@@ -1,9 +1,8 @@
 """Perf-observatory probe on a forced-host-platform 8-device CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax, so it produces a real number on any machine —
-including one whose accelerator backend is wedged, which is exactly when
-bench.py falls back to it.
+BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 One training run + one elastic run exercise all three ledgers
 (telemetry/perf.py), and everything lands in a ``run_report.json`` and

@@ -1,8 +1,7 @@
 """MPMD pipeline-bubble probe on forced-host-platform CPU workers.
 
-Self-contained: forces ``JAX_PLATFORMS=cpu`` BEFORE importing jax, so it
-produces a real number on any machine — including one whose accelerator
-backend is wedged, which is exactly when bench.py falls back to it.
+Self-contained: forces ``JAX_PLATFORMS=cpu`` BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 One PipelineRunner fit (parallel/mpmd/): S=2 stage groups over spawned
 actor-pool workers, 1F1B over M=4 microbatches, activations handed off

@@ -1,9 +1,8 @@
 """Live-resize downtime probe on a forced-host-platform 8-device CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax, so it produces a real number on any machine —
-including one whose accelerator backend is wedged, which is exactly when
-bench.py falls back to it.
+BEFORE importing jax, so it runs the same way on any machine and never
+touches the chip.
 
 One dp=8 fit is interrupted at step 2, then the SAME dp=8→dp=4 shrink is
 recovered both ways and the downtime (recovery entry → first completed

@@ -2,10 +2,8 @@
 a replica chaos window, on a forced host-platform CPU mesh.
 
 Self-contained: forces ``JAX_PLATFORMS=cpu`` with 8 virtual devices
-BEFORE importing jax (matching the other CPU-mesh fallback probes), so
-it produces a real number on any machine — including one whose
-accelerator backend is wedged, which is exactly when bench.py falls
-back to it.
+BEFORE importing jax (matching the other CPU-mesh probes), so it
+runs the same way on any machine and never touches the chip.
 
 Two phases over the same mixed-length sustained workload:
 

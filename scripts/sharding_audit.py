@@ -8,8 +8,8 @@ script extracts it statically (AST only — never imports jax, safe on a
 wedged machine) into ``SHARDING_INVENTORY.json``:
 
 - per inventoried module: every ``PartitionSpec(...)`` / ``P(...)``
-  construction (line, source text), every ``shard_map`` /
-  ``shard_map_compat`` call site, and the module's axis-name constants;
+  construction (line, source text), every ``jax.shard_map`` call
+  site, and the module's axis-name constants;
 - the canonical axis registry from ``parallel/mesh.py`` (string + tuple
   constants — ``DATA_AXIS`` ... ``BATCH_AXES``);
 - totals, so diffs of the committed artifact show inventory drift in
@@ -103,7 +103,7 @@ def extract_inventory(lint):
             if leaf == "PartitionSpec" or fname in aliases:
                 specs.append({"line": node.lineno,
                               "text": _unparse(node, info.lines)})
-            elif leaf in ("shard_map", "shard_map_compat"):
+            elif leaf == "shard_map":
                 shard_maps.append({"line": node.lineno})
         axis_consts = {n: v for n, v in info.consts.items()
                        if key == config.axes_module}

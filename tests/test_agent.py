@@ -261,11 +261,9 @@ def test_pool_env_and_health_over_agents(two_agents):
 def _distributed_psum_agent(process_id):
     import jax
     import jax.numpy as jnp
-    from ray_lightning_accelerators_tpu.parallel.sharding import (
-        shard_map_compat)
 
     assert jax.process_count() == 2
-    out = shard_map_compat(
+    out = jax.shard_map(
         lambda x: jax.lax.psum(x, "i"),
         mesh=jax.sharding.Mesh(jax.devices(), ("i",)),
         in_specs=jax.sharding.PartitionSpec("i"),
@@ -716,6 +714,35 @@ def test_single_host_agent_fans_out(tmp_path):
         trainer.teardown()
     finally:
         agent.shutdown()
+
+
+def test_fan_out_decision_leaves_driver_backend_untouched(
+        cpu_mesh_subprocess):
+    """One process per chip: a driver that initialises its backend takes
+    the chip its same-host worker needs.  The fan-out decision
+    (``_launch_plan`` + ``_spawn_platform``) must therefore read
+    configuration only.  Proven with a platform that cannot initialise:
+    any backend touch in the driver raises."""
+    cpu_mesh_subprocess("""
+import jax
+from ray_lightning_accelerators_tpu import HorovodRayAccelerator, Trainer
+
+trainer = Trainer(max_epochs=1, enable_checkpointing=False,
+                  accelerator=HorovodRayAccelerator(
+                      num_hosts=1, num_slots=1, agents=["127.0.0.1:1"]))
+plan = trainer._launch_plan()
+assert plan is not None and plan["num_processes"] == 1, plan
+env, platform, cpu_per = trainer._spawn_platform(plan)
+# nothing but the unknown platform is configured: workers detect their own
+assert (platform, cpu_per) == (None, None), (platform, cpu_per)
+assert env["RLA_TPU_INSIDE_WORKER"] == "1"
+try:
+    jax.devices()
+except RuntimeError:
+    pass  # the driver's backend was still uninitialised until here
+else:
+    raise AssertionError("no_such_platform initialised?")
+""", env_extra={"JAX_PLATFORMS": "no_such_platform"})
 
 
 def test_queue_server_binds_loopback_by_default():

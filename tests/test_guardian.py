@@ -1,5 +1,5 @@
 """Numeric anomaly guardian (runtime/guardian.py): traced guard vector,
-blame taxonomy, quarantine skip ledger, ElasticRunner rewind loop, and
+blame classes, quarantine skip ledger, ElasticRunner rewind loop, and
 the serve-tier decode guard.
 
 The acceptance loop for the subsystem: inject ``badbatch@stepK`` numeric

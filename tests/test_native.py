@@ -29,6 +29,27 @@ def test_builds():
     assert native.available(), native.build_error()
 
 
+def test_cached_library_is_keyed_on_its_sources(tmp_path, monkeypatch):
+    """The cached ``.so`` is git-ignored, so one copied along with a
+    tree says nothing about the sources it was built from: the name
+    carries a hash of the ``.cc`` files, and a stray library under any
+    other name (here: the pre-hash name) is never picked up."""
+    import os
+    import shutil
+
+    for name in native._sources():
+        shutil.copy(os.path.join(native._DIR, name), tmp_path / name)
+    (tmp_path / "_rla_native.so").write_bytes(b"not built from these")
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    before = native._out_path()
+    assert os.path.basename(before) == \
+        f"_rla_native.{native._source_digest()}.so"
+    assert not os.path.exists(before)  # the stray file does not satisfy it
+    with open(tmp_path / native._sources()[0], "a") as f:
+        f.write("// edited\n")
+    assert native._out_path() != before  # edited sources => rebuilt
+
+
 @pytest.mark.parametrize("shuffle", [False, True])
 def test_matches_python_path_bit_exact(shuffle):
     # sampling stays in Python, so native batches are bit-identical to the

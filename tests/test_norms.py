@@ -72,3 +72,18 @@ def test_bf16_stays_bf16():
     scale = jnp.ones((128,), jnp.bfloat16)
     assert rms_norm(x, scale).dtype == jnp.bfloat16
     assert rms_norm_interpret(x, scale).dtype == jnp.bfloat16
+
+
+def test_row_block_is_a_sublane_multiple_or_the_whole_array():
+    """The TPU lowering refuses any other row block (a 57-token prompt
+    used to become 57 one-row blocks and failed on the chip)."""
+    from ray_lightning_accelerators_tpu.ops.norms import _row_block
+    assert _row_block(8192) == 512
+    assert _row_block(24) == 8
+    assert _row_block(57) == 57 and _row_block(4) == 4
+    assert _row_block(1031) is None  # long and odd: the jnp reference
+    for rows in range(1, 1100):
+        br = _row_block(rows)
+        assert br is None or (rows % br == 0
+                              and (br % 8 == 0 or br == rows))
+

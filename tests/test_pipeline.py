@@ -58,16 +58,6 @@ def test_pipeline_gradients_match():
                                    atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x XLA limitation: the dp>1 x pp>1 composition "
-           "lowers a PartitionId instruction inside the pipeline's "
-           "partial-manual shard_map, which 0.4.x SPMD partitioning "
-           "rejects as ambiguous ('UNIMPLEMENTED: PartitionId "
-           "instruction is not supported for SPMD partitioning'). "
-           "Environmental, not a repo regression: reproduces on clean "
-           "seed HEAD, and the dp=1 pipeline tests above cover the "
-           "schedule itself on this jax.  Re-enable on jax >= 0.5.")
 def test_gpt_trains_with_pipeline(tmpdir):
     """Full model under dp2 x pp2: trains below chance loss; stage params
     actually sharded over the pipeline axis."""
@@ -91,23 +81,15 @@ def test_gpt_pipeline_matches_plain(tmpdir):
                                    atol=2e-4, rtol=2e-3)
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) >= (0, 5),
-    reason="the dp>1 x pp>1 SPMD composition works on jax >= 0.5; the "
-           "typed refusal only guards 0.4.x")
-def test_dp_times_pp_refused_typed_on_jax04():
-    """Regression for the skipif above (test_gpt_trains_with_pipeline):
-    on jax 0.4.x the dp>1 x pp>1 composition must fail EAGERLY as a
-    PipelineCompatError naming the alternatives, not as a deep XLA
-    'PartitionId instruction is not supported' crash mid-compile."""
-    from ray_lightning_accelerators_tpu.parallel.pipeline import (
-        PipelineCompatError)
+def test_dp_times_pp_matches_sequential():
+    """dp>1 x pp>1: the partial-manual shard_map (only ``pipeline``
+    manual, the data axis left to the partitioner) must give the
+    sequential result on a batch sharded over ``data``."""
     mesh = Accelerator(MeshConfig(data=2, pipeline=2)).build_mesh()
     params = _layers_params(n_layers=4)
-    x = jnp.ones((8, 16))
-    with pytest.raises(PipelineCompatError) as exc_info:
-        jax.jit(lambda p, xx: pipeline_apply(
-            _stage_fn, p, xx, mesh, 4))(params, x)
-    msg = str(exc_info.value)
-    assert "jax >= 0.5" in msg
-    assert "pipeline_stages" in msg  # points at the MPMD alternative
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 16))
+    out = jax.jit(lambda p, xx: pipeline_apply(
+        _stage_fn, p, xx, mesh, 4))(params, x)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_stage_fn(params, x)),
+                               atol=1e-5, rtol=1e-5)

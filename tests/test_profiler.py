@@ -156,6 +156,20 @@ def test_flops_estimate_and_mfu():
     assert mfu(1e9, 1e-3, peak_flops=1e12) == pytest.approx(1.0)
 
 
+def test_unknown_device_has_no_peak():
+    """A utilization against an unknown peak is not a number: a device
+    missing from the published-peak table raises (it used to read as
+    MFU 0.0), and the table names where its figures come from."""
+    from ray_lightning_accelerators_tpu.utils import profiler as prof
+
+    assert prof.peak_bf16_flops("TPU v5 lite") == 197e12
+    assert "Google Cloud" in prof.PEAK_BF16_FLOPS_SOURCE
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        prof.peak_bf16_flops("cpu")
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        prof.mfu(1e9, 1e-3)  # the forced-CPU suite's own device
+
+
 def test_trace_op_summary_parses_device_events(tmp_path):
     """trace_op_summary reads an XPlane-exported trace.json.gz, keeps only
     device-clock events, resolves nesting (a scan's children don't

@@ -65,10 +65,9 @@ def test_fsdp_matches_dp_loss(tmpdir):
     Tolerance 3e-3, not bitwise: FSDP re-associates the f32 gradient
     reduction (per-shard partial sums + all-gather vs one replicated
     allreduce), and f32 addition is not associative — after 8 SGD steps
-    at lr 0.05 the trajectories drift ~1.05e-3 relative on this jax
-    build (0.4.37 CPU; measured 2.6632 vs 2.6660, reproduces on clean
-    seed HEAD where the old rel=1e-3 bound sat exactly on the knife
-    edge).  The bound still catches a wrong-math regression by two
+    at lr 0.05 the trajectories drift ~1.05e-3 relative on the CPU
+    backend (measured 2.6632 vs 2.6660: the old rel=1e-3 bound sat
+    exactly on the knife edge).  The bound still catches a wrong-math regression by two
     orders of magnitude."""
     x, y = synthetic_cifar10(256, seed=0)
 

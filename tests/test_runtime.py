@@ -146,11 +146,9 @@ def _distributed_psum(process_id, coord, nprocs):
                       cpu_devices_per_process=1)
     import jax
     import jax.numpy as jnp
-    from ray_lightning_accelerators_tpu.parallel.sharding import (
-        shard_map_compat)
 
     assert jax.process_count() == nprocs
-    out = shard_map_compat(
+    out = jax.shard_map(
         lambda x: jax.lax.psum(x, "i"),
         mesh=jax.sharding.Mesh(jax.devices(), ("i",)),
         in_specs=jax.sharding.PartitionSpec("i"),

@@ -358,8 +358,6 @@ def test_sanitizer_records_traced_collectives(spmd_sanitizer):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ray_lightning_accelerators_tpu.parallel.sharding import (
-        shard_map_compat)
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
 
@@ -367,9 +365,9 @@ def test_sanitizer_records_traced_collectives(spmd_sanitizer):
         own = jax.lax.axis_index("data")
         return jax.lax.psum(x, "data") + own
 
-    out = shard_map_compat(f, mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data"),
-                           check_rep=False)(jnp.arange(4, dtype=jnp.float32))
+    out = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                        out_specs=P("data"),
+                        check_vma=False)(jnp.arange(4, dtype=jnp.float32))
     assert out.shape == (4,)
     san = spmd_sanitizer.get_sanitizer()
     seq = san.sequence()
@@ -620,8 +618,6 @@ def _trace_rank_collectives(rank, divergent_rank):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ray_lightning_accelerators_tpu.parallel.sharding import (
-        shard_map_compat)
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
 
@@ -631,9 +627,9 @@ def _trace_rank_collectives(rank, divergent_rank):
             y = jax.lax.pmean(y, "data")
         return y
 
-    out = shard_map_compat(f, mesh=mesh, in_specs=P(None),
-                           out_specs=P(None),
-                           check_rep=False)(jnp.ones((4,), jnp.float32))
+    out = jax.shard_map(f, mesh=mesh, in_specs=P(None),
+                        out_specs=P(None),
+                        check_vma=False)(jnp.ones((4,), jnp.float32))
     return float(np.asarray(out)[0])
 
 

@@ -1,0 +1,309 @@
+"""What the chip's compiler says, asked without a chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+*described* (``v5e:2x2``), not attached.  These tests hand it the main
+path at the 124M-GPT widths ``chip_smoke.py`` runs: every Pallas kernel,
+the whole jitted train step (one chip, and the four-chip FSDP layouts),
+the serve engine's paged programs and the int8 ``generate``.  Interpret
+mode on the CPU mesh cannot see what this sees: a tiling the compiler
+refuses, a kernel it cannot partition, a program that does not fit HBM.
+
+Nothing runs and nothing is timed: a compile that passes is not a chip
+run.  Code that asks ``jax.default_backend()`` would take its CPU branch
+here, so the ``chip_dispatch`` fixture steers it onto the kernel branch
+*in the test* -- the program has no option for it.
+
+The topology is described inside a module-scoped fixture (never at
+import, in a ``skipif`` or in ``parametrize`` arguments): only the xdist
+worker that is handed this file loads libtpu.  All such tests live in
+this one file for the same reason.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from chip_smoke import kernels_in as _kernels
+from ray_lightning_accelerators_tpu import RayTPUAccelerator, Trainer
+from ray_lightning_accelerators_tpu.core.state import TrainState
+from ray_lightning_accelerators_tpu.models.transformer import GPT
+from ray_lightning_accelerators_tpu.ops import quant
+from ray_lightning_accelerators_tpu.ops.attention import flash_attention
+from ray_lightning_accelerators_tpu.ops.norms import layer_norm, rms_norm
+from ray_lightning_accelerators_tpu.runtime import guardian
+from ray_lightning_accelerators_tpu.serve import ServeEngine
+from ray_lightning_accelerators_tpu.utils.seed import rng_from_seed
+
+# the 124M GPT and the step shape chip_smoke.py runs on the chip
+SIZE = chip_smoke.Size()
+PER_CHIP_BATCH, SEQ = SIZE.batch, SIZE.seq
+HBM_BYTES = 16 * 2 ** 30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_dispatch(monkeypatch):
+    """Steer every ``jax.default_backend()`` dispatch in the package
+    (ops/attention.py, ops/norms.py, GPT._q8_kernel_mode, the serve
+    engine's donation switch) onto its on-chip branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# --------------------------------------------------------------------- #
+# Pallas kernels at the 124M widths                                      #
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("block", [512, 1024])
+def test_flash_forward_compiles(one_chip, chip_dispatch, block):
+    q = _sds((PER_CHIP_BATCH, 12, SEQ, 64), jnp.bfloat16, one_chip)
+    fwd = jax.jit(functools.partial(flash_attention, causal=True,
+                                    block_q=block, block_k=block))
+    lowered = fwd.lower(q, q, q)
+    assert _kernels(lowered) == ["_flash_kernel"]
+    lowered.compile()
+
+
+@pytest.mark.parametrize("block,bwd_kernels", [
+    (512, ["_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"]),
+    (1024, ["_flash_bwd_fused_kernel"]),  # k_len == block_k: one pass
+])
+def test_flash_backward_compiles(one_chip, chip_dispatch, block,
+                                 bwd_kernels):
+    q = _sds((PER_CHIP_BATCH, 12, SEQ, 64), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, block, block)
+        return out.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    assert _kernels(lowered) == sorted(["_flash_kernel"] + bwd_kernels)
+    lowered.compile()
+
+
+def test_norm_kernels_compile(one_chip, chip_dispatch):
+    x = _sds((8192, 768), jnp.bfloat16, one_chip)
+    s = _sds((768,), jnp.float32, one_chip)
+    rms = jax.jit(rms_norm).lower(x, s)
+    assert _kernels(rms) == ["_rms_kernel"]
+    rms.compile()
+    ln = jax.jit(layer_norm).lower(x, s, s)
+    assert _kernels(ln) == ["_ln_kernel"]
+    ln.compile()
+
+
+@pytest.mark.parametrize("rows", [4, 57, 1000])
+def test_rms_norm_compiles_at_row_counts_off_the_sublane_tile(
+        one_chip, chip_dispatch, rows):
+    """Decode at 4 slots, a 57-token prompt, 1000 = 8 * 125 rows: the
+    TPU lowering takes row blocks that are a multiple of 8 or the whole
+    array (57 one-row blocks were refused on the chip, PR 22)."""
+    x = _sds((1, rows, 768), jnp.bfloat16, one_chip)
+    s = _sds((768,), jnp.float32, one_chip)
+    lowered = jax.jit(rms_norm).lower(x, s)
+    assert _kernels(lowered) == ["_rms_kernel"]
+    lowered.compile()
+
+
+@pytest.mark.parametrize("k,n", [(768, 768), (768, 3072), (3072, 768)])
+def test_int8_matmul_decode_shapes_compile(one_chip, k, n):
+    """q/k/v/o, MLP-in and MLP-out projections at decode batch 16."""
+    x = _sds((16, k), jnp.bfloat16, one_chip)
+    wq = _sds((k, n), jnp.int8, one_chip)
+    scale = _sds((n,), jnp.float32, one_chip)
+    lowered = quant.int8_matmul.lower(x, wq, scale)
+    assert _kernels(lowered) == ["_mm_kernel"]
+    lowered.compile()
+
+
+def test_int8_matmul_nt_unembed_compiles(one_chip):
+    """The tied-embedding unembed, 16 x 768 x 50304."""
+    x = _sds((16, 768), jnp.bfloat16, one_chip)
+    wq = _sds((50304, 768), jnp.int8, one_chip)
+    lowered = quant.int8_matmul_nt.lower(x, wq)
+    assert _kernels(lowered) == ["_mm_nt_kernel"]
+    lowered.compile()
+
+
+# --------------------------------------------------------------------- #
+# The whole jitted train step                                            #
+# --------------------------------------------------------------------- #
+def _abstract_train_step(devices, per_chip_batch, use_fsdp=False,
+                         **trainer_kw):
+    """The Trainer's own jitted train step for ``devices``, with every
+    operand a shape: what ``Trainer._fit_local`` sets up before
+    ``_compile``, with ``jax.eval_shape`` in place of real arrays (a
+    described device cannot hold one)."""
+    module = GPT(chip_smoke._model_config(SIZE), lr=3e-4)
+    trainer = Trainer(
+        max_epochs=1, precision="bf16", enable_checkpointing=False, seed=0,
+        accelerator=RayTPUAccelerator(num_workers=len(devices),
+                                      use_fsdp=use_fsdp,
+                                      devices=list(devices)),
+        **trainer_kw)
+    trainer.module, module.trainer = module, trainer
+    module.compute_dtype = trainer.compute_dtype
+    trainer._mesh = trainer.accelerator.build_mesh()
+    trainer._tx = trainer._build_tx(module)
+
+    def make_state():
+        init_rng, state_rng = jax.random.split(rng_from_seed(trainer.seed))
+        params = module.init_params(init_rng)
+        state = TrainState.create(params, trainer._tx, state_rng)
+        if trainer.grad_compression is not None:
+            residual, grad_accum = trainer._fresh_exchange_buffers(
+                module, params, trainer._mesh)
+            state = state.replace(residual=residual, grad_accum=grad_accum)
+        return state.replace(guard_ema=jnp.asarray(guardian.fresh_state()))
+
+    state = jax.eval_shape(make_state)
+    batch = jax.ShapeDtypeStruct((per_chip_batch * len(devices), SEQ),
+                                 jnp.int32)
+    trainer._compile(module, state, batch)
+    state = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
+                         state, trainer._state_shardings)
+    batch = _sds(batch.shape, batch.dtype, trainer._batch_sharding)
+    return trainer, trainer._train_step_fn.lower(state, batch)
+
+
+def _per_device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+_STEP_KERNELS = ["_flash_bwd_fused_kernel", "_flash_kernel", "_rms_kernel"]
+
+
+def test_single_chip_train_step_compiles(topo, chip_dispatch):
+    """custom_vjp kernels inside the layer scan, fused loss, guard tail,
+    AdamW and donation, composed -- at bench.py's batch 16 x 1024."""
+    _, lowered = _abstract_train_step(topo.devices[:1], PER_CHIP_BATCH)
+    assert _kernels(lowered) == _STEP_KERNELS
+    compiled = lowered.compile()
+    assert _per_device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("trainer_kw", [
+    {},
+    {"grad_compression": "int8", "gather_mode": "scan"},
+], ids=["fsdp", "fsdp-int8-scan-gather"])
+def test_four_chip_fsdp_train_step_compiles(topo, chip_dispatch,
+                                            trainer_kw):
+    """One program across the 2x2 mesh.  Under plain jit the compiler
+    refuses a Pallas call it would have to partition ("Mosaic kernels
+    cannot be automatically partitioned"), so the model runs its kernels
+    per shard (parallel/sharding.shard_local); the compressed exchange
+    runs them inside its own full-manual shard_map."""
+    trainer, lowered = _abstract_train_step(
+        topo.devices, PER_CHIP_BATCH // 4, use_fsdp=True, **trainer_kw)
+    if trainer_kw:
+        assert trainer._gather_mode_eff == "scan"
+    assert _kernels(lowered) == _STEP_KERNELS
+    compiled = lowered.compile()
+    assert _per_device_bytes(compiled) < HBM_BYTES
+    # params and Adam moments really are 1/4 per chip
+    wq = trainer._state_shardings.params["layers"]["attn"]["wq"]
+    assert wq.shard_shape((12, 768, 12, 64)) == (12, 192, 12, 64)
+
+
+# --------------------------------------------------------------------- #
+# Serve engine programs and int8 generate                                #
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def gpt_bf16(chip_dispatch):
+    model = GPT(chip_smoke._model_config(SIZE))
+    model.compute_dtype = jnp.bfloat16
+    shapes = jax.eval_shape(lambda: jax.tree.map(
+        lambda p: p.astype(jnp.bfloat16),
+        model.init_params(jax.random.PRNGKey(0))))
+    return model, shapes
+
+
+def test_paged_serve_programs_compile(one_chip, gpt_bf16):
+    """The engine's own jitted programs -- the paged decode step and the
+    paged prefill chunk (smallest and largest bucket) -- with the pool
+    operand donated, as it is on the chip and never is on CPU."""
+    model, shapes = gpt_bf16
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    # the engine never inspects its params; the programs take them as an
+    # operand, so placeholders stand in for 124M real weights
+    engine = ServeEngine(model, jax.tree.map(
+        lambda s: np.zeros((1,), s.dtype), shapes))
+    assert engine.paged and engine._donate
+    params = on_chip(shapes)
+    pool = on_chip(jax.eval_shape(
+        lambda: model.paged_cache_alloc(engine.n_blocks, engine.block_len)))
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    B, M = engine.max_slots, engine.table_blocks
+    step = engine._step.lower(params, pool, i32(B, M), i32(B), i32(B))
+    assert _kernels(step) == ["_rms_kernel"]
+    # donated: the pool is aliased to the output, not copied (>=: the
+    # chip's tiled layout pads the [.., block_len, head_dim] minor dims)
+    compiled = step.compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    for bucket in (engine.block_len,
+                   engine._chunk_blocks * engine.block_len):
+        chunk = engine._chunk_prefill_fn(bucket).lower(
+            params, pool, i32(M), i32(1, bucket), i32(), i32())
+        compiled = chunk.compile()
+        assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_int8_generate_compiles_on_the_kernel_path(one_chip, gpt_bf16):
+    """Batch-16 greedy generate over ``quantize_weights`` params: every
+    decode matmul of the model's own shapes takes the compiled q8
+    kernels (none declined), composed with prefill's flash kernel."""
+    model, shapes = gpt_bf16
+    q8 = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                      jax.eval_shape(GPT.quantize_weights, shapes))
+    assert model._q8_kernel_mode() == "compiled"
+    declined = set(GPT._q8_declined_shapes)
+    gen = jax.jit(functools.partial(model.generate, max_new_tokens=32,
+                                    temperature=0.0))
+    lowered = gen.lower(q8, _sds((16, 128), jnp.int32, one_chip))
+    assert GPT._q8_declined_shapes == declined
+    assert _kernels(lowered) == ["_flash_kernel", "_mm_kernel",
+                                 "_mm_nt_kernel", "_rms_kernel"]
+    lowered.compile()
