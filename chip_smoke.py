@@ -218,9 +218,9 @@ def phase_kernels(size: Size, seed: int) -> None:
         bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
         fwd_kernels = kernels_in(fwd.lower(q, k, v))
         bwd_kernels = kernels_in(bwd.lower(q, k, v))
-        require(fwd_kernels == ["_flash_kernel"],
+        require(fwd_kernels == ["flash_fwd"],
                 f"flash forward dispatched to {fwd_kernels or 'reference'}")
-        require(any(n.startswith("_flash_bwd") for n in bwd_kernels),
+        require(any(n.startswith("flash_bwd") for n in bwd_kernels),
                 f"flash backward dispatched to {bwd_kernels}")
         err_fwd = _rel_err(fwd(q, k, v), ref_out)
         err_bwd = [_rel_err(a, b) for a, b in zip(bwd(q, k, v), ref_grads)]
@@ -255,7 +255,7 @@ def phase_kernels(size: Size, seed: int) -> None:
         wq = jax.random.randint(next(keys), (kk, n), -127, 128, jnp.int8)
         sc = jax.random.uniform(next(keys), (n,), jnp.float32, 0.002, 0.02)
         names = kernels_in(quant.int8_matmul.lower(xr, wq, sc))
-        require(names == ["_mm_kernel"], f"int8_matmul lowered {names}")
+        require(names == ["q8_matmul"], f"int8_matmul lowered {names}")
         with jax.default_matmul_precision("highest"):
             ref_out = xr.astype(jnp.float32) @ (
                 wq.astype(jnp.float32) * sc[None, :])
@@ -266,7 +266,7 @@ def phase_kernels(size: Size, seed: int) -> None:
     wq = jax.random.randint(next(keys), (size.vocab_size, d), -127, 128,
                             jnp.int8)
     names = kernels_in(quant.int8_matmul_nt.lower(xr, wq))
-    require(names == ["_mm_nt_kernel"], f"int8_matmul_nt lowered {names}")
+    require(names == ["q8_matmul_nt"], f"int8_matmul_nt lowered {names}")
     with jax.default_matmul_precision("highest"):
         ref_out = xr.astype(jnp.float32) @ wq.astype(jnp.float32).T
     err = _rel_err(quant.int8_matmul_nt(xr, wq), ref_out)
@@ -344,8 +344,8 @@ def phase_train(size: Size, seed: int, on_chip: bool = True):
                   observed_tokens_per_s=size.batch * size.seq / step_s,
                   scanned_epoch=trainer._epoch_scan_fn is not None)
     if on_chip:
-        require("_flash_kernel" in names
-                and any(n.startswith("_flash_bwd") for n in names),
+        require("flash_fwd" in names
+                and any(n.startswith("flash_bwd") for n in names),
                 f"train step lowering holds {names}: no flash fwd+bwd")
         stats = jax.devices()[0].memory_stats()
         record.update(peak_hbm_bytes=stats["peak_bytes_in_use"],

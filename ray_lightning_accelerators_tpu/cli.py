@@ -44,7 +44,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     tr = sub.add_parser(
         "trace", help="summarize an XPlane device trace directory "
                       "(written by Profiler.start_trace) as a per-op / "
-                      "per-category roofline table")
+                      "per-category roofline table, each op with its "
+                      "named scope where scopes.json lies beside it")
     tr.add_argument("trace_dir", help="directory passed to start_trace")
     tr.add_argument("--top", type=int, default=25,
                     help="rows in the per-op table (0 = all)")
@@ -74,10 +75,15 @@ def main(argv: Optional[List[str]] = None) -> None:
                                key=lambda kv: -kv[1]["self_ms"]):
             print(f"{cat:<26} {row['self_ms']:>10.2f} {row['gbps']:>8.1f} "
                   f"{row['tfs']:>7.1f} {row['pct']:>6.1f}")
-        print(f"\n{'op':<44} {'self ms':>10} {'n':>6} {'%':>6}")
+        # scopes.json lay beside the trace (Profiler.stop_trace): each
+        # op's named-scope stack in the program that ran most of it
+        scope = (f"  scope in {s['scope_program']}"
+                 if "scope_program" in s else "")
+        print(f"\n{'op':<44} {'self ms':>10} {'n':>6} {'%':>6}{scope}")
         for op in s["ops"]:
             print(f"{op['name'][:44]:<44} {op['self_ms']:>10.2f} "
-                  f"{op['count']:>6d} {op['pct']:>6.1f}")
+                  f"{op['count']:>6d} {op['pct']:>6.1f}"
+                  f"  {op.get('scope', '')}".rstrip())
 
 
 if __name__ == "__main__":

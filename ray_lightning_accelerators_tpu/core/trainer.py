@@ -47,10 +47,12 @@ from ..data.loader import DataLoader
 from ..parallel import mesh as mesh_lib
 from ..telemetry import live as live_lib
 from ..telemetry import recorder as telemetry
+from ..telemetry import scopes as scopes_lib
 from ..utils import checkpoint as ckpt_lib
 from ..utils import compile_cache
 from ..utils.logging import CSVLogger, InMemoryLogger, Logger, log
 from ..utils.profiler import Profiler
+from ..utils.scope import scoped
 from ..utils.seed import rng_from_seed, seed_everything
 from .callbacks import Callback, ModelCheckpoint
 from .module import TpuModule
@@ -407,6 +409,9 @@ class Trainer:
         self._device_cache = None
         self._train_step_cached_fn = None
         self._epoch_scan_fn = None
+        # host seconds of the running epoch by phase (_epoch_span), moved
+        # into its epoch_end event
+        self._epoch_host: Dict[str, float] = {}
         self._zero1_update_sh = None
         # param shardings when the compressed exchange runs in the FSDP
         # (reduce-scatter/all-gather) regime; None = replicated-DP regime
@@ -1125,6 +1130,7 @@ class Trainer:
                      if f.kind in ("nanloss", "gradspike", "bitflip")
                      and chaos_lib.claim_numeric(f))
 
+    @scoped("guard")
     def _guard_tail(self, st: TrainState, new_state: TrainState, metrics,
                     grads=None, stacked_local=None):
         """Guardian hook shared by every step builder: fold the traced
@@ -1213,8 +1219,9 @@ class Trainer:
         # every leaf of the (arbitrary) batch / metrics subtree.
         repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
 
+        @scoped("optimizer")
         def apply_grads(grads, opt_state, params):
-            """Optimizer update shared by both step variants.  Under
+            """Optimizer update shared by every step builder.  Under
             ZeRO-1 the grads are pinned replicated (so the reduce is the
             SAME op as the replicated baseline -- the bit-identity
             guarantee) and the update tree is constrained to the
@@ -1287,11 +1294,11 @@ class Trainer:
         def predict_step(params, batch):
             return module.predict_step(params, batch)
 
-        self._train_step_fn = jax.jit(
+        self._train_step_fn = scopes_lib.Program("train_step", jax.jit(
             train_step,
             in_shardings=(state_sh, batch_sh),
             out_shardings=(state_sh, repl),
-            donate_argnums=0)
+            donate_argnums=0))
         if self._device_cache is not None:
             self._compile_cached_step(train_step, state_sh, batch_sh, repl)
         self._eval_step_fn = jax.jit(
@@ -1358,7 +1365,8 @@ class Trainer:
                 step_metrics_lr, chaos_numeric)
         local_grad_fn = collectives_lib.build_local_grads(
             mesh, vag, batch_sh.spec, extra_metrics=extra)
-        exchange_fn = collectives_lib.build_exchange(mesh, cfg)
+        exchange_fn = scoped("exchange")(
+            collectives_lib.build_exchange(mesh, cfg))
         from ..testing import chaos as chaos_lib
 
         def train_step(st: TrainState, batch):
@@ -1493,13 +1501,15 @@ class Trainer:
             scanned = self._scanned_keys
             prelude, hooks = collectives_lib.build_scan_param_gather(
                 mesh, self._fsdp_param_sh, scanned)
+            prelude = scoped("exchange")(prelude)
             local_scan_fn = collectives_lib.build_scan_local_grads(
                 mesh, vag, batch_sh.spec, self._fsdp_param_sh, scanned,
                 hooks, extra_metrics=extra)
             rest_sh = {kk: v for kk, v in self._fsdp_param_sh.items()
                        if kk not in scanned}
-            exchange_rest = (collectives_lib.build_fsdp_exchange(
-                mesh, cfg, rest_sh) if rest_sh else None)
+            exchange_rest = (scoped("exchange")(
+                collectives_lib.build_fsdp_exchange(mesh, cfg, rest_sh))
+                if rest_sh else None)
 
             def train_step(st: TrainState, batch):
                 step_rng = jax.random.fold_in(st.rng, st.step)
@@ -1531,10 +1541,11 @@ class Trainer:
 
         local_grad_fn = collectives_lib.build_local_grads(
             mesh, vag, batch_sh.spec, extra_metrics=extra)
-        gather_fn = collectives_lib.build_param_gather(
-            mesh, self._fsdp_param_sh)
-        exchange_fn = collectives_lib.build_fsdp_exchange(
-            mesh, cfg, self._fsdp_param_sh)
+        gather_fn = scoped("exchange")(
+            collectives_lib.build_param_gather(mesh, self._fsdp_param_sh))
+        exchange_fn = scoped("exchange")(
+            collectives_lib.build_fsdp_exchange(
+                mesh, cfg, self._fsdp_param_sh))
 
         def train_step(st: TrainState, batch):
             step_rng = jax.random.fold_in(st.rng, st.step)
@@ -1620,11 +1631,12 @@ class Trainer:
         def cached_step(st, cache, idx):
             return train_step(st, gather(cache, idx))
 
-        self._train_step_cached_fn = jax.jit(
-            cached_step,
-            in_shardings=(state_sh, repl, idx_row_sh),
-            out_shardings=(state_sh, repl),
-            donate_argnums=0)
+        self._train_step_cached_fn = scopes_lib.Program(
+            "train_step_cached", jax.jit(
+                cached_step,
+                in_shardings=(state_sh, repl, idx_row_sh),
+                out_shardings=(state_sh, repl),
+                donate_argnums=0))
 
         # whole-epoch fusion: ONE dispatch runs every step of an epoch as
         # a lax.scan over the index matrix.  Per-step dispatch overhead
@@ -1635,23 +1647,21 @@ class Trainer:
                 return cached_step(carry, cache, idx)
             return jax.lax.scan(body, st, idx_mat)
 
-        self._epoch_scan_fn = jax.jit(
+        self._epoch_scan_fn = scopes_lib.Program("epoch_scan", jax.jit(
             scanned_epoch,
             in_shardings=(state_sh, repl, idx_mat_sh),
             out_shardings=(state_sh, repl),
-            donate_argnums=0)
+            donate_argnums=0))
 
     def _can_scan_epoch(self) -> bool:
         """Whole-epoch fusion is eligible when nothing needs the host
         between steps: device cache active, no mid-epoch validation, no
-        wall-clock budget (max_time resolves per step in loop mode), no
-        per-step profiler spans, and no callback overriding
-        on_train_batch_end (the scan cannot call back per step)."""
+        wall-clock budget (max_time resolves per step in loop mode), and
+        no callback overriding on_train_batch_end (the scan cannot call
+        back per step).  A profiler never changes which program runs."""
         if self._epoch_scan_fn is None or self._device_cache is None:
             return False
         if self.val_check_interval or self.max_time is not None:
-            return False
-        if self.profiler is not None:
             return False
         # an active quarantine (runtime/guardian.py) needs the per-batch
         # skip seam of the step loop; badbatch chaos needs the host path
@@ -1694,28 +1704,29 @@ class Trainer:
         partial batch (drop_last=False) still runs through the host path.
         Guard conditions mirror the step loop exactly: a max_steps budget
         hit anywhere in the epoch marks it incomplete and stops."""
-        perm, bs, full_nb = self._epoch_index_plan(loader)
-        nb_epoch = full_nb
-        if self.limit_train_batches is not None:
-            nb_epoch = min(nb_epoch, self.limit_train_batches)
-        nb = nb_epoch
-        if self.max_steps:
-            nb = min(nb, max(0, self.max_steps - self.global_step))
-        budget_cut = nb < nb_epoch  # max_steps ends the epoch early
+        with self._epoch_span("epoch_plan", "plan_s"):
+            perm, bs, full_nb = self._epoch_index_plan(loader)
+            nb_epoch = full_nb
+            if self.limit_train_batches is not None:
+                nb_epoch = min(nb_epoch, self.limit_train_batches)
+            nb = nb_epoch
+            if self.max_steps:
+                nb = min(nb, max(0, self.max_steps - self.global_step))
+            budget_cut = nb < nb_epoch  # max_steps ends the epoch early
+            if nb:
+                idx_mat = self._put_index_matrix(
+                    perm[:nb * bs].astype(np.int32).reshape(nb, bs))
         train_metrics: Dict[str, Any] = {}
         if nb:
-            idx_mat = self._put_index_matrix(
-                perm[:nb * bs].astype(np.int32).reshape(nb, bs))
-            t_scan = time.perf_counter()
-            state, stacked = self._epoch_scan_fn(state, self._device_cache,
-                                                 idx_mat)
+            with self._epoch_span("epoch_dispatch", "dispatch_s"):
+                state, stacked = self._epoch_scan_fn(
+                    state, self._device_cache, idx_mat)
+            # the scanned epoch is ONE async dispatch: per-step phases
+            # don't exist, so the timeline gets one coarse nb-step row
+            # once the epoch's readback has shown the device's time
+            # (_after_train_epoch)
+            self._epoch_host["scanned_steps"] = nb
             if self.perf is not None:
-                # the scanned epoch is ONE async dispatch — per-step
-                # phases don't exist, so the timeline gets one coarse
-                # nb-step row (dispatch wall; device time lands at the
-                # next sync) and the HBM ledger its throttled sample
-                self.perf.timeline.observe_scan_epoch(
-                    time.perf_counter() - t_scan, nb)
                 self.perf.hbm.maybe_sample()
             first_step = self.global_step
             self.global_step += nb
@@ -1726,13 +1737,15 @@ class Trainer:
             hits = [i for i in range(nb)
                     if (first_step + i + 1) % cadence == 0]
             if hits:
-                # graftlint: ok(host-sync) — one post-epoch readback of
-                host = jax.device_get(stacked)  # the stacked metrics
+                with self._epoch_span("epoch_readback", "readback_s"):
+                    # graftlint: ok(host-sync) — one post-epoch readback of
+                    host = jax.device_get(stacked)  # the stacked metrics
                 g_stack = host.pop("guard", None)
-                for i in hits:
-                    self._log_now({k: float(v[i])
-                                   for k, v in host.items()},
-                                  step=first_step + i + 1)
+                with self._epoch_span("log_replay", "log_s"):
+                    for i in hits:
+                        self._log_now({k: float(v[i])
+                                       for k, v in host.items()},
+                                      step=first_step + i + 1)
                 if g_stack is not None:
                     # sticky flags: the last scanned row carries any trip
                     # graftlint: ok(host-sync) — already on host (the
@@ -2478,6 +2491,7 @@ class Trainer:
 
         train_metrics: Dict[str, Any] = {}
         use_scan = self._can_scan_epoch()
+        self._epoch_host = {}
         while not self._done():
             for c in self.callbacks:
                 c.on_train_epoch_start(self, module)
@@ -2795,8 +2809,9 @@ class Trainer:
         harvest metrics, run epoch-boundary validation, fire callbacks,
         advance the epoch counter."""
         if train_metrics:
-            # graftlint: ok(host-sync) — epoch-boundary readback
-            host = jax.device_get(train_metrics)
+            with self._epoch_span("epoch_readback", "readback_s"):
+                # graftlint: ok(host-sync) — epoch-boundary readback
+                host = jax.device_get(train_metrics)
             guard_row = host.pop("guard", None)
             # fence FIRST: a sticky trip must raise before checkpoint /
             # early-stop callbacks can act on post-anomaly state
@@ -2827,16 +2842,29 @@ class Trainer:
                 c.on_validation_end(self, module)
             telemetry.emit("validation", step=self.global_step,
                            epoch=self.current_epoch)
-        for c in self.callbacks:
-            c.on_train_epoch_end(self, module)
-        if not run_val and self._val_loader is None:
-            # checkpoint/early-stop callbacks keyed on validation_end
-            # still fire once per epoch on train metrics
+        with self._epoch_span("callbacks", "callbacks_s"):
             for c in self.callbacks:
-                c.on_validation_end(self, module)
+                c.on_train_epoch_end(self, module)
+            if not run_val and self._val_loader is None:
+                # checkpoint/early-stop callbacks keyed on validation_end
+                # still fire once per epoch on train metrics
+                for c in self.callbacks:
+                    c.on_validation_end(self, module)
         self.current_epoch += 1
+        host_s, self._epoch_host = self._epoch_host, {}
+        scanned = host_s.pop("scanned_steps", 0)
+        if scanned and self.perf is not None:
+            # dispatch + readback is the epoch's wall as the host sees
+            # it; the readback is where the device's time shows
+            self.perf.timeline.observe_scan_epoch(
+                host_s.get("dispatch_s", 0.0) + host_s.get("readback_s", 0.0),
+                scanned, compute_s=host_s.get("readback_s", 0.0))
+        # host floats only (the emit path stays sync-free): where the
+        # epoch's host time went -- plan_s / dispatch_s / log_s on the
+        # scanned path, readback_s (device wait) and callbacks_s on both
         telemetry.emit("epoch_end", epoch=self.current_epoch,
-                       step=self.global_step)
+                       step=self.global_step,
+                       **{k: round(v, 6) for k, v in host_s.items()})
         if self.enable_progress_bar:
             log.warning("epoch %d done (step %d) metrics=%s",
                         self.current_epoch, self.global_step,
@@ -2859,6 +2887,20 @@ class Trainer:
         module.on_validation_epoch_end()
         for c in self.callbacks:
             c.on_validation_end(self, module)
+
+    @contextmanager
+    def _epoch_span(self, name: str, key: str):
+        """One host phase of an epoch: a ``fit/<name>`` profiler span
+        (a null context without a profiler) and, always, one
+        perf_counter pair summed into the ``epoch_end`` event's
+        ``<key>`` field."""
+        t0 = time.perf_counter()
+        try:
+            with self._span("fit/" + name):
+                yield
+        finally:
+            self._epoch_host[key] = (self._epoch_host.get(key, 0.0)
+                                     + time.perf_counter() - t0)
 
     def _span(self, name: str, phase: Optional[str] = None):
         """Profiler span, or a null context when no profiler is attached
@@ -3223,13 +3265,22 @@ class Trainer:
         """Full release: compiled functions + device state (so a fresh fit
         can run in the same process) AND the persistent fan-out world --
         the reference's teardown ends its actors too
-        (ray_ddp.py:109-121)."""
+        (ray_ddp.py:109-121).  The programs are retired to the scope
+        registry (telemetry/scopes.py), which from here on holds the
+        last-called one of each name, with abstract arguments only, so
+        that its text can be asked for afterwards;
+        ``telemetry.scopes.clear()`` lets go of those too.  A Trainer
+        dropped without teardown takes its programs with it."""
         self._release_compiled_state()
         self.shutdown_workers()
 
     def _release_compiled_state(self) -> None:
         """Device-state half of teardown(), used by _strip_for_shipment --
         which must NOT end the world it just acquired."""
+        for program in (self._train_step_fn, self._train_step_cached_fn,
+                        self._epoch_scan_fn):
+            if program is not None:
+                program.retire()
         self._train_step_fn = None
         self._eval_step_fn = None
         self._test_step_fn = None

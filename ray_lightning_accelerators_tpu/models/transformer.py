@@ -35,6 +35,7 @@ from ..ops.attention import flash_attention
 from ..ops.moe import init_moe_params, moe_logical_axes, moe_mlp
 from ..ops.norms import rms_norm
 from ..utils.logging import log
+from ..utils.scope import scoped
 
 
 @dataclasses.dataclass
@@ -339,6 +340,7 @@ class GPT(TpuModule):
                 x, self.mesh, jax.sharding.PartitionSpec(*spec))
         return x
 
+    @scoped("gpt/embed")
     def _embed_lookup(self, params, tokens):
         """Token ids -> embedding rows, [*, d].
 
@@ -367,6 +369,7 @@ class GPT(TpuModule):
         onehot = jax.nn.one_hot(tokens, self.cfg.vocab_size, dtype=dt)
         return jnp.einsum("...v,vd->...d", onehot, self._wt(w, dt))
 
+    @scoped("gpt/norm")
     def _rms_norm(self, x, scale):
         # fused pallas kernel on TPU, jnp reference elsewhere
         # (ops/norms.py); per-shard on a multi-device mesh, where x is
@@ -431,69 +434,77 @@ class GPT(TpuModule):
         dt = self.compute_dtype
         a = layer_params["attn"]
         x = self._rms_norm(h, layer_params["ln1"])
-        q = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wq"], dt))
-        k = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wk"], dt))
-        v = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wv"], dt))
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        q = self._constrain(q, mesh_lib.BATCH_AXES, mesh_lib.TENSOR_AXIS,
-                            mesh_lib.SEQUENCE_AXIS, None)
-        # GQA may leave fewer kv heads than the tensor axis can divide;
-        # replicate kv over tensor in that case instead of crashing the
-        # sharding constraint
-        t_size = (mesh_lib.mesh_axis_size(self.mesh, mesh_lib.TENSOR_AXIS)
-                  if self.mesh is not None else 1)
-        kv_axis = (mesh_lib.TENSOR_AXIS
-                   if t_size <= 1 or cfg.kv_heads % t_size == 0 else None)
-        k = self._constrain(k, mesh_lib.BATCH_AXES, kv_axis,
-                            mesh_lib.SEQUENCE_AXIS, None)
-        v = self._constrain(v, mesh_lib.BATCH_AXES, kv_axis,
-                            mesh_lib.SEQUENCE_AXIS, None)
-        groups = cfg.n_heads // cfg.kv_heads
-        if groups > 1:  # GQA: broadcast each KV head over its query group
-            kr = jnp.repeat(k, groups, axis=1)
-            vr = jnp.repeat(v, groups, axis=1)
-        else:
-            kr, vr = k, v
-        attn = self._attention(q, kr, vr)
-        attn_out = jnp.einsum("bhsk,hkd->bsd", attn, self._wt(a["wo"], dt))
-        if dropout_rng is not None and cfg.dropout > 0:
-            dropout_rng, r_attn = jax.random.split(dropout_rng)
-            attn_out = self._dropout(attn_out, r_attn)
-        h = h + attn_out
+        with jax.named_scope("gpt/attn"):
+            q = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wq"], dt))
+            k = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wk"], dt))
+            v = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wv"], dt))
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+            q = self._constrain(q, mesh_lib.BATCH_AXES,
+                                mesh_lib.TENSOR_AXIS,
+                                mesh_lib.SEQUENCE_AXIS, None)
+            # GQA may leave fewer kv heads than the tensor axis can
+            # divide; replicate kv over tensor in that case instead of
+            # crashing the sharding constraint
+            t_size = (mesh_lib.mesh_axis_size(self.mesh,
+                                              mesh_lib.TENSOR_AXIS)
+                      if self.mesh is not None else 1)
+            kv_axis = (mesh_lib.TENSOR_AXIS
+                       if t_size <= 1 or cfg.kv_heads % t_size == 0
+                       else None)
+            k = self._constrain(k, mesh_lib.BATCH_AXES, kv_axis,
+                                mesh_lib.SEQUENCE_AXIS, None)
+            v = self._constrain(v, mesh_lib.BATCH_AXES, kv_axis,
+                                mesh_lib.SEQUENCE_AXIS, None)
+            groups = cfg.n_heads // cfg.kv_heads
+            if groups > 1:  # GQA: broadcast each KV head over its group
+                kr = jnp.repeat(k, groups, axis=1)
+                vr = jnp.repeat(v, groups, axis=1)
+            else:
+                kr, vr = k, v
+            attn = self._attention(q, kr, vr)
+            attn_out = jnp.einsum("bhsk,hkd->bsd", attn,
+                                  self._wt(a["wo"], dt))
+            if dropout_rng is not None and cfg.dropout > 0:
+                dropout_rng, r_attn = jax.random.split(dropout_rng)
+                attn_out = self._dropout(attn_out, r_attn)
+            h = h + attn_out
 
         x = self._rms_norm(h, layer_params["ln2"])
-        m = self._dequant_q8_leaves(layer_params["mlp"], dt)
-        if cfg.num_experts > 1:
-            y, aux = moe_mlp(x, m, top_k=cfg.moe_top_k,
-                             capacity_factor=cfg.moe_capacity_factor,
-                             compute_dtype=dt, mesh=self.mesh)
-        else:
-            aux = jnp.zeros((), jnp.float32)
-            up = jax.nn.gelu(self._mlp_train_matmul(x, m["wi"], dt))
-            up = self._constrain(up, mesh_lib.BATCH_AXES,
-                                 mesh_lib.SEQUENCE_AXIS,
-                                 mesh_lib.TENSOR_AXIS)
-            y = self._mlp_train_matmul(up, m["wo"], dt)
-        if dropout_rng is not None and cfg.dropout > 0:
-            y = self._dropout(y, dropout_rng)
-        h = h + y
-        h = self._constrain(h, mesh_lib.BATCH_AXES,
-                            mesh_lib.SEQUENCE_AXIS, None)
+        with jax.named_scope("gpt/mlp"):
+            m = self._dequant_q8_leaves(layer_params["mlp"], dt)
+            if cfg.num_experts > 1:
+                y, aux = moe_mlp(x, m, top_k=cfg.moe_top_k,
+                                 capacity_factor=cfg.moe_capacity_factor,
+                                 compute_dtype=dt, mesh=self.mesh)
+            else:
+                aux = jnp.zeros((), jnp.float32)
+                up = jax.nn.gelu(self._mlp_train_matmul(x, m["wi"], dt))
+                up = self._constrain(up, mesh_lib.BATCH_AXES,
+                                     mesh_lib.SEQUENCE_AXIS,
+                                     mesh_lib.TENSOR_AXIS)
+                y = self._mlp_train_matmul(up, m["wo"], dt)
+            if dropout_rng is not None and cfg.dropout > 0:
+                y = self._dropout(y, dropout_rng)
+            h = h + y
+            h = self._constrain(h, mesh_lib.BATCH_AXES,
+                                mesh_lib.SEQUENCE_AXIS, None)
         if return_kv:
             return h, aux, k, v
         return h, aux
 
-    def forward(self, params, batch, return_aux: bool = False,
-                return_hidden: bool = False, dropout_rng=None):
-        """``dropout_rng``: per-step PRNG key enabling dropout (train
-        mode); None (eval/decode) makes the forward deterministic."""
+    @staticmethod
+    def _tokens_of(batch):
         tokens = batch["input_ids"] if isinstance(batch, dict) else batch
         if isinstance(tokens, (tuple, list)):
             tokens = tokens[0]
+        return tokens
+
+    def _trunk(self, params, tokens, dropout_rng=None):
+        """Embedding and the layer stack, up to the final norm:
+        ``(hidden, moe aux loss)``."""
         if dropout_rng is not None and self.cfg.dropout <= 0:
             dropout_rng = None
-        dt = self.compute_dtype
         h = self._embed_lookup(params, tokens)
         h = self._constrain(h, mesh_lib.BATCH_AXES,
                             mesh_lib.SEQUENCE_AXIS, None)
@@ -528,8 +539,9 @@ class GPT(TpuModule):
                 if self.cfg.remat:
                     block_do = jax.checkpoint(block_do, policy=_remat_policy(
                         self.cfg.remat_policy))
-                (out, _), aux_per_layer = jax.lax.scan(
-                    block_do, (h_in, dropout_rng), layers)
+                with jax.named_scope("gpt/layers"):
+                    (out, _), aux_per_layer = jax.lax.scan(
+                        block_do, (h_in, dropout_rng), layers)
                 return out, jnp.sum(aux_per_layer)
 
             def block(carry, layer_params):
@@ -540,7 +552,8 @@ class GPT(TpuModule):
             if self.cfg.remat:
                 block = jax.checkpoint(block, policy=_remat_policy(
                     self.cfg.remat_policy))
-            out, aux_per_layer = jax.lax.scan(block, h_in, layers)
+            with jax.named_scope("gpt/layers"):
+                out, aux_per_layer = jax.lax.scan(block, h_in, layers)
             return out, jnp.sum(aux_per_layer)
 
         if self.mesh is not None and mesh_lib.mesh_axis_size(
@@ -560,11 +573,21 @@ class GPT(TpuModule):
             aux = jnp.zeros((), jnp.float32)
         else:
             h, aux = stack(h, params["layers"])
+        return h, aux
+
+    def _head(self, params, h):
+        """Final norm and LM head: f32 logits."""
         h = self._rms_norm(h, params["ln_f"])
-        if return_hidden:
-            return h, aux
-        logits = jnp.einsum("bsd,dv->bsv", h, self._unembed_w(params, dt))
-        logits = logits.astype(jnp.float32)
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            self._unembed_w(params, self.compute_dtype))
+        return logits.astype(jnp.float32)
+
+    def forward(self, params, batch, return_aux: bool = False,
+                dropout_rng=None):
+        """``dropout_rng``: per-step PRNG key enabling dropout (train
+        mode); None (eval/decode) makes the forward deterministic."""
+        h, aux = self._trunk(params, self._tokens_of(batch), dropout_rng)
+        logits = self._head(params, h)
         return (logits, aux) if return_aux else logits
 
     def _use_fused_loss(self) -> bool:
@@ -584,36 +607,37 @@ class GPT(TpuModule):
     # Steps                                                              #
     # ------------------------------------------------------------------ #
     def _lm_loss(self, params, batch, rng=None):
-        tokens = batch["input_ids"] if isinstance(batch, dict) else batch
-        if isinstance(tokens, (tuple, list)):
-            tokens = tokens[0]
+        """The head (final norm, LM head) is booked with the loss it
+        feeds: scope ``gpt/loss`` here, none in ``forward``."""
+        tokens = self._tokens_of(batch)
+        h, aux = self._trunk(params, tokens, rng)
         if self._use_fused_loss():
             from ..ops.losses import fused_linear_cross_entropy
-            h, aux = self.forward(params, tokens, return_hidden=True,
-                                  dropout_rng=rng)
-            d = h.shape[-1]
-            rows = h[:, :-1].reshape(-1, d)
-            targets = tokens[:, 1:].reshape(-1).astype(jnp.int32)
-            loss, acc = fused_linear_cross_entropy(
-                rows, self._unembed_w(params, self.compute_dtype),
-                targets, self.cfg.loss_chunk_rows, mesh=self.mesh,
-                label_smoothing=self.cfg.label_smoothing,
-                z_loss=self.cfg.z_loss)
+            with jax.named_scope("gpt/loss"):
+                h = self._rms_norm(h, params["ln_f"])
+                d = h.shape[-1]
+                rows = h[:, :-1].reshape(-1, d)
+                targets = tokens[:, 1:].reshape(-1).astype(jnp.int32)
+                loss, acc = fused_linear_cross_entropy(
+                    rows, self._unembed_w(params, self.compute_dtype),
+                    targets, self.cfg.loss_chunk_rows, mesh=self.mesh,
+                    label_smoothing=self.cfg.label_smoothing,
+                    z_loss=self.cfg.z_loss)
             return loss, acc, aux
-        logits, aux = self.forward(params, tokens, return_aux=True,
-                                   dropout_rng=rng)
-        logits, targets = logits[:, :-1], tokens[:, 1:]
-        eps, zl = self.cfg.label_smoothing, self.cfg.z_loss
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt_logit = jnp.take_along_axis(logits, targets[..., None],
-                                        axis=-1)[..., 0]
-        loss = lse - (1.0 - eps) * tgt_logit
-        if eps:
-            loss -= (eps / logits.shape[-1]) * jnp.sum(logits, -1)
-        if zl:
-            loss += zl * lse * lse
-        loss = loss.mean()
-        acc = jnp.mean(jnp.argmax(logits, -1) == targets)
+        with jax.named_scope("gpt/loss"):
+            logits = self._head(params, h)
+            logits, targets = logits[:, :-1], tokens[:, 1:]
+            eps, zl = self.cfg.label_smoothing, self.cfg.z_loss
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            tgt_logit = jnp.take_along_axis(logits, targets[..., None],
+                                            axis=-1)[..., 0]
+            loss = lse - (1.0 - eps) * tgt_logit
+            if eps:
+                loss -= (eps / logits.shape[-1]) * jnp.sum(logits, -1)
+            if zl:
+                loss += zl * lse * lse
+            loss = loss.mean()
+            acc = jnp.mean(jnp.argmax(logits, -1) == targets)
         return loss, acc, aux
 
     def training_step(self, params, batch, rng):

@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..analysis import knobs
+from ..utils.scope import scoped
 
 _NEG_INF = -1e30
 
@@ -130,6 +131,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[...] = jnp.transpose(lse, (1, 0))[None]
 
 
+@scoped("kernel/flash_fwd")
 def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
                    causal: bool, block_q: int, block_k: int,
                    interpret: bool, window: Optional[int] = None):
@@ -170,6 +172,7 @@ def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
             # the online-softmax state
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
 
 
@@ -334,12 +337,15 @@ def _flash_backward_fused(q3, k3, v3, g3, lse, delta, scale, causal,
             # the q walk carries the dk/dv accumulators
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q3, k3, v3, g3, lse, delta)
 
 
+@scoped("kernel/flash_bwd")
 def _flash_backward(q3, k3, v3, o3, lse, g3, scale, causal, block_q,
                     block_k, interpret, window=None):
-    """dq, dk, dv for folded [bh, seq, d] operands."""
+    """dq, dk, dv for folded [bh, seq, d] operands (the scope holds the
+    delta row-sum pre-pass with the kernels it feeds)."""
     bh, q_len, d = q3.shape
     k_len = k3.shape[1]
     # delta_i = rowsum(dO * O): tiny elementwise pass in XLA
@@ -364,6 +370,7 @@ def _flash_backward(q3, k3, v3, o3, lse, g3, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, g3, lse, delta)
     # dkv walks q inside k: swap the roles of the two inner grid dims
     qspec_t = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
@@ -381,6 +388,7 @@ def _flash_backward(q3, k3, v3, o3, lse, g3, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, g3, lse, delta)
     return dq, dk, dv
 

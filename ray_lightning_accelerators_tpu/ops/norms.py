@@ -78,7 +78,8 @@ def _row_block(rows: int) -> Optional[int]:
     return rows if rows <= _MAX_WHOLE_ROWS else None
 
 
-def _norm_call(kernel, x2: jax.Array, params, eps: float, interpret: bool):
+def _norm_call(kernel, x2: jax.Array, params, eps: float, interpret: bool,
+               name: str):
     rows, d = x2.shape
     br = _row_block(rows)
     in_specs = [pl.BlockSpec((br, d), lambda i: (i, 0))]
@@ -91,6 +92,7 @@ def _norm_call(kernel, x2: jax.Array, params, eps: float, interpret: bool):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name=name,
     )(x2, *[p.reshape(1, d) for p in params])
 
 
@@ -113,7 +115,9 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     if not _use_pallas(x):
         return rms_norm_reference(x, scale, eps)
     x2 = x.reshape(-1, d)
-    out = _norm_call(_rms_kernel, x2, (scale,), eps, interpret=False)
+    with jax.named_scope("kernel/rms_norm"):
+        out = _norm_call(_rms_kernel, x2, (scale,), eps, interpret=False,
+                         name="rms_norm")
     return out.reshape(x.shape)
 
 
@@ -138,7 +142,8 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     if not _use_pallas(x):
         return layer_norm_reference(x, scale, bias, eps)
     x2 = x.reshape(-1, d)
-    out = _norm_call(_ln_kernel, x2, (scale, bias), eps, interpret=False)
+    out = _norm_call(_ln_kernel, x2, (scale, bias), eps, interpret=False,
+                     name="layer_norm")
     return out.reshape(x.shape)
 
 
@@ -160,11 +165,12 @@ layer_norm.defvjp(_ln_fwd, _ln_bwd)
 # interpreter-mode entries (CPU correctness tests for the kernels)
 def rms_norm_interpret(x, scale, eps: float = 1e-6):
     d = x.shape[-1]
-    return _norm_call(_rms_kernel, x.reshape(-1, d), (scale,), eps,
-                      interpret=True).reshape(x.shape)
+    with jax.named_scope("kernel/rms_norm"):
+        return _norm_call(_rms_kernel, x.reshape(-1, d), (scale,), eps,
+                          interpret=True, name="rms_norm").reshape(x.shape)
 
 
 def layer_norm_interpret(x, scale, bias, eps: float = 1e-6):
     d = x.shape[-1]
     return _norm_call(_ln_kernel, x.reshape(-1, d), (scale, bias), eps,
-                      interpret=True).reshape(x.shape)
+                      interpret=True, name="layer_norm").reshape(x.shape)

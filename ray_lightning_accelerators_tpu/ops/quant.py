@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _pick_block
+from ..utils.scope import scoped
 
 # the kernels take the whole M dimension per grid cell: the f32
 # accumulator scratch [M, bn] + the [M, bk] input block must fit VMEM
@@ -122,6 +123,7 @@ def _check_supported(fn: str, m: int, k: int, n: int) -> None:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@scoped("kernel/q8_matmul")
 def int8_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
                 interpret: bool = False) -> jax.Array:
     """x [M,K] (bf16/f32) @ dequant(wq [K,N] int8, scale [N]) -> [M,N].
@@ -157,10 +159,12 @@ def int8_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q8_matmul",
     )(x, wq, s2)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@scoped("kernel/q8_matmul")
 def int8_matmul_nt(x: jax.Array, wq: jax.Array,
                    interpret: bool = False) -> jax.Array:
     """x [M,K] @ wq[N,K]^T -> [M,N], weight int8, no scale (fold
@@ -188,4 +192,5 @@ def int8_matmul_nt(x: jax.Array, wq: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q8_matmul_nt",
     )(x, wq)

@@ -9,7 +9,10 @@ export, and crash postmortem reports.
 - :mod:`.perf` — the perf observatory: :class:`StepTimeline` (per-step
   phase decomposition), :class:`HbmLedger` (per-pool HBM attribution +
   leak alarm) and :class:`GoodputLedger` (run-level wall-time
-  partition), exported through the registry.
+  partition), exported through the registry;
+- :mod:`.scopes` — the scope table: the named scopes the program's parts
+  carry, and how to ask a registered program for its compiled text and
+  its instructions' op names.
 
 See docs/API.md "Telemetry & tracing" / "Perf observatory" for event
 kinds, phase/pool vocabularies, export formats and the report schema.
@@ -25,6 +28,7 @@ from .recorder import (EMBED_TAIL_N, EVENT_KINDS, FlightRecorder,
                        configure, current_rank, current_trace_id, emit,
                        get_recorder, mint_trace_id, read_spill,
                        set_trace_id, spill_path_for, tail_events)
+from . import scopes
 from .registry import (MetricsRegistry, build_run_report,
                        gather_spill_dir, gather_worker_tails,
                        probe_snapshot_record, write_run_report)
@@ -40,4 +44,5 @@ __all__ = [
     "PHASE_KINDS", "GOODPUT_CATEGORIES", "exposed_comm_crosscheck",
     "tree_nbytes", "placed_bytes_total",
     "TelemetryServer", "LiveSources", "ClusterView", "classify_health",
+    "scopes",
 ]

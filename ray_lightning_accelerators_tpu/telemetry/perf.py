@@ -236,22 +236,40 @@ class StepTimeline:
             if len(self._recent) > self.ring_capacity:
                 del self._recent[0]
 
-    def observe_scan_epoch(self, wall_s: float, n_steps: int) -> None:
+    def observe_scan_epoch(self, wall_s: float, n_steps: int,
+                           compute_s: Optional[float] = None) -> None:
         """The scanned-epoch path is ONE dispatch for a whole epoch —
-        per-step phases do not exist there, so the epoch's wall is
-        attributed to ``compute`` across ``n_steps`` equal steps (one
-        coarse ring row marks the batch)."""
+        per-step phases do not exist there, so the epoch is one coarse
+        ring row of ``n_steps`` equal steps.  ``wall_s`` is dispatch +
+        readback as the host saw them; ``compute_s`` the part spent
+        waiting in the readback, which is where the device's time shows
+        (the dispatch itself returns at once), and the rest is booked as
+        ``other`` -- less what was compiled since the last boundary (a
+        first epoch's dispatch holds the program's compile), which is
+        ``compile`` here as in ``step_end``.  Without ``compute_s`` the
+        whole wall is compute."""
         n = max(1, int(n_steps))
+        compute = wall_s if compute_s is None else min(compute_s, wall_s)
+        phases = {"compute": compute, "other": wall_s - compute}
+        if self._compile_fn is not None:
+            seen, self._compile_at_begin = (self._compile_at_begin,
+                                            self._compile_fn())
+            phases["compile"] = min(max(self._compile_at_begin - seen, 0.0),
+                                    phases["other"])
+            phases["other"] -= phases["compile"]
+        phases = {k: v for k, v in phases.items()
+                  if v > 0 or k == "compute"}
         with self._lock:
             self._steps += n
             self._wall_total += wall_s
-            row = self._phases.setdefault("compute", [0, 0.0])
-            row[0] += n
-            row[1] += wall_s
+            for name, dt in phases.items():
+                row = self._phases.setdefault(name, [0, 0.0])
+                row[0] += n
+                row[1] += dt
             self._recent.append(
                 {"step": self._steps, "wall_s": round(wall_s, 6),
                  "scanned_steps": n,
-                 "phases": {"compute": round(wall_s, 6)}})
+                 "phases": {k: round(v, 6) for k, v in phases.items()}})
             if len(self._recent) > self.ring_capacity:
                 del self._recent[0]
 
@@ -497,6 +515,8 @@ class GoodputLedger:
                   "ckpt": "checkpoint", "drain": "drain",
                   "validation": "productive"}
     _SPAN_MAP = {"train_step": "productive", "h2d": "productive",
+                 "epoch_dispatch": "productive",
+                 "epoch_readback": "productive",
                  "data_fetch": "productive", "validation": "productive",
                  "ckpt": "checkpoint", "drain": "drain"}
 

@@ -10,11 +10,15 @@ Three layers:
 
 - **Span timing** (`Profiler.span`): nested host-side wall-clock spans with
   a thread-local stack.  Each span also opens a
-  ``jax.profiler.TraceAnnotation`` so the same names line up inside
-  TensorBoard/XProf device traces.  ``sync=True`` spans block on device work
-  (``jax.block_until_ready``) so step spans measure real compute.
+  ``jax.profiler.TraceAnnotation`` named ``rla:<nested path>`` so the
+  same names line up inside TensorBoard/XProf device traces and a trace
+  reader keeps the program's spans with one prefix test.  ``sync=True``
+  spans block on device work (``jax.block_until_ready``) so step spans
+  measure real compute.
 - **Device traces** (`start_trace`/`stop_trace`): wraps ``jax.profiler`` to
-  dump an XPlane/TensorBoard trace directory.
+  dump an XPlane/TensorBoard trace directory, with ``scopes.json`` (the
+  registered programs' instruction -> named-scope tables,
+  telemetry/scopes.py) beside it.
 - **Device memory** (`device_memory_stats`): PjRt per-device HBM counters.
 
 The Trainer takes ``profiler=`` and wraps its hot phases
@@ -29,6 +33,10 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+# every host span the program writes into a profiler trace starts with this
+TRACE_PREFIX = "rla:"
+SCOPES_FILE = "scopes.json"
 
 
 class _SpanHandle:
@@ -154,7 +162,7 @@ class Profiler:
         stack.append(name)
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation(name):
+            with jax.profiler.TraceAnnotation(TRACE_PREFIX + full):
                 yield handle
                 if self.sync and handle.value is not None:
                     # graftlint: ok(host-sync) — opt-in sync=True mode:
@@ -371,12 +379,17 @@ class Profiler:
         self._trace_dir = log_dir
 
     def stop_trace(self) -> Optional[str]:
+        """End the trace and write ``scopes.json`` beside it: every
+        registered program's instruction -> op-name table
+        (telemetry/scopes.py), which is what ``trace_op_summary`` needs
+        to tell forward from backward from optimizer."""
         import jax
 
         if self._trace_dir is None:
             return None
         jax.profiler.stop_trace()
         d, self._trace_dir = self._trace_dir, None
+        _write_scope_tables(d)
         return d
 
     @contextmanager
@@ -386,6 +399,24 @@ class Profiler:
             yield
         finally:
             self.stop_trace()
+
+
+def _write_scope_tables(trace_dir: str) -> None:
+    """``<trace_dir>/scopes.json``: ``{program: {instruction: op_name}}``.
+    Never fails the trace it annotates."""
+    import json
+    import logging
+    import os
+
+    from ..telemetry import scopes
+    try:
+        tables = {name: scopes.scope_table(name)
+                  for name in scopes.registered()}
+        with open(os.path.join(trace_dir, SCOPES_FILE), "w") as f:
+            json.dump(tables, f)
+    except Exception as e:  # a table is an annotation, not the trace
+        logging.getLogger(__name__).warning(
+            "no %s beside the trace in %s: %s", SCOPES_FILE, trace_dir, e)
 
 
 def trace_events(trace_dir: str) -> List[Dict[str, Any]]:
@@ -438,7 +469,12 @@ def trace_op_summary(trace_dir: str, top: int = 0) -> Dict[str, Any]:
     Nested events (``while`` bodies, fusions inside scans) are resolved
     by interval containment, so a scan's children are not double-counted
     against their parent.  Returns ``{"total_ms", "by_category":
-    {cat: {self_ms, gbps, tfs, pct}}, "ops": [top-N rows]}``."""
+    {cat: {self_ms, gbps, tfs, pct}}, "ops": [top-N rows]}``.  Where
+    ``Profiler.stop_trace`` left a ``scopes.json`` beside the trace,
+    each op row also has ``"scope"``: the op name (named-scope stack,
+    under JAX's ``jvp(`` / ``transpose(`` wrappers) of its instruction
+    in ``"scope_program"``, the registered program that knows the most
+    of the trace's time (``%fusion.3`` exists in more than one)."""
     evs = trace_events(trace_dir)
     # stack-based nesting, one stack PER DEVICE (pid): concurrent chips
     # overlap in time without any parent/child relation, but within one
@@ -487,8 +523,41 @@ def trace_op_summary(trace_dir: str, top: int = 0) -> Dict[str, Any]:
             "pct": 100.0 * dur / total_us if total_us else 0.0,
             **rates(dur, b, fl)}
            for (cat, name), (dur, n, b, fl) in rows]
-    return {"total_ms": total_us / 1e3, "by_category": by_category,
-            "ops": ops}
+    out = {"total_ms": total_us / 1e3, "by_category": by_category,
+           "ops": ops}
+    program, table = _scope_table(trace_dir, agg)
+    if program is not None:
+        out["scope_program"] = program
+        for op in ops:
+            op["scope"] = table.get(_instruction(op["name"]), "")
+    return out
+
+
+def _instruction(event_name: str) -> str:
+    """``%fusion.3`` of a trace event named ``%fusion.3 = ...`` (an
+    ``.xplane.pb`` names an op by its whole text) or ``fusion.3``."""
+    name = event_name.partition(" = ")[0]
+    return name if name.startswith("%") else "%" + name
+
+
+def _scope_table(trace_dir: str, agg) -> tuple:
+    """``(program, {instruction: op_name})`` of the table in
+    ``scopes.json`` that knows the most of the trace's time;
+    ``(None, {})`` without the file."""
+    import json
+    import os
+
+    path = os.path.join(trace_dir, SCOPES_FILE)
+    if not os.path.exists(path):
+        return None, {}
+    with open(path) as f:
+        tables = json.load(f)
+
+    def known_us(table):
+        return sum(row[0] for (_cat, name), row in agg.items()
+                   if _instruction(name) in table)
+    program = max(tables, key=lambda p: known_us(tables[p]), default=None)
+    return program, tables.get(program, {})
 
 
 def device_memory_stats() -> List[Dict[str, Any]]:

@@ -96,17 +96,24 @@ def test_trainer_profiler_integration():
 
 
 def test_trainer_profiler_integration_cached_path():
-    # device-cached path: train_step spans only (no per-batch h2d)
+    # device-cached path: a profiler never changes which program runs --
+    # the whole-epoch scan stays, with one span per host phase of the
+    # epoch instead of per-step spans
     prof = Profiler()
     train, val = boring_loaders()
-    trainer = Trainer(max_epochs=1, accelerator=RayTPUAccelerator(),
+    trainer = Trainer(max_epochs=2, accelerator=RayTPUAccelerator(),
                       precision="f32", enable_checkpointing=False,
                       profiler=prof, log_every_n_steps=10 ** 9, seed=0,
                       cache_dataset_on_device=True)
     trainer.fit(BoringModel(), train, val)
+    assert trainer._can_scan_epoch()
     s = prof.summary()
-    assert s["train_step"]["count"] == trainer.global_step > 0
-    assert "h2d" not in s
+    assert s["fit/epoch_dispatch"]["count"] == 2    # once per epoch
+    assert s["fit/epoch_plan"]["count"] == 2
+    assert s["fit/callbacks"]["count"] == 2
+    assert s["validation"]["count"] == 2
+    assert trainer.global_step > 0
+    assert "train_step" not in s and "h2d" not in s
 
 
 def test_device_trace_roundtrip(tmp_path):

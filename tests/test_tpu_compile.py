@@ -93,13 +93,13 @@ def test_flash_forward_compiles(one_chip, chip_dispatch, block):
     fwd = jax.jit(functools.partial(flash_attention, causal=True,
                                     block_q=block, block_k=block))
     lowered = fwd.lower(q, q, q)
-    assert _kernels(lowered) == ["_flash_kernel"]
+    assert _kernels(lowered) == ["flash_fwd"]
     lowered.compile()
 
 
 @pytest.mark.parametrize("block,bwd_kernels", [
-    (512, ["_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"]),
-    (1024, ["_flash_bwd_fused_kernel"]),  # k_len == block_k: one pass
+    (512, ["flash_bwd_dkv", "flash_bwd_dq"]),
+    (1024, ["flash_bwd_fused"]),  # k_len == block_k: one pass
 ])
 def test_flash_backward_compiles(one_chip, chip_dispatch, block,
                                  bwd_kernels):
@@ -110,7 +110,7 @@ def test_flash_backward_compiles(one_chip, chip_dispatch, block,
         return out.astype(jnp.float32).sum()
 
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
-    assert _kernels(lowered) == sorted(["_flash_kernel"] + bwd_kernels)
+    assert _kernels(lowered) == sorted(["flash_fwd"] + bwd_kernels)
     lowered.compile()
 
 
@@ -118,10 +118,10 @@ def test_norm_kernels_compile(one_chip, chip_dispatch):
     x = _sds((8192, 768), jnp.bfloat16, one_chip)
     s = _sds((768,), jnp.float32, one_chip)
     rms = jax.jit(rms_norm).lower(x, s)
-    assert _kernels(rms) == ["_rms_kernel"]
+    assert _kernels(rms) == ["rms_norm"]
     rms.compile()
     ln = jax.jit(layer_norm).lower(x, s, s)
-    assert _kernels(ln) == ["_ln_kernel"]
+    assert _kernels(ln) == ["layer_norm"]
     ln.compile()
 
 
@@ -134,7 +134,7 @@ def test_rms_norm_compiles_at_row_counts_off_the_sublane_tile(
     x = _sds((1, rows, 768), jnp.bfloat16, one_chip)
     s = _sds((768,), jnp.float32, one_chip)
     lowered = jax.jit(rms_norm).lower(x, s)
-    assert _kernels(lowered) == ["_rms_kernel"]
+    assert _kernels(lowered) == ["rms_norm"]
     lowered.compile()
 
 
@@ -145,7 +145,7 @@ def test_int8_matmul_decode_shapes_compile(one_chip, k, n):
     wq = _sds((k, n), jnp.int8, one_chip)
     scale = _sds((n,), jnp.float32, one_chip)
     lowered = quant.int8_matmul.lower(x, wq, scale)
-    assert _kernels(lowered) == ["_mm_kernel"]
+    assert _kernels(lowered) == ["q8_matmul"]
     lowered.compile()
 
 
@@ -154,7 +154,7 @@ def test_int8_matmul_nt_unembed_compiles(one_chip):
     x = _sds((16, 768), jnp.bfloat16, one_chip)
     wq = _sds((50304, 768), jnp.int8, one_chip)
     lowered = quant.int8_matmul_nt.lower(x, wq)
-    assert _kernels(lowered) == ["_mm_nt_kernel"]
+    assert _kernels(lowered) == ["q8_matmul_nt"]
     lowered.compile()
 
 
@@ -205,7 +205,7 @@ def _per_device_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-_STEP_KERNELS = ["_flash_bwd_fused_kernel", "_flash_kernel", "_rms_kernel"]
+_STEP_KERNELS = ["flash_bwd_fused", "flash_fwd", "rms_norm"]
 
 
 def test_single_chip_train_step_compiles(topo, chip_dispatch):
@@ -278,7 +278,7 @@ def test_paged_serve_programs_compile(one_chip, gpt_bf16):
 
     B, M = engine.max_slots, engine.table_blocks
     step = engine._step.lower(params, pool, i32(B, M), i32(B), i32(B))
-    assert _kernels(step) == ["_rms_kernel"]
+    assert _kernels(step) == ["rms_norm"]
     # donated: the pool is aliased to the output, not copied (>=: the
     # chip's tiled layout pads the [.., block_len, head_dim] minor dims)
     compiled = step.compile()
@@ -304,6 +304,6 @@ def test_int8_generate_compiles_on_the_kernel_path(one_chip, gpt_bf16):
                                     temperature=0.0))
     lowered = gen.lower(q8, _sds((16, 128), jnp.int32, one_chip))
     assert GPT._q8_declined_shapes == declined
-    assert _kernels(lowered) == ["_flash_kernel", "_mm_kernel",
-                                 "_mm_nt_kernel", "_rms_kernel"]
+    assert _kernels(lowered) == ["flash_fwd", "q8_matmul",
+                                 "q8_matmul_nt", "rms_norm"]
     lowered.compile()
