@@ -18,6 +18,9 @@ prints no result:
               matmuls) against their references at the model's widths;
               the lowered public op must hold the kernel's TPU custom
               call, so a dispatch that slid to the reference cannot pass.
+              Then the benchmark cells' own flash shape (64 heads of
+              [1024, 64], one 1024^2 causal block): checked, and the
+              forward's and backward's microseconds per head printed.
 3. *train*    ``Trainer.fit(GPT, DataLoader)``: finite, falling loss,
               zero compiles in the second epoch, the flash forward and
               backward kernels in the train step's lowering, peak HBM.
@@ -178,22 +181,16 @@ def phase_device(chips: int) -> dict:
     return device
 
 
-# --------------------------------------------------------------------- #
-# 2. kernels                                                             #
-# --------------------------------------------------------------------- #
-def phase_kernels(size: Size, seed: int) -> None:
+def _check_flash(shape, blocks, keys):
+    """Compiled causal flash attention, forward and ``jax.grad``, against
+    the f32 reference at ``shape`` under each grid block.  Returns the
+    bf16 (q, k, v) and the f32 cotangent it drew."""
     import jax
     import jax.numpy as jnp
 
-    from ray_lightning_accelerators_tpu.ops import quant
     from ray_lightning_accelerators_tpu.ops.attention import (
         attention_reference, flash_attention)
-    from ray_lightning_accelerators_tpu.ops.norms import (
-        layer_norm, layer_norm_reference, rms_norm, rms_norm_reference)
 
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
-    head_dim = size.d_model // size.n_heads
-    shape = (max(1, size.batch // 2), size.n_heads, size.seq, head_dim)
     q, k, v = (jax.random.normal(next(keys), shape, jnp.bfloat16)
                for _ in range(3))
     g = jax.random.normal(next(keys), shape, jnp.float32)
@@ -207,7 +204,7 @@ def phase_kernels(size: Size, seed: int) -> None:
                                             causal=True))(*f32)
         ref_grads = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(*f32)
 
-    for block in sorted({min(512, size.seq), size.flash_block}):
+    for block in blocks:
         fwd = jax.jit(functools.partial(flash_attention, causal=True,
                                         block_q=block, block_k=block))
 
@@ -230,6 +227,24 @@ def phase_kernels(size: Size, seed: int) -> None:
         require(err_fwd <= TOL_FLASH_FWD, f"flash fwd error {err_fwd}")
         require(max(err_bwd) <= TOL_FLASH_GRAD,
                 f"flash grad error {err_bwd}")
+    return q, k, v, g
+
+
+# --------------------------------------------------------------------- #
+# 2. kernels                                                             #
+# --------------------------------------------------------------------- #
+def phase_kernels(size: Size, seed: int) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_accelerators_tpu.ops import quant
+    from ray_lightning_accelerators_tpu.ops.norms import (
+        layer_norm, layer_norm_reference, rms_norm, rms_norm_reference)
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    head_dim = size.d_model // size.n_heads
+    _check_flash((max(1, size.batch // 2), size.n_heads, size.seq, head_dim),
+                 sorted({min(512, size.seq), size.flash_block}), keys)
 
     rows = size.batch * size.seq // 2
     x = jax.random.normal(next(keys), (rows, size.d_model),
@@ -273,6 +288,49 @@ def phase_kernels(size: Size, seed: int) -> None:
     emit("kernels", kernel="int8_matmul_nt", shape=[m, d, size.vocab_size],
          err=err)
     require(err <= TOL_INT8, f"int8_matmul_nt error {err}")
+
+
+def phase_flash_cell_shape(seed: int, iters: int = 100) -> None:
+    """The benchmark cells' kernel: batch x heads = 64 (``gpt2-medium``
+    at batch 4) heads of [1024, 64] in bf16 under one 1024^2 causal
+    block.  Checked like the others, then timed: device time per head of
+    the forward kernel and of the backward (delta pre-pass and fused
+    kernel), from ``iters`` chained calls in one program."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_accelerators_tpu.ops.attention import (
+        causal_tiles, flash_attention)
+
+    shape, block = (4, 16, 1024, 64), 1024
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 4))
+    q, k, v, g = _check_flash(shape, [block], keys)
+    attend = functools.partial(flash_attention, causal=True, block_q=block,
+                               block_k=block)
+
+    g = g.astype(q.dtype)
+    # each call's output is the next call's q: nothing hoists or folds
+    chains = {
+        "fwd": lambda q: attend(q, k, v),
+        "fwd_bwd": lambda q: jax.vjp(attend, q, k, v)[1](g)[0],
+    }
+    seconds = {}
+    for name, step in chains.items():
+        chain = jax.jit(lambda q, step=step: jax.lax.fori_loop(
+            0, iters, lambda _, x: step(x), q))
+        chain(q).block_until_ready()
+        runs = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            chain(q).block_until_ready()
+            runs.append(time.perf_counter() - t0)
+        seconds[name] = min(runs)
+    per_head = 1e6 / (iters * shape[0] * shape[1])
+    visited, total = causal_tiles(shape[2], shape[2], block, block, True)
+    emit("flash_cell_shape", shape=list(shape), block=block,
+         tiles_visited=visited, tiles_total=total,
+         fwd_us_per_head=seconds["fwd"] * per_head,
+         bwd_us_per_head=(seconds["fwd_bwd"] - seconds["fwd"]) * per_head)
 
 
 # --------------------------------------------------------------------- #
@@ -676,6 +734,7 @@ def main(argv=None) -> None:
         phase_four_chip(size, args.seed)
     else:
         phase_kernels(size, args.seed)
+        phase_flash_cell_shape(args.seed)
         phase_train(size, args.seed)
         model, params = phase_generate(size, args.seed)
         phase_serve(size, args.seed, model, params)

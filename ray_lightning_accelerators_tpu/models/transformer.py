@@ -85,11 +85,12 @@ class TransformerConfig:
     # combinable with a sharded sequence axis (ring/Ulysses are full-
     # attention strategies)
     sliding_window: Optional[int] = None
-    # flash-attention kernel block sizes; None = ops/attention.py default
-    # (512, env-overridable).  At seq 1024 on v5e-class chips 1024x1024
-    # measured fastest in the (since deleted) pre-PR-1 traces:
-    # per-grid-cell overhead beat the causal block-skipping that smaller
-    # blocks enable.  To be re-measured (ROADMAP S3).
+    # flash-attention grid blocks; None = ops/attention.py default (512,
+    # env-overridable).  A block that holds the whole sequence is the fast
+    # one on the v5e: no k-walk carry, and the kernels walk only its causal
+    # triangle.  At seq 1024, heads of 64, us a head forward / backward:
+    # 2.5 / 4.8 with 1024 against 5.4 / 5.0 with 512 (PERF.md section 6,
+    # PR 26); both benchmark cells set 1024.
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
 

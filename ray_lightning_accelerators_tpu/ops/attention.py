@@ -4,17 +4,25 @@ The reference has no attention op (it delegates all compute to the user's
 torch model); this framework ships transformer models, and attention is the
 hot op, so it gets a hand-written TPU kernel:
 
-- online-softmax flash attention with large (512) q/kv blocks -- attention
-  at transformer shapes is HBM-traffic-bound, so fewer k/v reloads beat
-  MXU-sized 128 tiles; bf16 operands feed the MXU directly with f32
-  accumulation, and the forward also emits per-row log-sum-exp for the
-  backward;
-- causal masking with whole-block skipping (blocks strictly above the
-  diagonal do no MXU work);
+- flash attention over (block_q, block_k) grid blocks, 512 unless the
+  caller or ``RLA_TPU_FLASH_BLOCK_Q/K`` says otherwise: bf16 operands feed
+  the MXU directly with f32 accumulation, row reductions fold the lane
+  tiles on the VPU before the one cross-lane reduce, and the forward also
+  emits per-row log-sum-exp for the backward.  At head width 64 the
+  kernels are bound by the MXU (both matmuls half-fill it) and by
+  per-block overheads, not by HBM traffic (PERF.md, PR 26);
+- a key length of several blocks runs the k-walk (online softmax carried
+  in scratch) with whole-block causal skipping: blocks strictly above the
+  diagonal do no MXU work;
+- a key length of one block runs without a carry, and where that block is
+  the whole causal square (block == sequence) forward and backward walk
+  only its causal triangle, in strips of sub-tiles (``_diag_tile``,
+  ``causal_tiles``);
 - hand-written backward kernels (``jax.custom_vjp``): a dq pass and a
-  dk/dv pass recompute score blocks from q/k and the saved lse in
-  TRANSPOSED [block_k, block_q] space (per-query rows broadcast along
-  lanes), never materializing [S, S] in HBM.
+  dk/dv pass -- or one fused pass when the key length is one block --
+  recompute score blocks from q/k and the saved lse in TRANSPOSED
+  [block_k, block_q] space (per-query rows broadcast along lanes), never
+  materializing [S, S] in HBM.
 
 On non-TPU backends (tests on the virtual CPU mesh), dispatch falls back to
 a reference jnp implementation with identical semantics.
@@ -65,12 +73,175 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # --------------------------------------------------------------------- #
-# Pallas kernel                                                         #
+# Which scores a block computes                                          #
 # --------------------------------------------------------------------- #
+# A walk lists, statically, what a kernel computes of one grid block:
+#   [(query rows, [(key rows, mask_at), ...]), ...]
+# rows are slices into the block; ``mask_at`` is the (first query, first
+# key) position the causal / window mask of that part is taken at, None
+# where every score of the part is live.  The query rows of one entry see
+# all of their parts at once: one softmax, one dq write.
+_LANES = 128
+
+
+def _block_needed(qi, ki, block_q: int, block_k: int, causal: bool,
+                  window: Optional[int]):
+    """Whether block (qi, ki) holds a live score.  Causal: not strictly
+    above the diagonal; a sliding window additionally not entirely left
+    of every query's window start.  Takes ints or traced program ids."""
+    needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
+    if window is not None:
+        needed = needed & (ki * block_k + block_k - 1
+                           >= qi * block_q - window + 1)
+    return needed
+
+
+def _diag_tile(q_len: int, k_len: int, block_q: int, block_k: int,
+               causal: bool, window: Optional[int]) -> Optional[int]:
+    """Side of the square tiles the causal block is walked in, None where
+    a block runs as one masked square.
+
+    The walk engages where one block holds the whole causal square
+    (block == sequence, what the 1024-block configs run).  On the v5e at
+    [64, 1024, 64] bf16 it takes forward + backward from 11.1 to 7.4 us a
+    head, while the diagonal blocks of a 2x2 grid of 512-blocks lose
+    6-10 % to it: their strips meet in the k-walk's scratch and run one
+    after the other (PERF.md, PR 26).  A sliding window keeps the masked
+    square: its left edge would want a second mask.  Tile 256 beat 128 by
+    1 % and 512 by 19 % at block 1024; 512- and 256-blocks tie between
+    their tiles, so smaller blocks take two strips."""
+    if (not causal or window is not None
+            or not q_len == k_len == block_q == block_k):
+        return None
+    tiles = [t for t in range(_LANES, min(256, block_q // 2) + 1, _LANES)
+             if block_q % t == 0]
+    return max(tiles, default=None)
+
+
+def _square(qi, ki, block_q: int, block_k: int, causal: bool,
+            window: Optional[int]) -> tuple:
+    """The walk entry of the whole block (qi, ki) as one part, masked at
+    its own position (not at all where nothing masks)."""
+    mask_at = ((qi * block_q, ki * block_k)
+               if causal or window is not None else None)
+    return slice(0, block_q), [(slice(0, block_k), mask_at)]
+
+
+def _triangle(block: int, tile: int) -> list:
+    """The walk of the causal block on the diagonal in strips of ``tile``
+    query rows: strip i needs keys [0, (i+1) tile), all left of its
+    diagonal tile is live by construction, so only that tile is masked,
+    and what lies right of it is never computed -- n(n+1)/2 of n^2
+    tiles."""
+    walk = []
+    for lo in range(0, block, tile):
+        rows = slice(lo, lo + tile)
+        left = [(slice(0, lo), None)] if lo else []
+        walk.append((rows, left + [(rows, (0, 0))]))
+    return walk
+
+
+def _one_k_block_walk(qi, block_q: int, block_k: int, causal: bool,
+                      window: Optional[int], tile: Optional[int]) -> list:
+    """The walk of q block ``qi`` against the one key block: the
+    triangle where ``_diag_tile`` gave a tile, else the masked square."""
+    if tile is not None:
+        return _triangle(block_q, tile)
+    return [_square(qi, 0, block_q, block_k, causal, window)]
+
+
+def causal_tiles(q_len: int, k_len: int, block_q: int, block_k: int,
+                 causal: bool, window: Optional[int] = None) -> tuple:
+    """(visited, total) score tiles of one head's [q_len, k_len] square
+    at these (effective) grid blocks, counted off what the kernels run.
+    The unit is the diagonal walk's tile, or the whole block where that
+    walk does not engage.  Static: engagement is decided from shapes at
+    trace time, so this is the counter of how often it engages."""
+    tile = _diag_tile(q_len, k_len, block_q, block_k, causal, window)
+    if tile is None:
+        blocks = [(qi, ki) for qi in range(q_len // block_q)
+                  for ki in range(k_len // block_k)]
+        return (sum(bool(_block_needed(qi, ki, block_q, block_k, causal,
+                                       window)) for qi, ki in blocks),
+                len(blocks))
+    visited = sum((rows.stop - rows.start) // tile
+                  for _, parts in _triangle(block_q, tile)
+                  for rows, _ in parts)
+    return visited, (block_q // tile) ** 2
+
+
+def _masked(s: jax.Array, q0, k0, window: Optional[int],
+            q_axis: int) -> jax.Array:
+    """``s`` with the scores no query may see at -inf.  Queries run along
+    ``q_axis`` from position q0, keys along the other axis from k0."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    mask = qpos >= kpos
+    if window is not None:
+        mask &= (qpos - kpos) < window
+    return jnp.where(mask, s, _NEG_INF)
+
+
+# --------------------------------------------------------------------- #
+# Forward kernels                                                        #
+# --------------------------------------------------------------------- #
+def _lane_fold(x: jax.Array, op) -> jax.Array:
+    """[rows, n * 128] -> [rows, 128]: ``op`` across the lane tiles, on
+    the VPU.  A row reduction then costs one cross-lane (XLU) reduce per
+    eight rows instead of one per vreg of the block; those were a quarter
+    of the forward kernel's time (4.99 -> 3.79 us a head, PERF.md PR 26)."""
+    out = x[:, :_LANES]
+    for lo in range(_LANES, x.shape[1], _LANES):
+        out = op(out, x[:, lo:lo + _LANES])
+    return out
+
+
+def _scores(q_ref, k_ref, rows, parts, scale: float,
+            window: Optional[int]) -> list:
+    """[rows, cols] f32 scores of each part of one walk entry."""
+    out = []
+    for cols, mask_at in parts:
+        # bf16 operands straight into the MXU with an f32 accumulator --
+        # casting to f32 first would halve MXU throughput for no accuracy
+        # gain (the accumulate is f32 either way)
+        s = jax.lax.dot_general(
+            q_ref[0, rows], k_ref[0, cols], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if mask_at is not None:
+            s = _masked(s, *mask_at, window, q_axis=0)
+        out.append(s)
+    return out
+
+
+def _row_max(scores: list) -> jax.Array:
+    """[rows, 1] maximum over every part of a walk entry."""
+    folded = functools.reduce(
+        jnp.maximum, [_lane_fold(s, jnp.maximum) for s in scores])
+    return jnp.max(folded, axis=1, keepdims=True)
+
+
+def _weigh(scores: list, parts: list, m: jax.Array, v_ref) -> tuple:
+    """exp(s - m) of every part against its rows of V: ([rows, 128]
+    lane-partial row sums, [rows, d] weighted values), both f32."""
+    sums = pv = None
+    for s, (cols, _) in zip(scores, parts):
+        p = jnp.exp(s - m)
+        part_sums = _lane_fold(p, jnp.add)
+        part_pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, cols], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        sums = part_sums if sums is None else sums + part_sums
+        pv = part_pv if pv is None else pv + part_pv
+    return sums, pv
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
                   window: Optional[int]):
+    """The k-walk: online softmax over the key blocks of one q block,
+    carried in scratch (running max, lane-partial denominator, output
+    accumulator)."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     last_k = pl.num_programs(2) - 1
@@ -84,51 +255,55 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     # causal: blocks strictly above the diagonal contribute nothing;
     # sliding window additionally skips blocks entirely left of every
     # query's window start
-    needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
-    if window is not None:
-        needed = needed & (ki * block_k + block_k - 1
-                           >= qi * block_q - window + 1)
-
-    @pl.when(needed)
+    @pl.when(_block_needed(qi, ki, block_q, block_k, causal, window))
     def _compute():
-        # bf16 operands straight into the MXU with an f32 accumulator --
-        # casting to f32 first would halve MXU throughput for no accuracy
-        # gain (the accumulate is f32 either way)
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [block_q, block_k]
-        if causal or window is not None:
-            rows = jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1)
-            qpos = qi * block_q + rows
-            kpos = ki * block_k + cols
-            mask = qpos >= kpos
-            if window is not None:
-                mask &= (qpos - kpos) < window
-            s = jnp.where(mask, s, _NEG_INF)
+        rows, parts = _square(qi, ki, block_q, block_k, causal, window)
+        scores = _scores(q_ref, k_ref, rows, parts, scale, window)
         m_prev = m_scr[:, :1]                        # [block_q, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # [block_q, block_k]
+        m_new = jnp.maximum(m_prev, _row_max(scores))
         alpha = jnp.exp(m_prev - m_new)              # [block_q, 1]
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [block_q, d]
+        sums, pv = _weigh(scores, parts, m_new, v_ref)
+        l_scr[:] = alpha * l_scr[:] + sums
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(ki == last_k)
     def _finish():
-        l = l_scr[:, :1]
+        l = jnp.sum(l_scr[:], axis=1, keepdims=True)
         l = jnp.where(l == 0.0, 1.0, l)              # fully-masked rows -> 0
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
         # log-sum-exp per query row, for the backward recompute (the
         # transpose moves [block_q, 1] sublanes onto lanes once per block)
         lse = m_scr[:, :1] + jnp.log(l)
         lse_ref[...] = jnp.transpose(lse, (1, 0))[None]
+
+
+def _flash_one_k_block_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                              scale: float, causal: bool, block_q: int,
+                              block_k: int, window: Optional[int],
+                              tile: Optional[int]):
+    """The whole key length in one block: every query row sees all of
+    its keys at once, so there is no online-softmax carry and nothing
+    goes through scratch -- each strip of the walk is an independent
+    chain of values, which is what lets the causal triangle pay (strips
+    that met in the k-walk's scratch ran one after the other)."""
+    walk = _one_k_block_walk(pl.program_id(1), block_q, block_k, causal,
+                             window, tile)
+    # a strip's QK^T is issued before the strip ahead of it goes through
+    # softmax and PV: the compiler keeps the strips in program order, and
+    # this order gives the MXU work while the VPU and EUP are on the
+    # softmax (2.54 against 3.07 us a head strip after strip)
+    ahead = _scores(q_ref, k_ref, *walk[0], scale, window)
+    for i, (rows, parts) in enumerate(walk):
+        scores = ahead
+        if i + 1 < len(walk):
+            ahead = _scores(q_ref, k_ref, *walk[i + 1], scale, window)
+        m = _row_max(scores)
+        sums, pv = _weigh(scores, parts, m, v_ref)
+        # l >= 1: the row's maximum contributes exp(0)
+        l = jnp.sum(sums, axis=1, keepdims=True)
+        o_ref[0, rows] = (pv / l).astype(o_ref.dtype)
+        lse_ref[0, :, rows] = jnp.transpose(m + jnp.log(l), (1, 0))
 
 
 @scoped("kernel/flash_fwd")
@@ -139,13 +314,23 @@ def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
     Returns (out [bh, seq, d], lse [bh, 1, seq] f32)."""
     bh, q_len, d = q3.shape
     k_len = k3.shape[1]
-    grid = (bh, q_len // block_q, k_len // block_k)
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               window=window)
+    common = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window)
+    if block_k == k_len:
+        kernel = functools.partial(
+            _flash_one_k_block_kernel, **common,
+            tile=_diag_tile(q_len, k_len, block_q, block_k, causal, window))
+        scratch = []
+    else:
+        kernel = functools.partial(_flash_kernel, **common)
+        scratch = [
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # lane-partial denom
+            pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
+        ]
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, q_len // block_q, k_len // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
@@ -162,11 +347,7 @@ def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
             jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, 1, q_len), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
-        ],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             # bh and q blocks are independent; only the kv walk carries
             # the online-softmax state
@@ -189,26 +370,37 @@ def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
 #   dQ  = dS @ K            dK = dS^T @ Q           dV = P^T @ dO
 #   delta_i = sum_d dO_id * O_id     P = exp(S - lse)
 
+def _bwd_strip(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, q_rows, parts,
+               *, scale, window):
+    """Shared recompute of one walk entry, all of its parts in one set of
+    matmuls (the parts are adjacent key rows; only the mask tells them
+    apart, and its pieces are whole sublane tiles): returns (key rows,
+    pT [k, q] f32, dsT [k, q] f32)."""
+    k_rows = slice(parts[0][0].start, parts[-1][0].stop)
+    sT = jax.lax.dot_general(
+        k_ref[0, k_rows], q_ref[0, q_rows], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # [k, q]
+    pieces = []
+    for rows, mask_at in parts:
+        piece = sT[rows.start - k_rows.start:rows.stop - k_rows.start]
+        if mask_at is not None:
+            piece = _masked(piece, *mask_at, window, q_axis=1)
+        pieces.append(piece)
+    sT = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=0)
+    pT = jnp.exp(sT - lse_ref[0, :, q_rows])              # [k, q]
+    dpT = jax.lax.dot_general(
+        v_ref[0, k_rows], do_ref[0, q_rows], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)               # [k, q]
+    dsT = pT * (dpT - dta_ref[0, :, q_rows]) * scale
+    return k_rows, pT, dsT
+
+
 def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, qi, ki, *,
                scale, causal, block_q, block_k, window):
-    """Shared recompute: returns (pT [bk,bq] f32, dsT [bk,bq] f32)."""
-    sT = jax.lax.dot_general(
-        k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # [bk, bq]
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 0)
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 1)
-    if causal or window is not None:
-        mask = qpos >= kpos
-        if window is not None:
-            mask &= (qpos - kpos) < window
-        sT = jnp.where(mask, sT, _NEG_INF)
-    pT = jnp.exp(sT - lse_ref[0])                        # [bk, bq]
-    dpT = jax.lax.dot_general(
-        v_ref[0], do_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)               # [bk, bq]
-    dsT = pT * (dpT - dta_ref[0]) * scale
+    """The whole block (qi, ki) as one masked square: (pT, dsT)."""
+    q_rows, parts = _square(qi, ki, block_q, block_k, causal, window)
+    _, pT, dsT = _bwd_strip(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                            q_rows, parts, scale=scale, window=window)
     return pT, dsT
 
 
@@ -222,12 +414,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
-    if window is not None:
-        needed = needed & (ki * block_k + block_k - 1
-                           >= qi * block_q - window + 1)
-
-    @pl.when(needed)
+    @pl.when(_block_needed(qi, ki, block_q, block_k, causal, window))
     def _compute():
         _, dsT = _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                             qi, ki, scale=scale, causal=causal,
@@ -253,12 +440,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
-    if window is not None:
-        needed = needed & (ki * block_k + block_k - 1
-                           >= qi * block_q - window + 1)
-
-    @pl.when(needed)
+    @pl.when(_block_needed(qi, ki, block_q, block_k, causal, window))
     def _compute():
         pT, dsT = _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                              qi, ki, scale=scale, causal=causal,
@@ -278,12 +460,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                             dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                            scale, causal, block_q, block_k, window):
+                            scale, causal, block_q, block_k, window, tile):
     """Single-k-block fused backward: one pass computes dq for this q
     block AND accumulates dk/dv across q blocks, sharing the sT/dpT
-    recompute the split kernels each redo (5 MXU matmuls per cell vs
+    recompute the split kernels each redo (5 MXU matmuls per strip vs
     3+4).  Engaged when the whole key length fits one block
-    (block_k == k_len), which the large-block configs hit."""
+    (block_k == k_len), which the large-block configs hit; where that
+    block is the whole causal square it is walked as a triangle."""
     qi = pl.program_id(1)
     last_q = pl.num_programs(1) - 1
 
@@ -294,18 +477,22 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
     # with the full K extent in-block every causal/window q block has
     # live keys, so there is no whole-block skip
-    pT, dsT = _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-                         qi, 0, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k, window=window)
-    dv_scr[:] += jax.lax.dot_general(
-        pT.astype(do_ref.dtype), do_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_scr[:] += jax.lax.dot_general(
-        dsT.astype(q_ref.dtype), q_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dq_ref[0] = jax.lax.dot_general(
-        dsT.astype(k_ref.dtype), k_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+    for q_rows, parts in _one_k_block_walk(qi, block_q, block_k, causal,
+                                           window, tile):
+        k_rows, pT, dsT = _bwd_strip(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, q_rows, parts,
+            scale=scale, window=window)
+        dv_scr[k_rows] += jax.lax.dot_general(
+            pT.astype(do_ref.dtype), do_ref[0, q_rows],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        dk_scr[k_rows] += jax.lax.dot_general(
+            dsT.astype(q_ref.dtype), q_ref[0, q_rows],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        # dQ[q, d] = dsT^T @ K == contract dsT dim0 with K dim0
+        dq_ref[0, q_rows] = jax.lax.dot_general(
+            dsT.astype(k_ref.dtype), k_ref[0, k_rows],
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
 
     @pl.when(qi == last_q)
     def _finish():
@@ -322,9 +509,10 @@ def _flash_backward_fused(q3, k3, v3, g3, lse, delta, scale, causal,
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i: (b, 0, 0))
     rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
     return pl.pallas_call(
-        functools.partial(_flash_bwd_fused_kernel, scale=scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          window=window),
+        functools.partial(
+            _flash_bwd_fused_kernel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, window=window,
+            tile=_diag_tile(q_len, k_len, block_q, block_k, causal, window)),
         grid=(bh, q_len // block_q),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=[qspec, kspec, kspec],
@@ -438,10 +626,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``window`` enables sliding-window causal attention (see
     attention_reference).
 
-    Default blocks are 512x512: attention at transformer shapes is
-    HBM-traffic-bound (k/v reload once per q block), so fewer, larger q
-    blocks beat MXU-sized 128 tiles; 512 keeps the f32 score block at
-    1 MB, small enough for double-buffered VMEM.
+    ``block_q`` / ``block_k`` are the grid blocks (default 512, clipped to
+    the largest 128-multiple that divides the sequence).  Fewer, larger
+    blocks win on the v5e: a block that holds the whole sequence runs
+    without the k-walk's carry and, when causal, over the causal
+    triangle only (at [64, 1024, 64] bf16, us a head forward / backward:
+    2.5 / 4.8 with block 1024, 5.4 / 5.0 with 512; PERF.md, PR 26).
     """
     b, h, q_len, d = q.shape
     scale_v = scale if scale is not None else d ** -0.5

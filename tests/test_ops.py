@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from ray_lightning_accelerators_tpu.ops.attention import (
-    attention_reference, flash_attention, flash_attention_interpret)
+    attention_reference, causal_tiles, flash_attention,
+    flash_attention_interpret)
 
 
 # CPU runs both paths in strict f32; on real TPU the MXU's default matmul
@@ -29,13 +30,41 @@ def _qkv(b=2, h=2, s=256, d=64, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_reference(causal):
-    q, k, v = _qkv()
+# (seq, block) pairs: a k-walk of 128-blocks; one block that holds the whole
+# causal square, which the kernels walk as a triangle of 2, 2 and 4 strips
+# (causal_tiles: 3/4, 3/4, 10/16); a 2x2 grid whose diagonal blocks would
+# tile but run as masked squares
+_ONE_BLOCK = [(256, 256), (512, 512), (1024, 1024)]
+_GRIDS = [(256, 128)] + _ONE_BLOCK + [(512, 256)]
+
+
+@pytest.mark.parametrize("causal,seq,block",
+                         [(False, 256, 128), (False, 512, 512)]
+                         + [(True, s, b) for s, b in _GRIDS])
+def test_flash_matches_reference(causal, seq, block):
+    q, k, v = _qkv(b=1, s=seq)
     ref = attention_reference(q, k, v, causal=causal)
     out = flash_attention_interpret(q, k, v, causal=causal,
-                                    block_q=128, block_k=128)
+                                    block_q=block, block_k=block)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **_TOL)
+
+
+@pytest.mark.parametrize("shape,causal,window,want", [
+    ((1024, 1024, 1024, 1024), True, None, (10, 16)),   # both cells: t 256
+    ((512, 512, 512, 512), True, None, (3, 4)),
+    ((256, 256, 256, 256), True, None, (3, 4)),
+    ((384, 384, 384, 384), True, None, (6, 9)),         # 128 divides, 192 no
+    ((128, 128, 128, 128), True, None, (1, 1)),         # a single tile
+    ((1024, 1024, 1024, 1024), False, None, (1, 1)),
+    ((1024, 1024, 1024, 1024), True, 48, (1, 1)),       # window: the square
+    ((1024, 1024, 512, 512), True, None, (3, 4)),       # whole-block skip
+    ((1024, 1024, 512, 512), False, None, (4, 4)),
+    ((1024, 1024, 512, 1024), True, None, (2, 2)),
+    ((1024, 1024, 128, 128), True, 128, (15, 64)),      # diagonal + one left
+])
+def test_causal_tiles_counts_what_the_kernels_visit(shape, causal, window,
+                                                    want):
+    assert causal_tiles(*shape, causal, window) == want
 
 
 def test_flash_uneven_blocks():
@@ -92,11 +121,13 @@ def test_sliding_window_reference(window):
     np.testing.assert_allclose(np.asarray(out), ref, **_TOL)
 
 
-@pytest.mark.parametrize("window", [64, 100])
-def test_sliding_window_kernel_matches(window):
-    q, k, v = _qkv(s=256)
-    out = flash_attention_interpret(q, k, v, causal=True, block_q=128,
-                                    block_k=128, window=window)
+@pytest.mark.parametrize("window,seq,block", [
+    (64, 256, 128), (100, 256, 128),
+    (48, 512, 512), (300, 512, 512)])   # one block; 300 is wider than a tile
+def test_sliding_window_kernel_matches(window, seq, block):
+    q, k, v = _qkv(b=1, s=seq)
+    out = flash_attention_interpret(q, k, v, causal=True, block_q=block,
+                                    block_k=block, window=window)
     ref = attention_reference(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **_TOL)
 
@@ -118,18 +149,23 @@ def test_sliding_window_gradients():
                                    **_GRAD_TOL)
 
 
-@pytest.mark.parametrize("block_k", [128, 256])
-@pytest.mark.parametrize("causal,window", [(False, None), (True, None),
-                                           (True, 96)])
-def test_flash_backward_kernels_match(causal, window, block_k):
+@pytest.mark.parametrize("seq,block_q,block_k,causal,window", [
+    (256, 128, bk, c, w) for bk in (128, 256)
+    for c, w in ((False, None), (True, None), (True, 96))
+] + [(s, b, b, True, None) for s, b in _ONE_BLOCK + [(512, 256)]] + [
+    (512, 512, 512, False, None), (512, 512, 512, True, 48),
+    (512, 512, 512, True, 300)])
+def test_flash_backward_kernels_match(seq, block_q, block_k, causal, window):
     """The hand-written backward kernels must reproduce XLA autodiff of
-    the reference: block_k=128 exercises the split dq + dkv passes,
-    block_k=256 (== k_len) the FUSED single-k-block kernel that shares
-    the score recompute."""
+    the reference: block_k < seq exercises the split dq + dkv passes,
+    block_k == seq the FUSED single-k-block kernel that shares the score
+    recompute -- as one masked square (block_q < block_k, a window,
+    non-causal) and, where one block holds the causal square, as the
+    triangle of strips."""
     from ray_lightning_accelerators_tpu.ops.attention import (
         flash_attention_grads_interpret)
 
-    q, k, v = _qkv(b=2, h=2, s=256, d=64)
+    q, k, v = _qkv(b=1, h=2, s=seq, d=64)
     g = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
 
     def ref(q_, k_, v_):
@@ -138,7 +174,7 @@ def test_flash_backward_kernels_match(causal, window, block_k):
     _, vjp = jax.vjp(ref, q, k, v)
     want = vjp(g)
     got = flash_attention_grads_interpret(q, k, v, g, causal=causal,
-                                          block_q=128, block_k=block_k,
+                                          block_q=block_q, block_k=block_k,
                                           window=window)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **_GRAD_TOL)
