@@ -21,6 +21,11 @@ prints no result:
               Then the benchmark cells' own flash shape (64 heads of
               [1024, 64], one 1024^2 causal block): checked, and the
               forward's and backward's microseconds per head printed.
+              Then the ``train-lfm2-moe-8k`` cell's two operators at its
+              shapes (the dropless expert layer: 32,768 rows, 8 of 32
+              experts held, 2048 x 1792; the gated short convolution at
+              [4, 8192, 2048]) against the float32 reference, forward
+              and backward, rows and milliseconds per call printed.
 3. *train*    ``Trainer.fit(GPT, DataLoader)``: finite, falling loss,
               zero compiles in the second epoch, the flash forward and
               backward kernels in the train step's lowering, peak HBM.
@@ -64,6 +69,9 @@ TOL_FLASH_FWD = 2e-2
 TOL_FLASH_GRAD = 4e-2
 TOL_NORM = 1e-2
 TOL_INT8 = 1e-2
+# the LFM2 cell's operators, bf16 against float32, forward and VJP: three
+# chained matmuls and a gate round like the flash gradients do
+TOL_LFM2 = 4e-2
 # two correct bf16 programs for the same greedy decode (cached vs
 # re-forward, paged vs dense) can disagree where the top two logits
 # nearly tie; a chosen token may trail the re-forward argmax by at most
@@ -331,6 +339,91 @@ def phase_flash_cell_shape(seed: int, iters: int = 100) -> None:
          tiles_visited=visited, tiles_total=total,
          fwd_us_per_head=seconds["fwd"] * per_head,
          bwd_us_per_head=(seconds["fwd_bwd"] - seconds["fwd"]) * per_head)
+
+
+def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
+                           d_model: int = 2048, expert_width: int = 1792,
+                           experts: int = 32, held: int = 8, top_k: int = 4,
+                           iters: int = 5, on_chip: bool = True) -> None:
+    """The ``train-lfm2-moe-8k`` cell's two new operators at its shapes
+    (32,768 rows into 8 held experts of 32, each 2048 x 1792, top-4; the
+    gated short convolution at [4, 8192, 2048]) against the plain
+    float32 reference, forward and backward.  The comparison runs on one
+    sequence (the reference's float32 intermediates of four do not fit
+    beside it), the timing on the whole batch; both get float32 inputs,
+    so the router (float32, full precision on both sides) chooses alike
+    and the difference is bfloat16 arithmetic alone.  Prints rows and
+    milliseconds per call."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_accelerators_tpu.models import reference_lfm2 as ref
+    from ray_lightning_accelerators_tpu.ops import moe
+    from ray_lightning_accelerators_tpu.ops.conv import gated_short_conv
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 2), 8))
+    ids = tuple(range(held))
+    model = {"num_experts": experts, "moe_top_k": top_k,
+             "moe_norm_topk": True, "moe_routed_scale": 1.0}
+    x = jax.random.normal(next(keys), (batch, seq, d_model), jnp.float32)
+    g = jax.random.normal(next(keys), x.shape, jnp.float32)
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / iters * 1e3
+
+    def check(name, system, reference, params, **fields):
+        """``system`` / ``reference``: (params, x) -> y.  Compared with
+        their VJPs at cotangent ``g`` on the first sequence, then the
+        system timed on the batch."""
+        fwd = jax.jit(system)
+        both = jax.jit(lambda p, x_, g_: jax.vjp(system, p, x_)[1](g_))
+        if on_chip:
+            names = kernels_in(fwd.lower(params, x))
+            require(name != "dropless_moe" or names,
+                    "the expert layer lowered without its kernel")
+            fields["kernels"] = names
+        y, vjp = jax.vjp(reference, params, x[:1])
+        errs = {"fwd": _rel_err(fwd(params, x[:1]), y)}
+        got, want = both(params, x[:1], g[:1]), vjp(g[:1])
+        for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(
+                got)[0], jax.tree.leaves(want)):
+            if float(jnp.max(jnp.abs(b))) > 0:      # the bias has none
+                errs["d" + jax.tree_util.keystr(path)] = _rel_err(a, b)
+        emit("lfm2_cell_shapes", op=name, shape=list(x.shape), errs=errs,
+             fwd_ms=timed(fwd, params, x),
+             fwd_bwd_ms=timed(both, params, x, g), **fields)
+        worst = max(errs.values())
+        require(worst <= TOL_LFM2, f"{name}: error {worst} ({errs})")
+
+    p = moe.init_dropless_params(next(keys), d_model, expert_width, experts,
+                                 held)
+    stats = jax.jit(lambda p_, x_: moe.dropless_moe(
+        x_, p_, top_k=top_k, held=ids, num_experts=experts)[1])(p, x)
+    require(float(stats["rows_routed"]) == float(stats["rows_computed"]),
+            f"a routed row was not computed: {stats}")
+    check("dropless_moe",
+          lambda p_, x_: moe.dropless_moe(
+              x_, p_, top_k=top_k, held=ids, num_experts=experts)[0],
+          lambda p_, x_: ref.sparse_block(x_, p_, model, ids)[0], p,
+          rows_in=batch * seq, rows_routed=float(stats["rows_routed"]),
+          load_max_over_mean=float(stats["load_max_over_mean"]))
+
+    c = {"w_in": jax.random.normal(next(keys), (d_model, 3 * d_model))
+         * d_model ** -0.5,
+         "conv_w": jax.random.normal(next(keys), (d_model, 3)) * 3 ** -0.5,
+         "w_out": jax.random.normal(next(keys), (d_model, d_model))
+         * d_model ** -0.5}
+    check("gated_short_conv",
+          lambda c_, x_: gated_short_conv(
+              x_.astype(jnp.bfloat16), c_["w_in"].astype(jnp.bfloat16),
+              c_["conv_w"], c_["w_out"].astype(jnp.bfloat16)
+              ).astype(jnp.float32),
+          lambda c_, x_: ref.conv_operator(x_, c_), c)
 
 
 # --------------------------------------------------------------------- #
@@ -735,6 +828,7 @@ def main(argv=None) -> None:
     else:
         phase_kernels(size, args.seed)
         phase_flash_cell_shape(args.seed)
+        phase_lfm2_cell_shapes(args.seed)
         phase_train(size, args.seed)
         model, params = phase_generate(size, args.seed)
         phase_serve(size, args.seed, model, params)

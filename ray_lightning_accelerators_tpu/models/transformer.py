@@ -32,7 +32,10 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as sharding_lib
 from ..parallel.ring_attention import ring_attention_sharded
 from ..ops.attention import flash_attention
-from ..ops.moe import init_moe_params, moe_logical_axes, moe_mlp
+from ..ops.conv import gated_short_conv
+from ..ops.moe import (dropless_logical_axes, dropless_moe,
+                       init_dropless_params, init_moe_params,
+                       moe_logical_axes, moe_mlp)
 from ..ops.norms import rms_norm
 from ..utils.logging import log
 from ..utils.scope import scoped
@@ -93,6 +96,107 @@ class TransformerConfig:
     # PR 26); both benchmark cells set 1024.
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
+    # ---- a layer stack of several kinds ------------------------------ #
+    # ``layer_types`` or ``moe_router="sigmoid"`` makes the stack a
+    # sequence of RUNS: consecutive layers of one (operator, feed-forward)
+    # kind are one stacked subtree ``params["layers_<i>"]`` and one
+    # ``lax.scan``, so compile time grows with runs, not layers.  The
+    # other fields below state the rest of that block (the mixed stack
+    # runs SwiGLU, QK-norm and rotate-half rotary and refuses anything
+    # else by name) and are refused on the uniform block above, whose
+    # parameter tree (``params["layers"]``) and program they never touch.
+    # Training only: decode, serving, quantize_weights and the pipeline
+    # walk one uniform stack and refuse a mixed one by name.
+    #
+    # per-layer sequence operator, "conv" (gated short convolution,
+    # ops/conv.py) or "full_attention"; None = attention everywhere
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_kernel: int = 3          # taps of the short convolution
+    # "softmax": the capacity path of ops/moe.py in every layer (today's
+    # ``num_experts > 1``).  "sigmoid": the dropless path -- sigmoid
+    # scores, a selection bias, top-k weights normalised
+    # (``moe_norm_topk``) and scaled (``moe_routed_scale``), SwiGLU
+    # experts of width ``moe_d_ff`` -- in every layer past the first
+    # ``num_dense_layers``, which keep a dense MLP of width ``d_ff``
+    moe_router: str = "softmax"
+    num_dense_layers: int = 0
+    moe_d_ff: Optional[int] = None        # expert width; None = d_ff
+    # ids of the experts this chip holds (the router keeps all
+    # ``num_experts`` outputs and its ``moe_top_k`` whatever is held;
+    # what absent experts would add is left out); None = all of them
+    moe_experts_held: Optional[Tuple[int, ...]] = None
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    gated_mlp: bool = False       # SwiGLU (silu(x W1) * (x W3)) W2
+    qk_norm: bool = False         # per-head RMSNorm on q and k before rope
+    # "interleaved": pairs (2i, 2i+1); "half": pairs (i, i + head_dim/2),
+    # the rotate-half convention of the published checkpoints
+    rope_style: str = "interleaved"
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        # lists from JSON (a benchmark config, a checkpoint's hparams)
+        for name in ("layer_types", "moe_experts_held"):
+            value = getattr(self, name)
+            if value is not None:
+                setattr(self, name, tuple(value))
+        self.layer_runs()       # a config no block runs fails here
+
+    # the fields that only a mixed stack reads, with their defaults, and
+    # the one value of each that the mixed stack's block implements
+    _MIXED_ONLY = (("num_dense_layers", 0), ("moe_d_ff", None),
+                   ("moe_experts_held", None), ("gated_mlp", False),
+                   ("qk_norm", False), ("rope_style", "interleaved"),
+                   ("norm_eps", 1e-6))
+    _MIXED_BLOCK = (("gated_mlp", True), ("qk_norm", True),
+                    ("rope_style", "half"))
+
+    def layer_runs(self) -> Optional[Tuple[Tuple[str, str, int], ...]]:
+        """``((operator, feed_forward, n_layers), ...)`` in stack order,
+        or None for the uniform block (neither ``layer_types`` nor
+        ``moe_router="sigmoid"``).  operator: "conv" | "attn";
+        feed_forward: "dense" | "sparse"."""
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.layer_types is None and self.moe_router == "softmax":
+            for name, default in self._MIXED_ONLY:
+                if getattr(self, name) != default:
+                    raise ValueError(
+                        f"TransformerConfig.{name} belongs to the mixed "
+                        "layer stack: set layer_types or "
+                        "moe_router='sigmoid' with it")
+            return None
+        for name, needed in self._MIXED_BLOCK:
+            if getattr(self, name) != needed:
+                raise NotImplementedError(
+                    f"a mixed layer stack (layer_types / moe_router="
+                    f"'sigmoid') runs {name}={needed!r} only")
+        types = self.layer_types or ("full_attention",) * self.n_layers
+        if len(types) != self.n_layers or set(types) - {
+                "conv", "full_attention"}:
+            raise ValueError(
+                f"layer_types must name n_layers={self.n_layers} layers, "
+                f"each 'conv' or 'full_attention'; got {types}")
+        sparse = self.moe_router == "sigmoid" and self.num_experts > 1
+        if self.num_experts > 1 and not sparse:
+            raise NotImplementedError(
+                "a mixed layer stack (layer_types) carries the dropless "
+                "expert layer only: set moe_router='sigmoid'")
+        runs = []
+        for i, kind in enumerate(types):
+            key = ("conv" if kind == "conv" else "attn",
+                   "sparse" if sparse and i >= self.num_dense_layers
+                   else "dense")
+            if runs and tuple(runs[-1][:2]) == key:
+                runs[-1][2] += 1
+            else:
+                runs.append([*key, 1])
+        return tuple(tuple(r) for r in runs)
+
+    @property
+    def experts_held(self) -> Tuple[int, ...]:
+        held = self.moe_experts_held
+        return tuple(range(self.num_experts)) if held is None else held
 
     @property
     def head_dim(self) -> int:
@@ -117,6 +221,18 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     rx1 = x1 * cos - x2 * sin
     rx2 = x2 * cos + x1 * sin
     return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embeddings in the rotate-half convention: dimension i pairs
+    with i + d/2 (``_rope`` pairs 2i with 2i + 1).  x: [b, h, s, d]."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def _rope_rows(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -283,19 +399,97 @@ class GPT(TpuModule):
             }
 
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
-        layers = jax.vmap(layer)(layer_keys)  # stacked: leading dim n_layers
         params = {
             "embed": dense(k_embed, (cfg.vocab_size, d), d) * d ** 0.5 * 0.02,
-            "layers": layers,
             "ln_f": jnp.ones((d,), jnp.float32),
         }
+        runs = cfg.layer_runs()
+        if runs is None:
+            # stacked: leading dim n_layers
+            params["layers"] = jax.vmap(layer)(layer_keys)
+        else:
+            first = 0
+            for i, (op, ff, n) in enumerate(runs):
+                params[f"layers_{i}"] = jax.vmap(functools.partial(
+                    self._init_kind_layer, op=op, ff=ff))(
+                        layer_keys[first:first + n])
+                first += n
         if not cfg.tie_embeddings:
             params["unembed"] = dense(k_out, (d, cfg.vocab_size), d)
         return params
 
+    def _init_kind_layer(self, key, *, op: str, ff: str) -> Dict[str, Any]:
+        """One layer of a mixed stack: operator ``op`` ("conv" | "attn"),
+        feed-forward ``ff`` ("dense" SwiGLU | "sparse")."""
+        cfg = self.cfg
+        d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        ks = jax.random.split(key, 8)
+
+        def dense(k, shape, fan_in):
+            return jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5
+
+        out = {"ln1": jnp.ones((d,), jnp.float32),
+               "ln2": jnp.ones((d,), jnp.float32)}
+        if op == "conv":
+            out["conv"] = {
+                "w_in": dense(ks[0], (d, 3 * d), d),
+                "conv_w": dense(ks[1], (d, cfg.conv_kernel),
+                                cfg.conv_kernel),
+                "w_out": dense(ks[2], (d, d), d),
+            }
+        else:
+            out["attn"] = {
+                "wq": dense(ks[0], (d, h, hd), d),
+                "wk": dense(ks[1], (d, kv, hd), d),
+                "wv": dense(ks[2], (d, kv, hd), d),
+                "wo": dense(ks[3], (h, hd, d), d),
+                "q_norm": jnp.ones((hd,), jnp.float32),
+                "k_norm": jnp.ones((hd,), jnp.float32),
+            }
+        if ff == "sparse":
+            out["mlp"] = init_dropless_params(
+                ks[4], d, cfg.moe_d_ff or cfg.d_ff, cfg.num_experts,
+                len(cfg.experts_held))
+        else:
+            out["mlp"] = {"w1": dense(ks[4], (d, cfg.d_ff), d),
+                          "w3": dense(ks[5], (d, cfg.d_ff), d),
+                          "w2": dense(ks[6], (cfg.d_ff, d), cfg.d_ff)}
+        return out
+
+    def _kind_logical_axes(self, op: str, ff: str) -> Dict[str, Any]:
+        axes: Dict[str, Any] = {"ln1": ("layers", None),
+                                "ln2": ("layers", None)}
+        if op == "conv":
+            axes["conv"] = {"w_in": ("layers", "embed", "mlp"),
+                            "conv_w": ("layers", None, None),
+                            "w_out": ("layers", "mlp", "embed")}
+        else:
+            axes["attn"] = {"wq": ("layers", "embed", "heads", "kv"),
+                            "wk": ("layers", "embed", "heads", "kv"),
+                            "wv": ("layers", "embed", "heads", "kv"),
+                            "wo": ("layers", "heads", "kv", "embed"),
+                            "q_norm": ("layers", None),
+                            "k_norm": ("layers", None)}
+        if ff == "sparse":
+            axes["mlp"] = {name: ("layers",) + ax for name, ax
+                           in dropless_logical_axes().items()}
+        else:
+            axes["mlp"] = {"w1": ("layers", "embed", "mlp"),
+                           "w3": ("layers", "embed", "mlp"),
+                           "w2": ("layers", "mlp", "embed")}
+        return axes
+
     def param_logical_axes(self) -> Dict[str, Any]:
         """Logical axis names per leaf; consumed by the accelerator to build
         mesh shardings (parallel/sharding.py rules)."""
+        runs = self.cfg.layer_runs()
+        if runs is not None:
+            axes = {"embed": ("vocab", "embed"), "ln_f": (None,)}
+            for i, (op, ff, _) in enumerate(runs):
+                axes[f"layers_{i}"] = self._kind_logical_axes(op, ff)
+            if not self.cfg.tie_embeddings:
+                axes["unembed"] = ("embed", "vocab")
+            return axes
         if self.cfg.num_experts > 1:
             mlp_axes = {name: ("layers",) + ax
                         for name, ax in moe_logical_axes().items()}
@@ -326,8 +520,22 @@ class GPT(TpuModule):
     def scanned_param_subtrees(self) -> Tuple[str, ...]:
         """The layer stack is scanned — the overlap-aware FSDP gather
         (``Trainer(gather_mode="scan")``) keeps it fsdp-sharded as scan
-        operands and all-gathers each layer inside the scan body."""
-        return ("layers",)
+        operands and all-gathers each layer inside the scan body.  A
+        mixed stack is one stacked subtree per run."""
+        runs = self.cfg.layer_runs()
+        if runs is None:
+            return ("layers",)
+        return tuple(f"layers_{i}" for i in range(len(runs)))
+
+    def _uniform_stack_only(self, what: str) -> None:
+        """Every walker of ``params["layers"]`` as ONE uniform stack
+        calls this first: a mixed stack has no such subtree (and a conv
+        layer's serving state is not a KV cache)."""
+        if self.cfg.layer_runs() is not None:
+            raise NotImplementedError(
+                f"{what} walks params['layers'] as one uniform stack of "
+                "attention blocks; a mixed layer stack (TransformerConfig"
+                ".layer_types / moe_router='sigmoid') trains only")
 
     # ------------------------------------------------------------------ #
     # Forward                                                            #
@@ -376,8 +584,14 @@ class GPT(TpuModule):
         # (ops/norms.py); per-shard on a multi-device mesh, where x is
         # [batch, seq, d] (or [batch, d]) rows and the scale replicated
         rows = ("batch", "seq", None) if x.ndim == 3 else ("batch", None)
+        norm = rms_norm
+        if self.cfg.norm_eps != 1e-6:
+            eps = self.cfg.norm_eps
+
+            def norm(rows_, scale_):
+                return rms_norm(rows_, scale_, eps)
         return sharding_lib.shard_local(
-            rms_norm, self.mesh, (rows, (None,)), rows)(x, scale)
+            norm, self.mesh, (rows, (None,)), rows)(x, scale)
 
     def _attention(self, q, k, v):
         if self.mesh is not None and mesh_lib.mesh_axis_size(
@@ -494,6 +708,98 @@ class GPT(TpuModule):
             return h, aux, k, v
         return h, aux
 
+    def _block_kind(self, h, lp, positions, op: str, ff: str):
+        """One layer of a mixed stack: ``h + op(norm(h))``, then
+        ``+ ff(norm(.))``; attention with per-head RMSNorm on q and k and
+        rotate-half rotary, the dense feed-forward SwiGLU.  Returns
+        ``(h, stats)``: the expert layer's counters for a sparse
+        feed-forward, ``{}`` for a dense one."""
+        cfg = self.cfg
+        dt = self.compute_dtype
+        x = self._rms_norm(h, lp["ln1"])
+        if op == "conv":
+            with jax.named_scope("gpt/conv"):
+                c = lp["conv"]
+                y = gated_short_conv(x, c["w_in"].astype(dt), c["conv_w"],
+                                     c["w_out"].astype(dt))
+        else:
+            with jax.named_scope("gpt/attn"):
+                a = lp["attn"]
+                q = jnp.einsum("bsd,dhk->bhsk", x, a["wq"].astype(dt))
+                k = jnp.einsum("bsd,dhk->bhsk", x, a["wk"].astype(dt))
+                v = jnp.einsum("bsd,dhk->bhsk", x, a["wv"].astype(dt))
+                q = _rope_half(rms_norm(q, a["q_norm"], cfg.norm_eps),
+                               positions, cfg.rope_theta)
+                k = _rope_half(rms_norm(k, a["k_norm"], cfg.norm_eps),
+                               positions, cfg.rope_theta)
+                q = self._constrain(q, mesh_lib.BATCH_AXES,
+                                    mesh_lib.TENSOR_AXIS,
+                                    mesh_lib.SEQUENCE_AXIS, None)
+                groups = cfg.n_heads // cfg.kv_heads
+                if groups > 1:  # GQA: each KV head serves its group
+                    k = jnp.repeat(k, groups, axis=1)
+                    v = jnp.repeat(v, groups, axis=1)
+                y = jnp.einsum("bhsk,hkd->bsd", self._attention(q, k, v),
+                               a["wo"].astype(dt))
+        h = h + y
+        x = self._rms_norm(h, lp["ln2"])
+        m, stats = lp["mlp"], {}
+        if ff == "sparse":
+            y, stats = dropless_moe(
+                x, m, top_k=cfg.moe_top_k, held=cfg.experts_held,
+                num_experts=cfg.num_experts, norm_topk=cfg.moe_norm_topk,
+                scale=cfg.moe_routed_scale, compute_dtype=dt,
+                mesh=self.mesh)
+        else:
+            with jax.named_scope("gpt/mlp"):
+                up = jax.nn.silu(jnp.einsum(
+                    "bsd,df->bsf", x, m["w1"].astype(dt))
+                    ) * jnp.einsum("bsd,df->bsf", x, m["w3"].astype(dt))
+                up = self._constrain(up, mesh_lib.BATCH_AXES,
+                                     mesh_lib.SEQUENCE_AXIS,
+                                     mesh_lib.TENSOR_AXIS)
+                y = jnp.einsum("bsf,fd->bsd", up, m["w2"].astype(dt))
+        h = self._constrain(h + y, mesh_lib.BATCH_AXES,
+                            mesh_lib.SEQUENCE_AXIS, None)
+        return h, stats
+
+    def _run_stacks(self, params, h, runs):
+        """The mixed stack: one ``lax.scan`` (with remat as the uniform
+        stack has it) per run, in order.  Returns ``(h, stats)`` with the
+        sparse layers' counters summed over layers (``load_max_over_
+        mean`` averaged) and their chosen expert ids, empty without a
+        sparse layer."""
+        pos = jnp.arange(h.shape[1])
+        per_layer = []
+        for i, (op, ff, _) in enumerate(runs):
+            key = f"layers_{i}"
+            gather = collectives_lib.current_layer_gather(key)
+
+            def block(carry, lp, op=op, ff=ff, gather=gather):
+                if gather is not None:
+                    lp = gather(lp)
+                return self._block_kind(carry, lp, pos, op, ff)
+
+            if self.cfg.remat:
+                block = jax.checkpoint(block, policy=_remat_policy(
+                    self.cfg.remat_policy))
+            with jax.named_scope("gpt/layers"):
+                h, stats = jax.lax.scan(block, h, params[key])
+            if stats:
+                per_layer.append(stats)
+        if not per_layer:
+            return h, {}
+        stats = {k: jnp.concatenate([s[k] for s in per_layer])
+                 for k in per_layer[0]}
+        return h, {
+            "moe_rows_routed": jnp.sum(stats["rows_routed"]),
+            "moe_rows_computed": jnp.sum(stats["rows_computed"]),
+            "moe_load_max_over_mean": jnp.mean(
+                stats["load_max_over_mean"]),
+            # [sparse layers, b, s, top_k]: for a comparison of the
+            # routing itself (forward(return_aux=True)); no metric
+            "moe_selected": stats["selected"]}
+
     @staticmethod
     def _tokens_of(batch):
         tokens = batch["input_ids"] if isinstance(batch, dict) else batch
@@ -503,12 +809,25 @@ class GPT(TpuModule):
 
     def _trunk(self, params, tokens, dropout_rng=None):
         """Embedding and the layer stack, up to the final norm:
-        ``(hidden, moe aux loss)``."""
+        ``(hidden, moe aux loss)``; of a mixed stack ``(hidden, the
+        expert layers' counters)``."""
         if dropout_rng is not None and self.cfg.dropout <= 0:
             dropout_rng = None
         h = self._embed_lookup(params, tokens)
         h = self._constrain(h, mesh_lib.BATCH_AXES,
                             mesh_lib.SEQUENCE_AXIS, None)
+        runs = self.cfg.layer_runs()
+        if runs is not None:
+            if dropout_rng is not None:
+                raise NotImplementedError(
+                    "dropout in a mixed layer stack (TransformerConfig"
+                    ".layer_types ...) is not supported; set dropout=0")
+            if self.mesh is not None and mesh_lib.mesh_axis_size(
+                    self.mesh, mesh_lib.PIPELINE_AXIS) > 1:
+                self._uniform_stack_only("pipeline parallelism")
+            # the second result is the expert layers' counters here (a
+            # dict, empty without a sparse layer), not an auxiliary loss
+            return self._run_stacks(params, h, runs)
 
         def stack(h_in, layers):
             # positions derive from the (static) seq length; recomputed here
@@ -644,7 +963,12 @@ class GPT(TpuModule):
     def training_step(self, params, batch, rng):
         loss, acc, aux = self._lm_loss(params, batch, rng=rng)
         metrics = {"loss": loss, "accuracy": acc}
-        if self.cfg.num_experts > 1:
+        if isinstance(aux, dict):
+            # a mixed stack: no auxiliary loss (the dropless router is
+            # steered by its bias); the counters ride the logged metrics,
+            # through the scanned epoch too
+            metrics.update({k: v for k, v in aux.items() if v.ndim == 0})
+        elif self.cfg.num_experts > 1:
             metrics["moe_aux_loss"] = aux
             loss = loss + self.cfg.moe_aux_weight * aux
         return loss, metrics
@@ -658,7 +982,21 @@ class GPT(TpuModule):
         return self.forward(params, batch)
 
     def configure_optimizers(self):
-        return optax.adamw(self.lr, weight_decay=0.01)
+        tx = optax.adamw(self.lr, weight_decay=0.01)
+        runs = self.cfg.layer_runs()
+        if runs is None or not any(ff == "sparse" for _, ff, _ in runs):
+            return tx
+
+        # the selection bias is a buffer: no update (AdamW's decay would
+        # shrink it on a zero gradient) and no optimizer state
+        def labels(params):
+            return jax.tree_util.tree_map_with_path(
+                lambda path, _: "buffer" if getattr(
+                    path[-1], "key", None) == "expert_bias" else "train",
+                params)
+
+        return optax.multi_transform(
+            {"train": tx, "buffer": optax.set_to_zero()}, labels)
 
     # ------------------------------------------------------------------ #
     # Weight-only int8 quantization (inference)                          #
@@ -697,6 +1035,11 @@ class GPT(TpuModule):
                          -127, 127).astype(jnp.int8)
             return {"q8": q, "scale": scale.astype(jnp.float32)}
 
+        if "layers" not in params:
+            raise NotImplementedError(
+                "GPT.quantize_weights walks params['layers'] as one "
+                "uniform stack; a mixed layer stack (TransformerConfig"
+                ".layer_types ...: params['layers_<i>']) trains only")
         out = {k: v for k, v in params.items()}
         out["layers"] = jax.tree.map(lambda a: quant(a, True),
                                      params["layers"])
@@ -881,6 +1224,9 @@ class GPT(TpuModule):
         write garbage k/v beyond ``last_index``, which is safe for linear
         decode: slot p is rewritten by the decode step at position p
         before any mask ever lets it be attended."""
+        self._uniform_stack_only(
+            "GPT._prefill (generate, generate_beam, ServeEngine, "
+            "speculative_generate)")
         dt = self.compute_dtype
         h = self._embed_lookup(params, tokens)
         pos = jnp.arange(tokens.shape[1])
@@ -998,6 +1344,7 @@ class GPT(TpuModule):
         tokens: [B,n] fed at positions pos0..pos0+n-1.  Returns (logits
         [B,n,V] f32, updated cache) — logits[:, i] predicts position
         pos0+i+1.  Requires the linear (non-rolling) cache."""
+        self._uniform_stack_only("GPT._decode_chunk (speculative scoring)")
         dt = self.compute_dtype
         h = self._embed_lookup(params, tokens)
 
@@ -1018,6 +1365,7 @@ class GPT(TpuModule):
     def _decode_token(self, params, cache, token, pos):
         """Full-depth single-token step.  token: [B] int32.  Returns
         (logits [B,V] f32, updated cache)."""
+        self._uniform_stack_only("GPT._decode_token (generate)")
         dt = self.compute_dtype
         h = self._embed_lookup(params, token)[:, None]  # [B,1,d]
 
@@ -1046,6 +1394,7 @@ class GPT(TpuModule):
         """Zeroed multi-slot KV cache [L, batch, kv_heads, total_len,
         head_dim] in the compute dtype — the serve engine's fixed decode
         slots."""
+        self._uniform_stack_only("GPT.decode_cache_alloc")
         cfg = self.cfg
         shape = (cfg.n_layers, batch, cfg.kv_heads, total_len,
                  cfg.head_dim)
@@ -1077,6 +1426,7 @@ class GPT(TpuModule):
         any token at any in-range position: their slot is fully rewritten
         by the next join before it is attended.  Returns (logits [B,V]
         f32, updated cache)."""
+        self._uniform_stack_only("GPT.decode_step_rows")
         dt = self.compute_dtype
         positions = jnp.asarray(positions, jnp.int32)
         h = self._embed_lookup(params, tokens)[:, None]  # [B,1,d]
@@ -1113,6 +1463,7 @@ class GPT(TpuModule):
         in the compute dtype — the paged serve engine's fixed HBM
         footprint (block 0 is conventionally the engine's garbage block:
         inactive decode rows scatter there, it is never table-mapped)."""
+        self._uniform_stack_only("GPT.paged_cache_alloc")
         cfg = self.cfg
         shape = (cfg.n_layers, n_blocks, cfg.kv_heads, block_len,
                  cfg.head_dim)
@@ -1233,6 +1584,7 @@ class GPT(TpuModule):
         positions: [B] int32.  Rows the caller considers inactive must
         carry an all-zero table (the garbage block) and any in-range
         position.  Returns (logits [B, V] f32, updated pool)."""
+        self._uniform_stack_only("GPT.decode_step_rows_paged")
         dt = self.compute_dtype
         positions = jnp.asarray(positions, jnp.int32)
         tables = jnp.asarray(tables, jnp.int32)
@@ -1261,6 +1613,7 @@ class GPT(TpuModule):
         true last prompt token's logits [1, V]) and the speculative chunk
         scorer (``last_index=None`` → logits [1, n, V]; logits[:, i]
         predicts position pos0+i+1).  Returns (logits, pool)."""
+        self._uniform_stack_only("GPT.decode_chunk_paged")
         dt = self.compute_dtype
         n = tokens.shape[1]
         pos = (jnp.asarray(pos0, jnp.int32)

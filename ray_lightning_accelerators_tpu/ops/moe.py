@@ -17,16 +17,27 @@ Design is GShard/Switch-style and deliberately XLA-shaped:
   ride the residual connection — the standard Switch behavior;
 - the load-balancing auxiliary loss (Switch eq. 4) is returned alongside
   the output so the caller can add ``aux_weight * aux`` to the task loss.
+
+Two expert paths live here (ROADMAP Queue 3 names the pair as a debt).
+The capacity path above is the one the ``expert`` mesh axis partitions.
+The dropless path (``dropless_moe``, below) is told which experts it
+holds: sigmoid scores with a selection bias, the (token, choice) pairs
+sorted by expert, one grouped matrix product per projection over the rows
+routed to the held experts, nothing dropped at any imbalance.  It runs
+without an exchange: what absent experts would add is left out.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..analysis import knobs
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as sharding_lib
 
@@ -160,4 +171,277 @@ def moe_logical_axes() -> Dict[str, Any]:
         "router": (None, None),               # tiny; replicate
         "wi": ("expert", "embed", "mlp"),
         "wo": ("expert", "mlp", "embed"),
+    }
+
+
+# --------------------------------------------------------------------- #
+# Dropless path: the experts held here                                   #
+# --------------------------------------------------------------------- #
+def sigmoid_routing(x: jax.Array, router: jax.Array, bias: jax.Array, *,
+                    top_k: int, norm_topk: bool, scale: float
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """``s = sigmoid(x W_g)`` in float32 at full matmul precision (a
+    near-tied choice flips on a rounded score and costs a whole expert);
+    ``sel = top_k(s + bias)``, the bias a buffer that only steers the
+    selection; weights ``s[sel]``, normalised over ALL chosen experts
+    (held or not) and scaled.  x: [t, d] -> (ids [t, k] int32,
+    weights [t, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(s + jax.lax.stop_gradient(
+        bias.astype(jnp.float32)), top_k)
+    # the chosen scores by comparison, not by gather: the transpose of a
+    # gather is a scatter-add, and a select is cheaper on this chip
+    chosen = ids[..., None] == jnp.arange(s.shape[-1])
+    w = jnp.sum(jnp.where(chosen, s[..., None, :], 0.0), axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return ids.astype(jnp.int32), w * scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inverse, top_k):
+    """Row ``order[i] // top_k`` of ``x`` for every sorted pair i.  The
+    pairs are a permutation of (token, choice), so the transpose is a
+    gather by the inverse permutation and a sum over the choices, not a
+    scatter-add."""
+    return x[order // top_k]
+
+
+def _dispatch_fwd(x, order, inverse, top_k):
+    return x[order // top_k], (inverse, x.shape[0])
+
+
+def _dispatch_bwd(top_k, res, g):
+    inverse, t = res
+    return (g[inverse].reshape(t, top_k, -1).sum(1).astype(g.dtype),
+            None, None)
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation: transposed by its inverse."""
+    return x[perm]
+
+
+_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
+                     lambda res, g: (g[res[1]], None, None))
+
+# m, k, n tile of the grouped-matmul kernel; the row tile is also what a
+# group's ragged end is padded to, so `moe_load_max_over_mean` explains
+# the padding.  Chosen on the v5e (PERF.md section 6, PR 27).
+GMM_ROW_TILE = 512
+
+
+def _tile(extent: int, limit: int = 1024) -> int:
+    """The largest multiple of 128 that divides ``extent``, at most
+    ``limit``."""
+    for cand in range(limit, 0, -128):
+        if extent % cand == 0:
+            return cand
+    return extent
+
+
+def _use_gmm_kernel(lhs: jax.Array, rhs: jax.Array, mesh) -> bool:
+    """The Pallas grouped matmul of ``jax.experimental.pallas.ops.tpu
+    .megablox`` (its grid over row tiles is as long as the group sizes
+    need, so work follows the routed rows) on a TPU where nothing is
+    partitioned; ``jax.lax.ragged_dot`` elsewhere (a Mosaic kernel
+    carries no GSPMD rule).  Both follow the rows on the v5e; at the
+    benchmark cell's shapes the kernel runs at 151-165 TFLOP/s and
+    ``ragged_dot`` at 94 (PERF.md section 6, PR 27)."""
+    if knobs.get_flag("RLA_TPU_DISABLE_PALLAS") \
+            or jax.default_backend() != "tpu":
+        return False
+    if not (mesh is None or mesh.size == 1 or sharding_lib.manual_axes()):
+        return False
+    return (lhs.shape[0] % GMM_ROW_TILE == 0 and lhs.shape[1] % 128 == 0
+            and rhs.shape[2] % 128 == 0)
+
+
+def _megablox():
+    # the package's ``gmm`` attribute is its differentiable function (one
+    # tiling for all three products); the kernels are in the module
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _gmm_call(lhs, rhs, group_sizes, transpose_rhs: bool = False):
+    """One grouped matmul through the kernel, its k and n tiles taken
+    from THIS product's extents (the backward's products swap them)."""
+    backend = _megablox()
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    with jax.named_scope("kernel/moe_gmm"):
+        return backend.gmm(
+            lhs, rhs, group_sizes, lhs.dtype,
+            (GMM_ROW_TILE, _tile(lhs.shape[1]), _tile(n)),
+            transpose_rhs=transpose_rhs)
+
+
+@jax.custom_vjp
+def _gmm(lhs, rhs, group_sizes):
+    return _gmm_call(lhs, rhs, group_sizes)
+
+
+def _gmm_fwd(lhs, rhs, group_sizes):
+    return _gmm_call(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(res, g):
+    """d lhs = g @ rhs^T by group; d rhs[group] = lhs[rows]^T @ g[rows]
+    (the kernel masks the rows of a tile that belong to another group by
+    select, so rows no group owns are never read as numbers)."""
+    backend = _megablox()
+    lhs, rhs, group_sizes = res
+    d_lhs = _gmm_call(g, rhs, group_sizes, transpose_rhs=True)
+    with jax.named_scope("kernel/moe_gmm"):
+        d_rhs = backend.tgmm(
+            lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+            (GMM_ROW_TILE, _tile(lhs.shape[1]), _tile(g.shape[1])))
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   mesh=None) -> jax.Array:
+    """``lhs[rows of group g] @ rhs[g]``: lhs [m, k] with its rows sorted
+    by group, rhs [g, k, n], group_sizes [g] int32 summing to at most m.
+    Rows past the last group are unspecified (the kernel never visits
+    them): the caller masks them."""
+    if _use_gmm_kernel(lhs, rhs, mesh):
+        return _gmm(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
+                 top_k: int, held: Sequence[int], num_experts: int,
+                 norm_topk: bool = True, scale: float = 1.0,
+                 compute_dtype=jnp.bfloat16,
+                 mesh: Optional[jax.sharding.Mesh] = None
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Sparse SwiGLU block over the experts held here.
+
+    Args:
+      x: ``[b, s, d]`` activations.
+      params: ``router`` ``[d, num_experts]``, ``expert_bias``
+        ``[num_experts]``, ``w1`` / ``w3`` ``[len(held), d, f]``, ``w2``
+        ``[len(held), f, d]`` (slot i is expert ``held[i]``).
+
+    The router keeps its full width and its ``top_k`` whatever is held;
+    ``out = sum_{e in sel, e held} w_e * SwiGLU_e(x)``.  Where only some
+    of the experts are held the weights carry no gradient: the router's
+    gradient is a sum over every chip's share (the exchange brings it),
+    and one share alone teaches the router to prefer the experts that
+    answer here, so the load would grow step by step.
+
+    Returns ``(y, stats)`` with ``rows_routed`` (pairs whose expert is
+    held, counted from the selection), ``rows_computed`` (pairs whose
+    output row came back from the grouped matmuls: the mask of the rows
+    they visit, carried through the same cut and the same inverse
+    permutation as their result; fewer than ``rows_routed`` if a buffer
+    or a group were ever cut short), ``load_max_over_mean`` (the fullest
+    held expert's rows over the mean) and ``selected`` ``[b, s, top_k]``,
+    the chosen expert ids.
+    """
+    if mesh is not None and mesh_lib.mesh_axis_size(
+            mesh, mesh_lib.EXPERT_AXIS) > 1:
+        raise NotImplementedError(
+            "dropless_moe (TransformerConfig.moe_router='sigmoid') runs "
+            "the experts held on one chip and has no exchange over the "
+            "`expert` mesh axis yet; set expert=1")
+    b, s, d = x.shape
+    t, n_held, dt = b * s, len(held), compute_dtype
+    rows = x.reshape(t, d)
+    with jax.named_scope("gpt/moe_route"):
+        ids, w = sigmoid_routing(rows, params["router"],
+                                 params["expert_bias"], top_k=top_k,
+                                 norm_topk=norm_topk, scale=scale)
+        # slot of each chosen expert among the held ones; n_held = absent
+        slot_of = np.full((num_experts,), n_held, np.int32)
+        slot_of[list(held)] = np.arange(n_held)
+        slots = jnp.asarray(slot_of)[ids].reshape(-1)          # [t * k]
+        is_held = slots < n_held
+        w = jnp.where(is_held.reshape(t, top_k), w, 0.0)
+        if n_held < num_experts:
+            w = jax.lax.stop_gradient(w)
+    with jax.named_scope("gpt/moe_dispatch"):
+        order = jnp.argsort(slots, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            slots[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+            axis=0, dtype=jnp.int32)
+        n_rows = jnp.sum(group_sizes)
+        xs = _dispatch_rows(rows.astype(dt), order, inverse, top_k)
+        pad = -xs.shape[0] % GMM_ROW_TILE
+        if pad:
+            xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        # rows past the last group (the absent experts' pairs, the pad)
+        # are never visited by the grouped matmuls: whatever the buffer
+        # held is masked on the way out, and by the mask's transpose on
+        # the way back, so neither direction ever reads it
+        computed = (jnp.arange(xs.shape[0]) < n_rows)[:, None]
+
+        def masked(a):
+            return jnp.where(computed, a, 0)
+
+        xs = masked(xs)
+    with jax.named_scope("gpt/moe_experts"):
+        gate = masked(grouped_matmul(xs, params["w1"].astype(dt),
+                                     group_sizes, mesh))
+        up = masked(grouped_matmul(xs, params["w3"].astype(dt),
+                                   group_sizes, mesh))
+        act = (jax.nn.silu(gate) * up).astype(dt)
+        ys = masked(grouped_matmul(act, params["w2"].astype(dt),
+                                   group_sizes, mesh))[:t * top_k]
+    with jax.named_scope("gpt/moe_combine"):
+        pairs = _permute_rows(ys, inverse, order).reshape(t, top_k, d)
+        y = jnp.einsum("tk,tkd->td", w.astype(dt), pairs)
+        came_back = computed[:t * top_k, 0][inverse]
+    stats = {
+        "rows_routed": jnp.sum(is_held).astype(jnp.float32),
+        "rows_computed": jnp.sum(came_back).astype(jnp.float32),
+        "load_max_over_mean": jnp.max(group_sizes) * n_held
+        / jnp.maximum(n_rows, 1).astype(jnp.float32),
+        "selected": ids.reshape(b, s, top_k),
+    }
+    return y.reshape(b, s, d).astype(x.dtype), stats
+
+
+def init_dropless_params(rng, d_model: int, d_ff: int, num_experts: int,
+                         n_held: int) -> Dict[str, jax.Array]:
+    """One layer's router, selection bias and held experts.  The bias is
+    a buffer (no gradient; ``GPT.configure_optimizers`` gives it no
+    optimizer state); it is drawn small so that it moves some selections
+    (the published models start it at zero and steer it by a balancing
+    rule the config gives no rate for)."""
+    kr, kb, k1, k3, k2 = jax.random.split(rng, 5)
+
+    def dense(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+
+    return {
+        "router": dense(kr, (d_model, num_experts), d_model),
+        "expert_bias": 0.01 * jax.random.normal(kb, (num_experts,),
+                                                jnp.float32),
+        "w1": dense(k1, (n_held, d_model, d_ff), d_model),
+        "w3": dense(k3, (n_held, d_model, d_ff), d_model),
+        "w2": dense(k2, (n_held, d_ff, d_model), d_ff),
+    }
+
+
+def dropless_logical_axes() -> Dict[str, Any]:
+    """Logical axis names for an `init_dropless_params` tree (one layer)."""
+    return {
+        "router": (None, None),
+        "expert_bias": (None,),
+        "w1": ("expert", "embed", "mlp"),
+        "w3": ("expert", "embed", "mlp"),
+        "w2": ("expert", "mlp", "embed"),
     }

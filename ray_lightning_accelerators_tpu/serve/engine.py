@@ -294,6 +294,9 @@ class ServeEngine:
 
         from ..utils import compile_cache
         compile_cache.enable()  # before this engine's first compile
+        if hasattr(model, "_uniform_stack_only"):
+            # a conv layer's serving state is not a KV block (ROADMAP R5)
+            model._uniform_stack_only("ServeEngine")
         if model.cfg.sliding_window is not None:
             raise ValueError(
                 "the serve engine needs linear cache slots; "

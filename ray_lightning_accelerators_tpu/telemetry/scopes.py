@@ -15,7 +15,10 @@ Named scopes are metadata only: nothing here runs on the device.
 
 The scope contract (docs/API.md "Named scopes", PERF.md §3):
 ``gpt/embed``, ``gpt/layers``, ``gpt/attn``, ``gpt/mlp``, ``gpt/norm``,
-``gpt/loss``, ``optimizer``, ``guard``, ``exchange``, ``kernel/<name>``.
+``gpt/conv``, ``gpt/moe_route``, ``gpt/moe_dispatch``, ``gpt/moe_experts``,
+``gpt/moe_combine``, ``gpt/loss``, ``optimizer``, ``guard``, ``exchange``,
+``kernel/<name>`` (``flash_fwd``, ``flash_bwd``, ``rms_norm``,
+``q8_matmul``, ``moe_gmm``).
 
 A ``Program`` wraps a jitted callable where it is built and, at its
 first call, keeps the ABSTRACT arguments (shape, dtype, sharding: no

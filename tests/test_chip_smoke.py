@@ -42,6 +42,15 @@ def test_one_chip_phases_rehearsal(out_dir, capsys):
     assert phases == ["train", "generate", "generate", "serve"]
 
 
+def test_lfm2_cell_shapes_phase_rehearsal(capsys):
+    chip_smoke.phase_lfm2_cell_shapes(
+        0, batch=2, seq=64, d_model=64, expert_width=48, experts=8, held=4,
+        top_k=2, iters=1, on_chip=False)
+    out = capsys.readouterr().out
+    assert '"op": "dropless_moe"' in out and '"rows_in": 128' in out
+    assert '"op": "gated_short_conv"' in out
+
+
 def test_four_chip_phase_rehearsal(out_dir, capsys):
     chip_smoke.phase_four_chip(TINY, 0)
     out = capsys.readouterr().out

@@ -1,0 +1,558 @@
+"""A layer stack of several kinds (gated short convolution, GQA with
+QK-norm, dense and sparse SwiGLU) against its plain float32 reference
+(``models/reference_lfm2.py``), at a small size with every kind of layer
+present, on the suite's CPU mesh: logits, loss and gradients; the share
+test (what the chips that share a layer compute adds up to the uncut
+layer); the dropless expert layer under an adversarial router; the
+uniform block's parameter tree and its one ``lax.scan`` left as they
+were; every walker of ``params["layers"]`` refusing a mixed stack by
+name; the scanned epoch carrying the expert layer's counters."""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_lightning_accelerators_tpu import (ArrayDataset, DataLoader,
+                                            RayTPUAccelerator, Trainer)
+from ray_lightning_accelerators_tpu.models import reference_lfm2 as ref
+from ray_lightning_accelerators_tpu.models.transformer import (
+    GPT, TransformerConfig)
+from ray_lightning_accelerators_tpu.ops import moe
+from ray_lightning_accelerators_tpu.ops.conv import gated_short_conv
+from ray_lightning_accelerators_tpu.parallel import mesh as mesh_lib
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+VOCAB, SEQ = 256, 32
+# published layers 1-5 of the pattern, at toy widths: conv + dense, then
+# a whole period (attention + sparse, 3 x conv + sparse)
+MODEL = dict(
+    vocab_size=VOCAB, d_model=64, n_heads=4, n_kv_heads=2, d_ff=160,
+    n_layers=5, max_seq_len=64, tie_embeddings=True, rope_theta=1e6,
+    layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+    conv_kernel=3, moe_router="sigmoid", num_dense_layers=1, num_experts=8,
+    moe_top_k=2, moe_d_ff=48, moe_experts_held=[0, 1, 2, 3],
+    moe_norm_topk=True, moe_routed_scale=1.0, gated_mlp=True, qk_norm=True,
+    rope_style="half", norm_eps=1e-5)
+
+
+def _gpt(**over):
+    model = GPT(TransformerConfig(**{**MODEL, **over}), lr=1e-3)
+    model.compute_dtype = jnp.float32
+    return model
+
+
+@pytest.fixture(scope="module")
+def system():
+    model = _gpt()
+    params = model.init_params(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0, VOCAB)
+    return model, params, tokens
+
+
+@pytest.fixture(scope="module", params=["share", "all"])
+def both_grads(request, system):
+    """The system's and the reference's loss and gradients, on a chip
+    that holds a share of the experts (4 of 8) and on one that holds
+    them all."""
+    model, params, tokens = system
+    cfg = MODEL
+    if request.param == "all":
+        cfg = {**MODEL, "moe_experts_held": None}
+        model = _gpt(moe_experts_held=None)
+        params = model.init_params(jax.random.PRNGKey(0))
+    (loss, _), grads = jax.jit(jax.value_and_grad(
+        lambda p: model.training_step(p, tokens, None), has_aux=True))(
+            params)
+    ref_loss, ref_grads = ref.loss_and_grads(params, tokens, cfg)
+    return float(loss), grads, float(ref_loss), ref_grads, request.param
+
+
+# --------------------------------------------------------------------- #
+# the stack as runs                                                      #
+# --------------------------------------------------------------------- #
+def test_runs_group_consecutive_layers_of_one_kind():
+    assert TransformerConfig(**MODEL).layer_runs() == (
+        ("conv", "dense", 1), ("attn", "sparse", 1), ("conv", "sparse", 3))
+    published = ["conv", "conv", "full_attention"] + [
+        "conv", "conv", "conv", "full_attention"] * 4 + [
+        "conv", "conv", "full_attention", "conv", "conv"]
+    whole = TransformerConfig(**{**MODEL, "n_layers": 24,
+                                 "layer_types": published,
+                                 "num_dense_layers": 2})
+    runs = whole.layer_runs()
+    assert len(runs) == 13 and sum(n for _, _, n in runs) == 24
+    assert runs[0] == ("conv", "dense", 2)
+    assert GPT(whole).scanned_param_subtrees() == tuple(
+        f"layers_{i}" for i in range(13))
+
+
+def test_logits_and_routing_match_the_reference(system):
+    model, params, tokens = system
+    logits, aux = jax.jit(
+        lambda p, t: model.forward(p, t, return_aux=True))(params, tokens)
+    ref_logits, routing = ref.forward(params, tokens, MODEL)
+    assert float(jnp.max(jnp.abs(logits - ref_logits))) < 1e-4 * float(
+        ref_logits.std())
+    assert routing["selected"].shape == (4, 2, SEQ, 2)      # 4 sparse layers
+    assert bool(jnp.all(jnp.sort(aux["moe_selected"], -1)
+                        == routing["selected"]))
+    assert float(aux["moe_rows_routed"]) == float(aux["moe_rows_computed"])
+    assert float(routing["margin"].min()) >= 0.0
+
+
+def test_loss_matches_the_reference(both_grads):
+    loss, _, ref_loss, _, _ = both_grads
+    assert abs(loss - ref_loss) < 1e-5 * abs(ref_loss)
+
+
+@pytest.mark.parametrize("group", ref.GROUPS)
+def test_gradients_match_the_reference(both_grads, group):
+    _, grads, _, ref_grads, held = both_grads
+    norms, ref_norms = (ref.grad_group_norms(grads),
+                        ref.grad_group_norms(ref_grads))
+    if group == "router" and held == "share":
+        # the combine weights carry no gradient where only some experts
+        # are held: one share's router gradient prefers the experts that
+        # answer here (the whole gradient is a sum over the shares)
+        assert norms[group] == ref_norms[group] == 0.0
+        return
+    assert ref_norms[group] > 0
+    assert abs(norms[group] - ref_norms[group]) < 1e-4 * ref_norms[group]
+    # leaf by leaf, for the leaves the group holds
+    holds = {"router": ("router",), "experts": ("w1", "w3", "w2"),
+             "conv": ("conv",), "attention": ("attn",),
+             "dense_mlp": ("layers_0", "mlp"), "embedding": ("embed",)}
+    for (path, g), (_, r) in zip(
+            jax.tree_util.tree_flatten_with_path(grads)[0],
+            jax.tree_util.tree_flatten_with_path(ref_grads)[0]):
+        names = [getattr(k, "key", None) for k in path]
+        if all(n in names for n in holds[group]):
+            assert float(jnp.max(jnp.abs(g - r))) <= 1e-4 * float(
+                jnp.max(jnp.abs(r)) + 1e-12), jax.tree_util.keystr(path)
+
+
+def test_selection_bias_is_a_buffer(both_grads, system):
+    """No gradient, and -- through ``configure_optimizers`` -- no update
+    and no optimizer state."""
+    model, params, _ = system
+    _, grads, _, _, _ = both_grads
+    bias = grads["layers_2"]["mlp"]["expert_bias"]
+    assert float(jnp.max(jnp.abs(bias))) == 0.0
+    tx = model.configure_optimizers()
+    state = tx.init(params)
+    n_params = len(jax.tree.leaves(params))
+    n_bias = sum("expert_bias" in jax.tree_util.keystr(p) for p, _ in
+                 jax.tree_util.tree_flatten_with_path(params)[0])
+    moments = [x for x in jax.tree.leaves(state) if getattr(x, "ndim", 0)]
+    assert n_bias == 2 and len(moments) == 2 * (n_params - n_bias)
+    updates, _ = tx.update(jax.tree.map(jnp.ones_like, params), state,
+                           params)
+    assert float(jnp.max(jnp.abs(
+        updates["layers_1"]["mlp"]["expert_bias"]))) == 0.0
+
+
+# --------------------------------------------------------------------- #
+# the expert layer that is told which experts it holds                   #
+# --------------------------------------------------------------------- #
+def _layer_params(num_experts=8, d=64, f=48, seed=3):
+    return moe.init_dropless_params(jax.random.PRNGKey(seed), d, f,
+                                    num_experts, num_experts)
+
+
+def _slice_experts(p, held):
+    idx = jnp.asarray(held)
+    return {**p, **{w: p[w][idx] for w in ("w1", "w3", "w2")}}
+
+
+@pytest.mark.parametrize("top_k", [2, 4])
+def test_shares_add_up_to_the_uncut_reference_layer(top_k):
+    """Four chips share the layer, two experts each: the partial results
+    of the four shares add up to what the uncut reference gives for the
+    whole layer (nothing is computed alike on every chip: no shared
+    expert), and the shares' rows add up to top_k a token."""
+    p = _layer_params()
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, SEQ, 64), jnp.float32)
+    model = {**MODEL, "moe_top_k": top_k}
+    whole, _, _ = ref.sparse_block(x, p, model, tuple(range(8)))
+    total, rows = jnp.zeros_like(x), 0.0
+    for chip in range(4):
+        held = (2 * chip, 2 * chip + 1)
+        y, stats = moe.dropless_moe(
+            x, _slice_experts(p, held), top_k=top_k, held=held,
+            num_experts=8, compute_dtype=jnp.float32)
+        part, _, _ = ref.sparse_block(x, _slice_experts(p, held), model,
+                                      held)
+        assert float(jnp.max(jnp.abs(y - part))) < 1e-5
+        total, rows = total + y, rows + float(stats["rows_computed"])
+    assert float(jnp.max(jnp.abs(total - whole))) < 1e-5
+    assert rows == 2 * SEQ * top_k
+
+
+@pytest.mark.parametrize("favourite", [0, 3])
+def test_dropless_under_an_adversarial_router(favourite):
+    """Every token's first choice is ONE held expert: the capacity path
+    would drop all but its budget; here the counter says every routed
+    row was computed, and the result is the reference's."""
+    held = (0, 1, 2, 3)
+    p = _slice_experts(_layer_params(), held)
+    # a bias can only steer the selection; make the router itself adore
+    # one expert: a large score whatever the token
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64)))
+    p["router"] = p["router"].at[:, favourite].set(1.0)
+    y, stats = moe.dropless_moe(x, p, top_k=2, held=held, num_experts=8,
+                                compute_dtype=jnp.float32)
+    assert bool(jnp.all(jnp.any(stats["selected"] == favourite, -1)))
+    assert float(stats["rows_routed"]) == float(stats["rows_computed"])
+    assert float(stats["rows_computed"]) >= 2 * SEQ     # one row a token
+    assert float(stats["load_max_over_mean"]) > 2.0
+    want, _, _ = ref.sparse_block(x, p, {**MODEL, "moe_top_k": 2}, held)
+    assert float(jnp.max(jnp.abs(y - want))) < 1e-4 * float(
+        jnp.max(jnp.abs(want)))
+    # the capacity path at the same load keeps its budget and no more
+    cap = moe.expert_capacity(SEQ, 8, 2, 1.25)
+    assert cap < SEQ
+
+
+@pytest.mark.parametrize("mutation", ["no_bias", "unnormalised_topk",
+                                      "scaled"])
+def test_routing_options_change_the_result(mutation):
+    """The reference comparison can tell a missing bias, an unnormalised
+    top-k and a routed scale apart: each moves the layer's output."""
+    held = tuple(range(8))
+    p = _layer_params()
+    p["expert_bias"] = p["expert_bias"] * 30.0   # moves many selections
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, SEQ, 64), jnp.float32)
+    kw = dict(top_k=2, held=held, num_experts=8, compute_dtype=jnp.float32)
+    base, _ = moe.dropless_moe(x, p, **kw)
+    want, _, _ = ref.sparse_block(x, p, {**MODEL, "moe_top_k": 2}, held)
+    assert float(jnp.max(jnp.abs(base - want))) < 1e-5
+    if mutation == "no_bias":
+        other, _ = moe.dropless_moe(
+            x, {**p, "expert_bias": jnp.zeros(8)}, **kw)
+    elif mutation == "unnormalised_topk":
+        other, _ = moe.dropless_moe(x, p, norm_topk=False, **kw)
+    else:
+        other, _ = moe.dropless_moe(x, p, scale=2.0, **kw)
+    assert float(jnp.max(jnp.abs(other - want))) > 1e-2
+
+
+def test_expert_axis_is_refused_by_name():
+    from jax.sharding import Mesh
+    devices = np.asarray(jax.devices()[:2]).reshape(1, 1, 2, 1, 1, 1)
+    names = (mesh_lib.DATA_AXIS, mesh_lib.FSDP_AXIS, mesh_lib.EXPERT_AXIS,
+             mesh_lib.TENSOR_AXIS, mesh_lib.SEQUENCE_AXIS,
+             mesh_lib.PIPELINE_AXIS)
+    mesh = Mesh(devices, names)
+    x = jnp.zeros((2, SEQ, 64))
+    with pytest.raises(NotImplementedError, match="expert"):
+        moe.dropless_moe(x, _layer_params(), top_k=2, held=tuple(range(8)),
+                         num_experts=8, mesh=mesh)
+
+
+# --------------------------------------------------------------------- #
+# the short convolution                                                  #
+# --------------------------------------------------------------------- #
+def _conv_params(d=64, taps=3):
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    return {"w_in": jax.random.normal(ks[0], (d, 3 * d)) * d ** -0.5,
+            "conv_w": jax.random.normal(ks[1], (d, taps)),
+            "w_out": jax.random.normal(ks[2], (d, d)) * d ** -0.5}
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+def test_short_conv_matches_the_reference_and_is_causal(taps):
+    p = _conv_params(taps=taps)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, SEQ, 64))
+    y = gated_short_conv(x, p["w_in"], p["conv_w"], p["w_out"])
+    assert float(jnp.max(jnp.abs(y - ref.conv_operator(x, p)))) < 1e-5
+    # a change at position t leaves every earlier output as it was
+    t = 11
+    y2 = gated_short_conv(x.at[:, t].add(1.0), p["w_in"], p["conv_w"],
+                          p["w_out"])
+    assert float(jnp.max(jnp.abs((y2 - y)[:, :t]))) == 0.0
+    assert float(jnp.max(jnp.abs((y2 - y)[:, t]))) > 0.0
+    # the last tap sits on the current position: t + taps - 1 is the
+    # last position the change reaches
+    assert float(jnp.max(jnp.abs((y2 - y)[:, t + taps - 1]))) > 0.0
+    assert float(jnp.max(jnp.abs((y2 - y)[:, t + taps:]))) == 0.0
+
+
+def test_short_conv_backward_holds_at_8k_tokens():
+    p = _conv_params(d=16)
+    x = jax.random.normal(jax.random.PRNGKey(9), (1, 8192, 16))
+
+    def loss(fn, x_, p_):
+        return jnp.sum(fn(x_, p_) ** 2)
+
+    got = jax.grad(lambda x_, p_: loss(lambda a, b: gated_short_conv(
+        a, b["w_in"], b["conv_w"], b["w_out"]), x_, p_), (0, 1))(x, p)
+    want = jax.grad(lambda x_, p_: loss(ref.conv_operator, x_, p_),
+                    (0, 1))(x, p)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * float(
+            jnp.max(jnp.abs(w)))
+
+
+# --------------------------------------------------------------------- #
+# a config that sets none of the new fields is today's block             #
+# --------------------------------------------------------------------- #
+def _count_scans(jaxpr) -> list:
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _count_scans(sub)
+    return found
+
+
+TODAY = {
+    "dense": (dict(), {
+        "embed": (VOCAB, 64), "ln_f": (64,),
+        "layers/attn/wq": (3, 64, 4, 16), "layers/attn/wk": (3, 64, 4, 16),
+        "layers/attn/wv": (3, 64, 4, 16), "layers/attn/wo": (3, 4, 16, 64),
+        "layers/mlp/wi": (3, 64, 128), "layers/mlp/wo": (3, 128, 64),
+        "layers/ln1": (3, 64), "layers/ln2": (3, 64)}),
+    "gqa_capacity_moe": (dict(n_kv_heads=2, num_experts=4), {
+        "embed": (VOCAB, 64), "ln_f": (64,),
+        "layers/attn/wq": (3, 64, 4, 16), "layers/attn/wk": (3, 64, 2, 16),
+        "layers/attn/wv": (3, 64, 2, 16), "layers/attn/wo": (3, 4, 16, 64),
+        "layers/mlp/router": (3, 64, 4), "layers/mlp/wi": (3, 4, 64, 128),
+        "layers/mlp/wo": (3, 4, 128, 64),
+        "layers/ln1": (3, 64), "layers/ln2": (3, 64)}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TODAY))
+def test_config_without_the_new_fields_builds_todays_tree(case):
+    over, pinned = TODAY[case]
+    cfg = TransformerConfig(vocab_size=VOCAB, d_model=64, n_heads=4,
+                            d_ff=128, n_layers=3, max_seq_len=64, **over)
+    assert cfg.layer_runs() is None
+    model = GPT(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    got = {"/".join(k.key for k in path): leaf.shape for path, leaf in
+           jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert got == pinned
+    assert model.scanned_param_subtrees() == ("layers",)
+    assert jax.tree.structure(model.param_logical_axes(),
+                              is_leaf=lambda x: isinstance(x, tuple)
+                              ) == jax.tree.structure(params)
+    tokens = jnp.zeros((2, SEQ), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p: model.forward(p, tokens))(params)
+    assert _count_scans(jaxpr.jaxpr) == [3]     # one scan over the stack
+    # and the optimizer is the plain one: no partition for a buffer
+    assert len(jax.tree.leaves(model.configure_optimizers().init(
+        {"w": jnp.zeros(3)}))) == 3
+
+
+@pytest.mark.parametrize("field", [
+    "num_dense_layers", "moe_d_ff", "moe_experts_held", "gated_mlp",
+    "qk_norm", "rope_style", "norm_eps"])
+def test_fields_of_the_mixed_stack_are_refused_on_the_uniform_block(field):
+    """Only ``layer_types`` or ``moe_router='sigmoid'`` turns the mixed
+    stack on; a field that only it reads does not rename
+    ``params["layers"]`` by itself, it is refused by name."""
+    with pytest.raises(ValueError, match=field):
+        TransformerConfig(vocab_size=VOCAB, d_model=64, n_heads=4, d_ff=128,
+                          n_layers=3, max_seq_len=64, **{field: MODEL[field]})
+
+
+@pytest.mark.parametrize("field,value", [
+    ("gated_mlp", False), ("qk_norm", False), ("rope_style", "interleaved")])
+def test_mixed_stack_runs_one_block_and_refuses_the_rest(field, value):
+    with pytest.raises(NotImplementedError, match=field):
+        TransformerConfig(**{**MODEL, field: value})
+
+
+def test_mixed_stack_is_one_scan_a_run(system):
+    model, params, tokens = system
+    jaxpr = jax.make_jaxpr(lambda p: model.forward(p, tokens))(params)
+    assert sorted(_count_scans(jaxpr.jaxpr)) == [1, 1, 3]
+    assert jax.tree.structure(
+        model.param_logical_axes(), is_leaf=lambda x: isinstance(x, tuple)
+    ) == jax.tree.structure(params)
+
+
+# --------------------------------------------------------------------- #
+# walkers of params["layers"] refuse a mixed stack by name               #
+# --------------------------------------------------------------------- #
+def _walkers():
+    prompt = jnp.zeros((1, 4), jnp.int32)
+    tok = jnp.zeros((1,), jnp.int32)
+
+    def speculative(m, p):
+        from ray_lightning_accelerators_tpu.models.speculative import (
+            speculative_generate)
+        return speculative_generate(m, p, m, p, prompt, 4)
+
+    def serve(m, p):
+        from ray_lightning_accelerators_tpu.serve import ServeEngine
+        return ServeEngine(m, p, max_slots=2)
+
+    return {
+        "generate": lambda m, p: m.generate(p, prompt, 4),
+        "generate_beam": lambda m, p: m.generate_beam(p, prompt, 4),
+        "_prefill": lambda m, p: m._prefill(p, prompt, 8),
+        "_decode_chunk": lambda m, p: m._decode_chunk(p, None, prompt, 0),
+        "_decode_token": lambda m, p: m._decode_token(p, None, tok, 0),
+        "decode_cache_alloc": lambda m, p: m.decode_cache_alloc(2, 16),
+        "decode_step_rows": lambda m, p: m.decode_step_rows(
+            p, None, tok, tok),
+        "paged_cache_alloc": lambda m, p: m.paged_cache_alloc(4, 16),
+        "decode_step_rows_paged": lambda m, p: m.decode_step_rows_paged(
+            p, None, None, tok, tok),
+        "decode_chunk_paged": lambda m, p: m.decode_chunk_paged(
+            p, None, None, prompt, 0),
+        "quantize_weights": lambda m, p: GPT.quantize_weights(p),
+        "speculative_generate": speculative,
+        "ServeEngine": serve,
+    }
+
+
+@pytest.mark.parametrize("walker", sorted(_walkers()))
+def test_walkers_of_the_uniform_stack_refuse_by_name(system, walker):
+    model, params, _ = system
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        _walkers()[walker](model, params)
+
+
+def test_pipeline_and_dropout_refuse_a_mixed_stack(system):
+    from jax.sharding import Mesh
+    model, params, tokens = system
+    with pytest.raises(NotImplementedError, match="dropout"):
+        _gpt(dropout=0.1).training_step(params, tokens,
+                                        jax.random.PRNGKey(0))
+    names = (mesh_lib.DATA_AXIS, mesh_lib.FSDP_AXIS, mesh_lib.EXPERT_AXIS,
+             mesh_lib.TENSOR_AXIS, mesh_lib.SEQUENCE_AXIS,
+             mesh_lib.PIPELINE_AXIS)
+    piped = _gpt()
+    piped.mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(
+        1, 1, 1, 1, 1, 2), names)
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        piped.forward(params, tokens)
+    with pytest.raises(NotImplementedError, match="moe_router"):
+        TransformerConfig(**{**MODEL, "moe_router": "softmax"})
+
+
+# --------------------------------------------------------------------- #
+# the normal path: Trainer.fit, the scanned epoch                        #
+# --------------------------------------------------------------------- #
+def _tokens(n=32):
+    p = np.arange(1, VOCAB + 1, dtype=np.float64) ** -1.0
+    return np.random.default_rng(0).choice(
+        VOCAB, p=p / p.sum(), size=(n, SEQ)).astype(np.int32)
+
+
+def _fit(tmpdir, model, **accel):
+    trainer = Trainer(max_epochs=3, precision="f32", seed=0,
+                      enable_checkpointing=False, log_every_n_steps=1,
+                      default_root_dir=str(tmpdir),
+                      accelerator=RayTPUAccelerator(**accel))
+    trainer.fit(model, DataLoader(ArrayDataset(_tokens()), batch_size=8,
+                                  shuffle=False))
+    return trainer
+
+
+@pytest.fixture
+def device_cache(monkeypatch):
+    """The device-resident data set (and with it the scanned epoch) is
+    off on the CPU unless asked for."""
+    monkeypatch.setattr(Trainer, "_CACHE_AUTO_ON_CPU", True)
+
+
+def test_scanned_epoch_carries_the_counters_out_with_the_loss(
+        tmpdir, device_cache):
+    model = _gpt(remat=True)
+    start = jax.device_get(model.init_params(jax.random.PRNGKey(0)))
+    model.params = start
+    trainer = _fit(tmpdir, model, num_workers=1)
+    # the whole-epoch scan ran (a Program keeps its arguments' shapes
+    # from its first call) and the per-step program never did
+    assert trainer._epoch_scan_fn.args is not None
+    assert trainer._train_step_cached_fn.args is None
+    rows = [r for r in trainer.logger.history if "moe_rows_routed" in r]
+    assert len(rows) == 12 and all("train_loss" in r for r in rows)
+    assert all(r["moe_rows_routed"] == r["moe_rows_computed"] > 0
+               for r in rows)
+    assert all(r["moe_load_max_over_mean"] >= 1.0 for r in rows)
+    losses = [r["train_loss"] for r in rows]
+    assert sum(losses[-4:]) < sum(losses[:4])
+    end = jax.device_get(trainer._state.params)
+    # the buffer stayed; its neighbours moved
+    assert np.array_equal(end["layers_2"]["mlp"]["expert_bias"],
+                          start["layers_2"]["mlp"]["expert_bias"])
+    assert not np.array_equal(end["layers_2"]["mlp"]["w1"],
+                              start["layers_2"]["mlp"]["w1"])
+
+
+def test_train_step_carries_the_new_scopes(tmpdir, device_cache):
+    """``gpt/conv`` and the four ``gpt/moe_*`` scopes, forward, remat's
+    second forward and backward, from the compiled text alone."""
+    from ray_lightning_accelerators_tpu.telemetry import scopes
+
+    model = _gpt(remat=True)
+    model.params = jax.device_get(model.init_params(jax.random.PRNGKey(0)))
+    trainer = _fit(tmpdir, model, num_workers=1)
+    trainer.teardown()
+    names = set(scopes.scope_table("epoch_scan").values())
+    for scope in ("conv", "moe_route", "moe_dispatch", "moe_experts",
+                  "moe_combine", "attn", "mlp", "layers"):
+        passes = {("bwd" if "transpose(" in n else "fwd")
+                  if "rematted_computation" not in n else "recompute"
+                  for n in names if f"/gpt/{scope}/" in f"/{n}/"
+                  or f"(gpt/{scope})" in n}
+        # the scan itself is not rematted; on a chip that holds a share
+        # of the experts the combine weights carry no gradient, so the
+        # route has no backward and the backward needs no second combine
+        want = {"layers": {"fwd", "bwd"},
+                "moe_route": {"fwd", "recompute"},
+                "moe_combine": {"fwd", "bwd"}}.get(
+                    scope, {"fwd", "bwd", "recompute"})
+        assert passes >= want, (scope, passes)
+
+
+@pytest.mark.parametrize("gather_mode", ["tree", "scan"])
+def test_fsdp_places_every_run_and_trains(tmpdir, device_cache,
+                                          gather_mode):
+    """FSDP sees more than one stacked subtree: each run's large leaves
+    are sharded, the in-scan gather takes each run by its key, and the
+    loss is the one-device loss."""
+    model = _gpt()
+    model.params = jax.device_get(model.init_params(jax.random.PRNGKey(0)))
+    one = _fit(tmpdir.join("one"), model, num_workers=1)
+    model = _gpt()
+    model.params = jax.device_get(model.init_params(jax.random.PRNGKey(0)))
+    trainer = Trainer(max_epochs=3, precision="f32", seed=0,
+                      enable_checkpointing=False, log_every_n_steps=1,
+                      default_root_dir=str(tmpdir.join("four")),
+                      gather_mode=gather_mode,
+                      grad_compression="int8" if gather_mode == "scan"
+                      else None,
+                      accelerator=RayTPUAccelerator(num_workers=4,
+                                                    use_fsdp=True))
+    trainer.fit(model, DataLoader(ArrayDataset(_tokens()), batch_size=8,
+                                  shuffle=False))
+    params = trainer._state.params
+    for key in ("layers_0", "layers_1", "layers_2"):
+        assert any(not leaf.sharding.is_fully_replicated
+                   for leaf in jax.tree.leaves(params[key])), key
+    a = float(one.callback_metrics["train_loss"])
+    b = float(trainer.callback_metrics["train_loss"])
+    assert abs(a - b) < (5e-2 if gather_mode == "scan" else 1e-3) * a
+
+
+# --------------------------------------------------------------------- #
+# the benchmark's copy of the reference                                  #
+# --------------------------------------------------------------------- #
+def test_the_two_reference_files_are_one_text():
+    with open(os.path.join(ROOT, "ray_lightning_accelerators_tpu", "models",
+                           "reference_lfm2.py")) as f:
+        ours = f.read()
+    with open(os.path.join(ROOT, "benchmark", "lib",
+                           "reference_lfm2.py")) as f:
+        assert f.read() == ours

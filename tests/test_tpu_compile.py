@@ -125,6 +125,39 @@ def test_norm_kernels_compile(one_chip, chip_dispatch):
     ln.compile()
 
 
+def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
+        one_chip, chip_dispatch):
+    """``train-lfm2-moe-8k``: 32,768 rows, 8 of 32 experts held, each
+    2048 x 1792, top-4 -- forward and backward hold the grouped-matmul
+    kernel, never ``ragged_dot``, and fit the chip."""
+    from ray_lightning_accelerators_tpu.ops import moe
+
+    held = tuple(range(8))
+    p = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: moe.init_dropless_params(
+            k, 2048, 1792, 32, 8), jax.random.PRNGKey(0)))
+    x = _sds((4, 8192, 2048), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        y, stats = moe.dropless_moe(x, p, top_k=4, held=held,
+                                    num_experts=32)
+        return y.astype(jnp.float32).sum(), stats["rows_computed"]
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)
+                      ).lower(p, x)
+    # megablox's gmm and tgmm both call their body ``kernel``; the scope
+    # ``kernel/moe_gmm`` is what tells them apart from other kernels
+    assert _kernels(lowered) == ["kernel"]
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") >= 3   # gmm, its transpose, tgmm
+    assert "stablehlo.ragged_dot" not in text
+    assert "kernel/moe_gmm" in lowered.as_text(debug_info=True)
+    mem = lowered.compile().memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES // 2
+
+
 @pytest.mark.parametrize("rows", [4, 57, 1000])
 def test_rms_norm_compiles_at_row_counts_off_the_sublane_tile(
         one_chip, chip_dispatch, rows):
