@@ -352,8 +352,10 @@ def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
     sequence (the reference's float32 intermediates of four do not fit
     beside it), the timing on the whole batch; both get float32 inputs,
     so the router (float32, full precision on both sides) chooses alike
-    and the difference is bfloat16 arithmetic alone.  Prints rows and
-    milliseconds per call."""
+    and the difference is bfloat16 arithmetic alone.  The expert layer
+    runs twice: as drawn (one window of its sorted pairs holds every
+    routed row) and crowded onto two held experts (two windows).  Prints
+    rows and milliseconds per call."""
     import jax
     import jax.numpy as jnp
 
@@ -384,7 +386,7 @@ def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
         both = jax.jit(lambda p, x_, g_: jax.vjp(system, p, x_)[1](g_))
         if on_chip:
             names = kernels_in(fwd.lower(params, x))
-            require(name != "dropless_moe" or names,
+            require(not name.startswith("dropless_moe") or names,
                     "the expert layer lowered without its kernel")
             fields["kernels"] = names
         y, vjp = jax.vjp(reference, params, x[:1])
@@ -402,16 +404,32 @@ def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
 
     p = moe.init_dropless_params(next(keys), d_model, expert_width, experts,
                                  held)
-    stats = jax.jit(lambda p_, x_: moe.dropless_moe(
-        x_, p_, top_k=top_k, held=ids, num_experts=experts)[1])(p, x)
-    require(float(stats["rows_routed"]) == float(stats["rows_computed"]),
-            f"a routed row was not computed: {stats}")
-    check("dropless_moe",
-          lambda p_, x_: moe.dropless_moe(
-              x_, p_, top_k=top_k, held=ids, num_experts=experts)[0],
-          lambda p_, x_: ref.sparse_block(x_, p_, model, ids)[0], p,
-          rows_in=batch * seq, rows_routed=float(stats["rows_routed"]),
-          load_max_over_mean=float(stats["load_max_over_mean"]))
+    # a selection bias that sends every token to two held experts: the
+    # rows pass one window of the sorted pairs, so the loop behind
+    # window 0 compiles and runs on the chip too
+    crowded = {**p, "expert_bias": p["expert_bias"].at[:2].add(2.0)}
+    window = moe.window_rows(batch * seq * top_k, held, experts)
+
+    def layer(p_, x_):
+        return moe.dropless_moe(x_, p_, top_k=top_k, held=ids,
+                                num_experts=experts)
+
+    counters = jax.jit(lambda p_, x_: layer(p_, x_)[1])
+    for name, p_, at_the_cell in (("dropless_moe", p, 1),
+                                  ("dropless_moe_overflow", crowded, 2)):
+        stats = counters(p_, x)
+        rounds = max(1, -(-int(stats["rows_routed"]) // window))
+        require(float(stats["rows_routed"]) == float(stats["rows_computed"])
+                and float(stats["rounds"]) == rounds,
+                f"{name}: a routed row was not computed, or not in "
+                f"{rounds} window(s): {stats}")
+        require(not on_chip or rounds == at_the_cell,
+                f"{name}: {rounds} window(s) at the cell's shapes")
+        check(name, lambda p_, x_: layer(p_, x_)[0],
+              lambda p_, x_: ref.sparse_block(x_, p_, model, ids)[0], p_,
+              rows_in=batch * seq, rows_routed=float(stats["rows_routed"]),
+              rounds=float(stats["rounds"]),
+              load_max_over_mean=float(stats["load_max_over_mean"]))
 
     c = {"w_in": jax.random.normal(next(keys), (d_model, 3 * d_model))
          * d_model ** -0.5,

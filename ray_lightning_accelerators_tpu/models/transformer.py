@@ -766,9 +766,9 @@ class GPT(TpuModule):
     def _run_stacks(self, params, h, runs):
         """The mixed stack: one ``lax.scan`` (with remat as the uniform
         stack has it) per run, in order.  Returns ``(h, stats)`` with the
-        sparse layers' counters summed over layers (``load_max_over_
-        mean`` averaged) and their chosen expert ids, empty without a
-        sparse layer."""
+        sparse layers' counters summed over layers (``rounds`` and
+        ``load_max_over_mean`` averaged) and their chosen expert ids,
+        empty without a sparse layer."""
         pos = jnp.arange(h.shape[1])
         per_layer = []
         for i, (op, ff, _) in enumerate(runs):
@@ -794,6 +794,7 @@ class GPT(TpuModule):
         return h, {
             "moe_rows_routed": jnp.sum(stats["rows_routed"]),
             "moe_rows_computed": jnp.sum(stats["rows_computed"]),
+            "moe_rounds": jnp.mean(stats["rounds"]),
             "moe_load_max_over_mean": jnp.mean(
                 stats["load_max_over_mean"]),
             # [sparse layers, b, s, top_k]: for a comparison of the

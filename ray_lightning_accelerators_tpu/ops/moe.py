@@ -24,7 +24,13 @@ The dropless path (``dropless_moe``, below) is told which experts it
 holds: sigmoid scores with a selection bias, the (token, choice) pairs
 sorted by expert, one grouped matrix product per projection over the rows
 routed to the held experts, nothing dropped at any imbalance.  It runs
-without an exchange: what absent experts would add is left out.
+without an exchange: what absent experts would add is left out.  The
+sorted pairs are walked in windows (``window_rows``: the held experts'
+nominal share of the pairs and half as much again), so the buffers of
+the sorted side follow the share held, not ``tokens x top_k``; window 0
+holds every routed row unless the load is far over nominal, the loop
+runs only as many windows as the rows reach, and ``stats["rounds"]``
+(logged as ``moe_rounds``) says how many ran.
 """
 
 from __future__ import annotations
@@ -200,41 +206,66 @@ def sigmoid_routing(x: jax.Array, router: jax.Array, bias: jax.Array, *,
     return ids.astype(jnp.int32), w * scale
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(x, order, inverse, top_k):
-    """Row ``order[i] // top_k`` of ``x`` for every sorted pair i.  The
-    pairs are a permutation of (token, choice), so the transpose is a
-    gather by the inverse permutation and a sum over the choices, not a
-    scatter-add."""
-    return x[order // top_k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_rows(x, pairs, pos, live, top_k):
+    """Row ``pairs[i] // top_k`` of ``x`` for the sorted pairs of one
+    window.  Every (token, choice) pair sits at one sorted position, so
+    the transpose is a gather by that position (``pos``, clipped to the
+    window; ``live`` where the window holds the pair) and a sum over the
+    choices, not a scatter-add."""
+    return x[pairs // top_k]
 
 
-def _dispatch_fwd(x, order, inverse, top_k):
-    return x[order // top_k], (inverse, x.shape[0])
+def _dispatch_fwd(x, pairs, pos, live, top_k):
+    return x[pairs // top_k], (pos, live, x.shape[0])
 
 
 def _dispatch_bwd(top_k, res, g):
-    inverse, t = res
-    return (g[inverse].reshape(t, top_k, -1).sum(1).astype(g.dtype),
-            None, None)
+    pos, live, t = res
+    g = jnp.where(live[:, None], g[pos], 0)
+    return (g.reshape(t, top_k, -1).sum(1).astype(g.dtype), None, None,
+            None)
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+def _rows_of_pairs(ys, w, pos):
+    """[t, top_k, d]: the row at every pair's (clipped) position."""
+    return ys[pos].reshape(*w.shape, -1)
+
+
 @jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation: transposed by its inverse."""
-    return x[perm]
+def _combine_rows(ys, w, pairs, pos):
+    """``y[t] = sum_k w[t, k] * ys[pos[t * top_k + k]]``: the window's
+    output rows back at their tokens (``w`` is zero where the window
+    does not hold the pair).  Transposed on the sorted side:
+    ``d ys[i] = w[pair i] * g[token of pair i]``, a gather of the
+    window's rows from ``[t, d]``."""
+    return jnp.einsum("tk,tkd->td", w, _rows_of_pairs(ys, w, pos))
 
 
-_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
-                     lambda res, g: (g[res[1]], None, None))
+def _combine_fwd(ys, w, pairs, pos):
+    return _combine_rows(ys, w, pairs, pos), (ys, w, pairs, pos)
+
+
+def _combine_bwd(res, g):
+    ys, w, pairs, pos = res
+    d_ys = w.reshape(-1)[pairs][:, None] * g[pairs // w.shape[1]]
+    d_w = jnp.einsum("td,tkd->tk", g, _rows_of_pairs(ys, w, pos))
+    return d_ys, d_w, None, None
+
+
+_combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 # m, k, n tile of the grouped-matmul kernel; the row tile is also what a
 # group's ragged end is padded to, so `moe_load_max_over_mean` explains
 # the padding.  Chosen on the v5e (PERF.md section 6, PR 27).
 GMM_ROW_TILE = 512
+# A window of the sorted pairs holds this many times the held experts'
+# nominal share of them (PERF.md section 6, PR 28: the largest
+# rows-a-layer seen on the chip against it).
+WINDOW_SPARE = 1.5
 
 
 def _tile(extent: int, limit: int = 1024) -> int:
@@ -320,6 +351,114 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
+def window_rows(pairs: int, n_held: int, num_experts: int) -> int:
+    """Rows of one window of the sorted (token, choice) pairs: the held
+    experts' nominal share of them with ``WINDOW_SPARE`` over it, in
+    whole row tiles; all the pairs where that is no fewer."""
+    spare = math.ceil(WINDOW_SPARE * pairs * n_held / num_experts)
+    return min(pairs, -(-spare // GMM_ROW_TILE) * GMM_ROW_TILE)
+
+
+def _window(diff, ints, start, *, m: int, top_k: int, mesh):
+    """The held experts' SwiGLU over sorted positions ``[start, start +
+    m)`` and what those rows add to every token: ``(y [t, d], pairs
+    whose output row came back)``.
+
+    diff: ``rows`` [t, d], ``w`` [t, top_k] (zero for a pair whose
+    expert is absent), ``w1`` / ``w3`` / ``w2``, all in the compute
+    dtype but ``w``.  ints: ``order`` (the pairs by sorted position, at
+    least ``start + m`` long), ``inverse`` (the sorted position of every
+    pair), ``first`` / ``last`` (each held expert's interval of sorted
+    positions), ``n_rows`` (pairs whose expert is held: they sort
+    first)."""
+    rows, w, w1, w3, w2 = diff
+    order, inverse, first, last, n_rows = ints
+    t, dt = rows.shape[0], rows.dtype
+    with jax.named_scope("gpt/moe_dispatch"):
+        group_sizes = (jnp.clip(last, start, start + m)
+                       - jnp.clip(first, start, start + m))
+        pairs = jax.lax.dynamic_slice_in_dim(order, start, m)
+        pos = inverse - start
+        live = (pos >= 0) & (pos < jnp.minimum(m, n_rows - start))
+        pos = jnp.clip(pos, 0, m - 1)
+        xs = _dispatch_rows(rows, pairs, pos, live, top_k)
+        pad = -m % GMM_ROW_TILE
+        if pad:
+            xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        # rows past the window's last group (the absent experts' pairs,
+        # the pad) are never visited by the grouped matmuls: whatever
+        # the buffer held is masked on the way out, and by the mask's
+        # transpose on the way back, so neither direction ever reads it
+        computed = (jnp.arange(m + pad) < jnp.sum(group_sizes))[:, None]
+
+        def masked(a):
+            return jnp.where(computed, a, 0)
+
+        xs = masked(xs)
+    with jax.named_scope("gpt/moe_experts"):
+        gate = masked(grouped_matmul(xs, w1, group_sizes, mesh))
+        up = masked(grouped_matmul(xs, w3, group_sizes, mesh))
+        act = (jax.nn.silu(gate) * up).astype(dt)
+        ys = masked(grouped_matmul(act, w2, group_sizes, mesh))[:m]
+    with jax.named_scope("gpt/moe_combine"):
+        y = _combine_rows(
+            ys, jnp.where(live.reshape(t, top_k), w, 0.0).astype(dt),
+            pairs, pos)
+        # live: from the selection's count; computed: from the groups
+        # the matmuls were given.  They agree unless a window is cut
+        came_back = jnp.sum(live & computed[pos, 0], dtype=jnp.int32)
+    return y, came_back
+
+
+def _rounds(n_rows, m: int):
+    """Windows of ``m`` sorted rows that hold a routed row; one at
+    least."""
+    return jnp.maximum(1, -(-n_rows // m))
+
+
+def _sum_windows(one, m, n_rows, like):
+    """``one(start)`` summed over the windows of ``m`` sorted rows that
+    hold a routed row (one at least), from zeros shaped ``like``."""
+    def body(i, acc):
+        return jax.tree.map(jnp.add, acc, one(i * m))
+
+    return jax.lax.fori_loop(0, _rounds(n_rows, m), body,
+                             jax.tree.map(jnp.zeros_like, like))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk_windows(window, m, diff, ints):
+    """``window(diff, ints, start)`` summed over the windows that hold a
+    routed row: ``(y, came_back, rounds)``.  Window 0 holds them all
+    unless the load is far over nominal; the loop runs as many as the
+    count asks for, so nothing is ever dropped, and a window that does
+    not run writes no buffer in either direction: the backward is the
+    same loop over each window's transpose, its forward computed again
+    there, one window's residuals alive at a time."""
+    y, came_back = _sum_windows(
+        lambda start: window(diff, ints, start), m, ints[-1],
+        jax.eval_shape(window, diff, ints, 0))
+    return y, came_back, _rounds(ints[-1], m)
+
+
+def _walk_fwd(window, m, diff, ints):
+    return _walk_windows(window, m, diff, ints), (diff, ints)
+
+
+def _walk_bwd(window, m, res, g):
+    diff, ints = res
+
+    def transposed(start):
+        _, vjp, _ = jax.vjp(lambda *d: window(d, ints, start), *diff,
+                            has_aux=True)
+        return vjp(g[0])
+
+    return _sum_windows(transposed, m, ints[-1], diff), None
+
+
+_walk_windows.defvjp(_walk_fwd, _walk_bwd)
+
+
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
                  top_k: int, held: Sequence[int], num_experts: int,
                  norm_topk: bool = True, scale: float = 1.0,
@@ -341,14 +480,22 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
     and one share alone teaches the router to prefer the experts that
     answer here, so the load would grow step by step.
 
+    The (token, choice) pairs are sorted by expert, the held experts'
+    first, and walked in windows of ``window_rows`` sorted rows: every
+    buffer on the sorted side has a window's rows, not ``tokens x
+    top_k``.  With every expert held (or tiny shapes) one window is all
+    the pairs and the program has no loop.
+
     Returns ``(y, stats)`` with ``rows_routed`` (pairs whose expert is
     held, counted from the selection), ``rows_computed`` (pairs whose
     output row came back from the grouped matmuls: the mask of the rows
     they visit, carried through the same cut and the same inverse
-    permutation as their result; fewer than ``rows_routed`` if a buffer
-    or a group were ever cut short), ``load_max_over_mean`` (the fullest
-    held expert's rows over the mean) and ``selected`` ``[b, s, top_k]``,
-    the chosen expert ids.
+    permutation as their result, summed over the windows that ran; fewer
+    than ``rows_routed`` if a window or a group were ever cut short),
+    ``rounds`` (the windows that ran: 1 unless the load was over
+    ``WINDOW_SPARE`` times nominal), ``load_max_over_mean`` (the fullest
+    held expert's rows over the mean) and ``selected`` ``[b, s,
+    top_k]``, the chosen expert ids.
     """
     if mesh is not None and mesh_lib.mesh_axis_size(
             mesh, mesh_lib.EXPERT_AXIS) > 1:
@@ -371,42 +518,32 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
         w = jnp.where(is_held.reshape(t, top_k), w, 0.0)
         if n_held < num_experts:
             w = jax.lax.stop_gradient(w)
+    m = window_rows(t * top_k, n_held, num_experts)
+    n_windows = -(-t * top_k // m)
     with jax.named_scope("gpt/moe_dispatch"):
         order = jnp.argsort(slots, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
         group_sizes = jnp.sum(
             slots[:, None] == jnp.arange(n_held, dtype=jnp.int32),
             axis=0, dtype=jnp.int32)
-        n_rows = jnp.sum(group_sizes)
-        xs = _dispatch_rows(rows.astype(dt), order, inverse, top_k)
-        pad = -xs.shape[0] % GMM_ROW_TILE
-        if pad:
-            xs = jnp.pad(xs, ((0, pad), (0, 0)))
-        # rows past the last group (the absent experts' pairs, the pad)
-        # are never visited by the grouped matmuls: whatever the buffer
-        # held is masked on the way out, and by the mask's transpose on
-        # the way back, so neither direction ever reads it
-        computed = (jnp.arange(xs.shape[0]) < n_rows)[:, None]
-
-        def masked(a):
-            return jnp.where(computed, a, 0)
-
-        xs = masked(xs)
-    with jax.named_scope("gpt/moe_experts"):
-        gate = masked(grouped_matmul(xs, params["w1"].astype(dt),
-                                     group_sizes, mesh))
-        up = masked(grouped_matmul(xs, params["w3"].astype(dt),
-                                   group_sizes, mesh))
-        act = (jax.nn.silu(gate) * up).astype(dt)
-        ys = masked(grouped_matmul(act, params["w2"].astype(dt),
-                                   group_sizes, mesh))[:t * top_k]
-    with jax.named_scope("gpt/moe_combine"):
-        pairs = _permute_rows(ys, inverse, order).reshape(t, top_k, d)
-        y = jnp.einsum("tk,tkd->td", w.astype(dt), pairs)
-        came_back = computed[:t * top_k, 0][inverse]
+        last = jnp.cumsum(group_sizes)
+        n_rows = last[-1]
+        # the last window may reach past the pairs: it reads pair 0
+        # there, on rows no group owns
+        order = jnp.pad(order, (0, n_windows * m - t * top_k))
+    window = functools.partial(_window, m=m, top_k=top_k, mesh=mesh)
+    diff = (rows.astype(dt), w) + tuple(
+        params[name].astype(dt) for name in ("w1", "w3", "w2"))
+    ints = (order, inverse, last - group_sizes, last, n_rows)
+    if n_windows == 1:
+        y, came_back = window(diff, ints, 0)
+        rounds = jnp.ones((), jnp.int32)
+    else:
+        y, came_back, rounds = _walk_windows(window, m, diff, ints)
     stats = {
         "rows_routed": jnp.sum(is_held).astype(jnp.float32),
-        "rows_computed": jnp.sum(came_back).astype(jnp.float32),
+        "rows_computed": came_back.astype(jnp.float32),
+        "rounds": rounds.astype(jnp.float32),
         "load_max_over_mean": jnp.max(group_sizes) * n_held
         / jnp.maximum(n_rows, 1).astype(jnp.float32),
         "selected": ids.reshape(b, s, top_k),

@@ -48,6 +48,7 @@ def test_lfm2_cell_shapes_phase_rehearsal(capsys):
         top_k=2, iters=1, on_chip=False)
     out = capsys.readouterr().out
     assert '"op": "dropless_moe"' in out and '"rows_in": 128' in out
+    assert '"op": "dropless_moe_overflow"' in out
     assert '"op": "gated_short_conv"' in out
 
 

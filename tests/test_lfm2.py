@@ -217,6 +217,107 @@ def test_dropless_under_an_adversarial_router(favourite):
     assert cap < SEQ
 
 
+def _full_buffer_layer(x, p, top_k, held, num_experts):
+    """The layer over ONE buffer of all ``tokens x top_k`` sorted pairs,
+    plain ``jnp`` under ordinary AD: what the windows must add up to."""
+    t, n_held = x.shape[0] * x.shape[1], len(held)
+    rows = x.reshape(t, -1)
+    ids, w = moe.sigmoid_routing(rows, p["router"], p["expert_bias"],
+                                 top_k=top_k, norm_topk=True, scale=1.0)
+    slot_of = np.full((num_experts,), n_held, np.int32)
+    slot_of[list(held)] = np.arange(n_held)
+    slots = jnp.asarray(slot_of)[ids].reshape(-1)
+    w = jnp.where((slots < n_held).reshape(t, top_k), w, 0.0)
+    if n_held < num_experts:
+        w = jax.lax.stop_gradient(w)
+    order = jnp.argsort(slots, stable=True)
+    sizes = jnp.sum(slots[:, None] == jnp.arange(n_held), 0, jnp.int32)
+    routed = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+    xs = rows[order // top_k]
+    act = jnp.where(routed, jax.nn.silu(
+        jax.lax.ragged_dot(xs, p["w1"], sizes))
+        * jax.lax.ragged_dot(xs, p["w3"], sizes), 0.0)
+    ys = jnp.where(routed, jax.lax.ragged_dot(act, p["w2"], sizes), 0.0)
+    pairs = ys[jnp.argsort(order)].reshape(t, top_k, -1)
+    return jnp.einsum("tk,tkd->td", w, pairs).reshape(x.shape)
+
+
+# 1024 tokens, top-2, 2 of 16 experts held: 2,048 pairs in windows of 512
+# rows (1.5 x the nominal 256, in whole row tiles), four windows at most
+WINDOWED = dict(tokens=1024, top_k=2, num_experts=16, held=(9, 3))
+
+
+def _steered(both: int, one: int):
+    """Tokens and a router that send ``both`` tokens to the two held
+    experts, ``one`` to one held and one absent expert and the rest to
+    two absent ones: ``2 * both + one`` routed rows, whatever the
+    noise."""
+    t, (h0, h1) = WINDOWED["tokens"], WINDOWED["held"]
+    kind = np.full((t,), 2)
+    kind[:both], kind[both:both + one] = 0, 1
+    kind = np.random.default_rng(8).permutation(kind)
+    x = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (1, t, 64))
+    x = x.at[0, :, :3].set(8.0 * jnp.asarray(np.eye(3)[kind]))
+    router = 0.05 * jax.random.normal(jax.random.PRNGKey(10), (64, 16))
+    router = router.at[:3].set(0.0)
+    for feature, experts in enumerate([(h0, h1), (h1, 5), (5, 12)]):
+        router = router.at[feature, list(experts)].set(1.0)
+    return x, router
+
+
+@pytest.mark.parametrize("case,steer,rounds", [
+    ("balanced", None, 1), ("window_full", (156, 200), 1),
+    ("one_over", (156, 201), 2), ("adversarial", (1024, 0), 4),
+    ("all_held", None, 1)])
+def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds):
+    """The expert layer walks its sorted pairs in windows; whatever the
+    load, output and every gradient are those of the one-buffer
+    formulation and of the reference's layer, every routed pair's row
+    comes back, and ``rounds`` says how many windows ran: one at a
+    balanced load and at exactly a window's rows, two at one row more,
+    all four under a router that sends every pair to the held experts
+    (the capacity path would drop 7 of 8 there).  With every expert
+    held the window is the buffer and the program has no loop."""
+    top_k, num_experts = WINDOWED["top_k"], WINDOWED["num_experts"]
+    held = tuple(range(16)) if case == "all_held" else WINDOWED["held"]
+    p = _slice_experts(_layer_params(num_experts), held)
+    x = jax.random.normal(jax.random.PRNGKey(11), (1, 1024, 64))
+    if steer is not None:
+        x, p["router"] = _steered(*steer)
+    assert moe.window_rows(2048, len(held), num_experts) == (
+        2048 if case == "all_held" else 512)
+    kw = dict(top_k=top_k, held=held, num_experts=num_experts)
+    model = {**MODEL, "moe_top_k": top_k, "num_experts": num_experts}
+    g = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+
+    def system(p_, x_):
+        y, stats = moe.dropless_moe(x_, p_, compute_dtype=jnp.float32, **kw)
+        return y, stats
+
+    y, vjp, stats = jax.vjp(system, p, x, has_aux=True)
+    assert float(stats["rows_routed"]) == float(stats["rows_computed"])
+    if steer is not None:
+        assert float(stats["rows_routed"]) == 2 * steer[0] + steer[1]
+    assert float(stats["rounds"]) == rounds
+    loops = [w for w in ("while", "cond")
+             if w + "[" in str(jax.make_jaxpr(system)(p, x))]
+    assert loops == ([] if case == "all_held" else ["while"])
+    for name, plain in [
+            ("full_buffer", lambda p_, x_: _full_buffer_layer(x_, p_, **kw)),
+            ("reference", lambda p_, x_: ref.sparse_block(
+                x_, p_, model, held)[0])]:
+        want, want_vjp = jax.vjp(plain, p, x)
+        assert float(jnp.max(jnp.abs(y - want))) < 1e-5 * float(
+            jnp.max(jnp.abs(want))), (name, "y")
+        (dp, dx), (want_dp, want_dx) = vjp(g), want_vjp(g)
+        names = ["w1", "w3", "w2"] + ["router"] * (case == "all_held")
+        for leaf, a, b in [("x", dx, want_dx)] + [
+                (n, dp[n], want_dp[n]) for n in names]:
+            assert float(jnp.max(jnp.abs(b))) > 0, (name, leaf)
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * float(
+                jnp.max(jnp.abs(b))), (name, leaf)
+
+
 @pytest.mark.parametrize("mutation", ["no_bias", "unnormalised_topk",
                                       "scaled"])
 def test_routing_options_change_the_result(mutation):
@@ -480,6 +581,8 @@ def test_scanned_epoch_carries_the_counters_out_with_the_loss(
     assert all(r["moe_rows_routed"] == r["moe_rows_computed"] > 0
                for r in rows)
     assert all(r["moe_load_max_over_mean"] >= 1.0 for r in rows)
+    # 128 pairs a layer are one window: every sparse layer ran one
+    assert all(r["moe_rounds"] == 1.0 for r in rows)
     losses = [r["train_loss"] for r in rows]
     assert sum(losses[-4:]) < sum(losses[:4])
     end = jax.device_get(trainer._state.params)

@@ -129,7 +129,12 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
         one_chip, chip_dispatch):
     """``train-lfm2-moe-8k``: 32,768 rows, 8 of 32 experts held, each
     2048 x 1792, top-4 -- forward and backward hold the grouped-matmul
-    kernel, never ``ragged_dot``, and fit the chip."""
+    kernel, never ``ragged_dot``, and fit the chip.  The sorted side is
+    a window of 49,152 rows, not the 131,072 pairs: no gate, up or
+    product of that many rows is left (the token-side ``[32768, 4,
+    2048]`` pairs of the combine's forward and the dispatch's backward
+    remain), and the temporaries stand at 1.27 GB where the one-buffer
+    layer (PR 27) had 2.49 GB."""
     from ray_lightning_accelerators_tpu.ops import moe
 
     held = tuple(range(8))
@@ -138,6 +143,7 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
         jax.eval_shape(lambda k: moe.init_dropless_params(
             k, 2048, 1792, 32, 8), jax.random.PRNGKey(0)))
     x = _sds((4, 8192, 2048), jnp.bfloat16, one_chip)
+    assert moe.window_rows(4 * 8192 * 4, 8, 32) == 49152
 
     def loss(p, x):
         y, stats = moe.dropless_moe(x, p, top_k=4, held=held,
@@ -153,7 +159,10 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
     assert text.count("tpu_custom_call") >= 3   # gmm, its transpose, tgmm
     assert "stablehlo.ragged_dot" not in text
     assert "kernel/moe_gmm" in lowered.as_text(debug_info=True)
-    mem = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    assert "[131072,1792]" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.6e9       # 2.485e9 before the windows
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES // 2
 
