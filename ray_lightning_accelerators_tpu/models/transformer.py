@@ -96,17 +96,22 @@ class TransformerConfig:
     # PR 26); both benchmark cells set 1024.
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
-    # ---- a layer stack of several kinds ------------------------------ #
-    # ``layer_types`` or ``moe_router="sigmoid"`` makes the stack a
-    # sequence of RUNS: consecutive layers of one (operator, feed-forward)
-    # kind are one stacked subtree ``params["layers_<i>"]`` and one
-    # ``lax.scan``, so compile time grows with runs, not layers.  The
-    # other fields below state the rest of that block (the mixed stack
-    # runs SwiGLU, QK-norm and rotate-half rotary and refuses anything
-    # else by name) and are refused on the uniform block above, whose
-    # parameter tree (``params["layers"]``) and program they never touch.
-    # Training only: decode, serving, quantize_weights and the pipeline
-    # walk one uniform stack and refuse a mixed one by name.
+    # ---- the layer stack ---------------------------------------------- #
+    # The stack is a sequence of RUNS (``layer_runs()``): consecutive
+    # layers of one (operator, feed-forward) kind are one stacked
+    # subtree of the parameters and one ``lax.scan``, so compile time
+    # grows with runs, not layers.  Without ``layer_types`` and with
+    # ``moe_router="softmax"`` it is ONE run of attention blocks in
+    # ``params["layers"]``; otherwise run i lives in
+    # ``params["layers_<i>"]`` (``run_keys()``: the names are a format,
+    # checkpoints and the benchmark's references read them).  The
+    # fields below state the rest of the block and each is read where
+    # it acts; ``layer_runs()`` accepts the combinations that have a
+    # reference behind them (GELU, interleaved rotary and no QK-norm
+    # with one run; SwiGLU, QK-norm and rotate-half rotary with
+    # ``layer_types`` / the sigmoid router) and refuses the rest by
+    # name.  Decode, serving, quantize_weights and the pipeline walk
+    # the one run in ``params["layers"]`` and refuse any other by name.
     #
     # per-layer sequence operator, "conv" (gated short convolution,
     # ops/conv.py) or "full_attention"; None = attention everywhere
@@ -142,8 +147,9 @@ class TransformerConfig:
                 setattr(self, name, tuple(value))
         self.layer_runs()       # a config no block runs fails here
 
-    # the fields that only a mixed stack reads, with their defaults, and
-    # the one value of each that the mixed stack's block implements
+    # the combinations with a reference behind them: these fields keep
+    # their defaults unless ``layer_types`` or the sigmoid router is set,
+    # and with either the block's three switches take these values
     _MIXED_ONLY = (("num_dense_layers", 0), ("moe_d_ff", None),
                    ("moe_experts_held", None), ("gated_mlp", False),
                    ("qk_norm", False), ("rope_style", "interleaved"),
@@ -151,21 +157,25 @@ class TransformerConfig:
     _MIXED_BLOCK = (("gated_mlp", True), ("qk_norm", True),
                     ("rope_style", "half"))
 
-    def layer_runs(self) -> Optional[Tuple[Tuple[str, str, int], ...]]:
-        """``((operator, feed_forward, n_layers), ...)`` in stack order,
-        or None for the uniform block (neither ``layer_types`` nor
-        ``moe_router="sigmoid"``).  operator: "conv" | "attn";
-        feed_forward: "dense" | "sparse"."""
+    @property
+    def _mixed(self) -> bool:
+        return self.layer_types is not None or self.moe_router == "sigmoid"
+
+    def layer_runs(self) -> Tuple[Tuple[str, str, int], ...]:
+        """``((operator, feed_forward, n_layers), ...)`` in stack order.
+        operator: "conv" | "attn"; feed_forward: "dense" | "capacity"
+        (``ops/moe.moe_mlp``) | "sparse" (``ops/moe.dropless_moe``)."""
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
-        if self.layer_types is None and self.moe_router == "softmax":
+        if not self._mixed:
             for name, default in self._MIXED_ONLY:
                 if getattr(self, name) != default:
                     raise ValueError(
                         f"TransformerConfig.{name} belongs to the mixed "
                         "layer stack: set layer_types or "
                         "moe_router='sigmoid' with it")
-            return None
+            return (("attn", "capacity" if self.num_experts > 1
+                     else "dense", self.n_layers),)
         for name, needed in self._MIXED_BLOCK:
             if getattr(self, name) != needed:
                 raise NotImplementedError(
@@ -193,6 +203,12 @@ class TransformerConfig:
                 runs.append([*key, 1])
         return tuple(tuple(r) for r in runs)
 
+    def run_keys(self) -> Tuple[str, ...]:
+        """The parameter subtree of each run, in stack order."""
+        if not self._mixed:
+            return ("layers",)
+        return tuple(f"layers_{i}" for i in range(len(self.layer_runs())))
+
     @property
     def experts_held(self) -> Tuple[int, ...]:
         held = self.moe_experts_held
@@ -211,66 +227,35 @@ class TransformerConfig:
         return kv
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings.  x: [b, h, s, d], positions: [s]."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [s,d/2]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x2 * cos + x1 * sin
-    return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def _rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings in the rotate-half convention: dimension i pairs
-    with i + d/2 (``_rope`` pairs 2i with 2i + 1).  x: [b, h, s, d]."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _rope_rows(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings with PER-ROW positions.  x: [b, h, 1, d],
-    positions: [b] — the continuous-batching decode step, where every
-    batch row sits at its own sequence position.  Element-for-element the
-    same arithmetic as `_rope`, so a row at position p matches the
-    shared-position decode path exactly."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = (positions[:, None].astype(jnp.float32)
-              * freqs[None, :])                       # [b, d/2]
-    cos = jnp.cos(angles)[:, None, None, :]
-    sin = jnp.sin(angles)[:, None, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x2 * cos + x1 * sin
-    return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def _rope_grid(x: jax.Array, positions: jax.Array,
-               theta: float) -> jax.Array:
-    """Rotary embeddings with a PER-ROW, PER-QUERY position grid.
-    x: [b, h, n, d], positions: [b, n] — the paged decode paths, where
-    every batch row carries its own vector of query positions (n == 1
-    for the batched step, b == 1 for chunk scoring).  Element-for-element
-    the same arithmetic as `_rope`/`_rope_rows`, so a query at position p
-    matches the dense decode paths exactly."""
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          style: str = "interleaved") -> jax.Array:
+    """Rotary embeddings.  x: [b, h, s, d]; positions: [s], shared by the
+    batch, or per row [b, s] ([b, 1]: every row of a decode step at its
+    own position).  One arithmetic per element whatever the rank, so a
+    query at position p comes out the same on every path (token identity
+    of the decode paths rests on it).  ``style``: "interleaved" pairs
+    dimension 2i with 2i + 1, "half" i with i + d/2 (rotate-half, the
+    published checkpoints' convention)."""
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = (positions[..., None].astype(jnp.float32)
-              * freqs[None, None, :])                  # [b, n, d/2]
-    cos = jnp.cos(angles)[:, None, :, :]               # [b, 1, n, d/2]
-    sin = jnp.sin(angles)[:, None, :, :]
+              * freqs[(None,) * positions.ndim])            # [.., d/2]
+    if angles.ndim == 3:
+        angles = angles[:, None]                    # the head axis
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if style == "half":
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     rx1 = x1 * cos - x2 * sin
     rx2 = x2 * cos + x1 * sin
     return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _dense(key, shape, fan_in) -> jax.Array:
+    """A float32 weight drawn at scale fan_in ** -0.5."""
+    return jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)
 
 
 def _channel_quant(w: jax.Array):
@@ -368,95 +353,72 @@ class GPT(TpuModule):
     # ------------------------------------------------------------------ #
     def init_params(self, rng) -> Dict[str, Any]:
         cfg = self.cfg
-        d, h, hd, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+        d = cfg.d_model
         k_embed, k_layers, k_out = jax.random.split(rng, 3)
-
-        def dense(key, shape, fan_in):
-            return (jax.random.normal(key, shape, jnp.float32)
-                    * (fan_in ** -0.5))
-
-        kv = cfg.kv_heads
-
-        def layer(key):
-            ks = jax.random.split(key, 6)
-            if cfg.num_experts > 1:
-                mlp = init_moe_params(ks[4], d, f, cfg.num_experts)
-            else:
-                mlp = {
-                    "wi": dense(ks[4], (d, f), d),
-                    "wo": dense(ks[5], (f, d), f),
-                }
-            return {
-                "attn": {
-                    "wq": dense(ks[0], (d, h, hd), d),
-                    "wk": dense(ks[1], (d, kv, hd), d),
-                    "wv": dense(ks[2], (d, kv, hd), d),
-                    "wo": dense(ks[3], (h, hd, d), d),
-                },
-                "mlp": mlp,
-                "ln1": jnp.ones((d,), jnp.float32),
-                "ln2": jnp.ones((d,), jnp.float32),
-            }
-
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
         params = {
-            "embed": dense(k_embed, (cfg.vocab_size, d), d) * d ** 0.5 * 0.02,
+            "embed": _dense(k_embed, (cfg.vocab_size, d), d) * d ** 0.5 * 0.02,
             "ln_f": jnp.ones((d,), jnp.float32),
         }
-        runs = cfg.layer_runs()
-        if runs is None:
-            # stacked: leading dim n_layers
-            params["layers"] = jax.vmap(layer)(layer_keys)
-        else:
-            first = 0
-            for i, (op, ff, n) in enumerate(runs):
-                params[f"layers_{i}"] = jax.vmap(functools.partial(
-                    self._init_kind_layer, op=op, ff=ff))(
-                        layer_keys[first:first + n])
-                first += n
+        first = 0
+        for key, (op, ff, n) in zip(cfg.run_keys(), cfg.layer_runs()):
+            # stacked: leading dim the run's layers
+            params[key] = jax.vmap(functools.partial(
+                self._init_layer, op=op, ff=ff))(layer_keys[first:first + n])
+            first += n
         if not cfg.tie_embeddings:
-            params["unembed"] = dense(k_out, (d, cfg.vocab_size), d)
+            params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
         return params
 
-    def _init_kind_layer(self, key, *, op: str, ff: str) -> Dict[str, Any]:
-        """One layer of a mixed stack: operator ``op`` ("conv" | "attn"),
-        feed-forward ``ff`` ("dense" SwiGLU | "sparse")."""
+    def _init_layer(self, key, *, op: str, ff: str) -> Dict[str, Any]:
+        """One layer: operator ``op`` ("conv" | "attn"), feed-forward
+        ``ff`` ("dense" | "capacity" | "sparse").  Which of its keys a
+        leaf is drawn from is part of the parameter format, and so is
+        the split count: ``split(key, 6)`` and ``split(key, 8)`` give
+        different keys, and the gated MLP's third projection took the
+        split to 8.  A tree drawn with one count cannot be drawn again
+        with the other (checkpoints, the benchmark's ``weights_seed``)."""
         cfg = self.cfg
-        d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
-        ks = jax.random.split(key, 8)
-
-        def dense(k, shape, fan_in):
-            return jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5
-
+        d, h, kv, hd, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads,
+                           cfg.head_dim, cfg.d_ff)
+        ks = jax.random.split(key, 8 if cfg.gated_mlp else 6)
         out = {"ln1": jnp.ones((d,), jnp.float32),
                "ln2": jnp.ones((d,), jnp.float32)}
         if op == "conv":
             out["conv"] = {
-                "w_in": dense(ks[0], (d, 3 * d), d),
-                "conv_w": dense(ks[1], (d, cfg.conv_kernel),
-                                cfg.conv_kernel),
-                "w_out": dense(ks[2], (d, d), d),
+                "w_in": _dense(ks[0], (d, 3 * d), d),
+                "conv_w": _dense(ks[1], (d, cfg.conv_kernel),
+                                 cfg.conv_kernel),
+                "w_out": _dense(ks[2], (d, d), d),
             }
         else:
             out["attn"] = {
-                "wq": dense(ks[0], (d, h, hd), d),
-                "wk": dense(ks[1], (d, kv, hd), d),
-                "wv": dense(ks[2], (d, kv, hd), d),
-                "wo": dense(ks[3], (h, hd, d), d),
-                "q_norm": jnp.ones((hd,), jnp.float32),
-                "k_norm": jnp.ones((hd,), jnp.float32),
+                "wq": _dense(ks[0], (d, h, hd), d),
+                "wk": _dense(ks[1], (d, kv, hd), d),
+                "wv": _dense(ks[2], (d, kv, hd), d),
+                "wo": _dense(ks[3], (h, hd, d), d),
             }
+            if cfg.qk_norm:
+                out["attn"]["q_norm"] = jnp.ones((hd,), jnp.float32)
+                out["attn"]["k_norm"] = jnp.ones((hd,), jnp.float32)
         if ff == "sparse":
             out["mlp"] = init_dropless_params(
-                ks[4], d, cfg.moe_d_ff or cfg.d_ff, cfg.num_experts,
+                ks[4], d, cfg.moe_d_ff or f, cfg.num_experts,
                 len(cfg.experts_held))
+        elif ff == "capacity":
+            out["mlp"] = init_moe_params(ks[4], d, f, cfg.num_experts)
+        elif cfg.gated_mlp:
+            out["mlp"] = {"w1": _dense(ks[4], (d, f), d),
+                          "w3": _dense(ks[5], (d, f), d),
+                          "w2": _dense(ks[6], (f, d), f)}
         else:
-            out["mlp"] = {"w1": dense(ks[4], (d, cfg.d_ff), d),
-                          "w3": dense(ks[5], (d, cfg.d_ff), d),
-                          "w2": dense(ks[6], (cfg.d_ff, d), cfg.d_ff)}
+            out["mlp"] = {"wi": _dense(ks[4], (d, f), d),
+                          "wo": _dense(ks[5], (f, d), f)}
         return out
 
-    def _kind_logical_axes(self, op: str, ff: str) -> Dict[str, Any]:
+    def _layer_logical_axes(self, op: str, ff: str) -> Dict[str, Any]:
+        """The logical axes of ``_init_layer``'s leaves, stacked."""
+        cfg = self.cfg
         axes: Dict[str, Any] = {"ln1": ("layers", None),
                                 "ln2": ("layers", None)}
         if op == "conv":
@@ -467,75 +429,54 @@ class GPT(TpuModule):
             axes["attn"] = {"wq": ("layers", "embed", "heads", "kv"),
                             "wk": ("layers", "embed", "heads", "kv"),
                             "wv": ("layers", "embed", "heads", "kv"),
-                            "wo": ("layers", "heads", "kv", "embed"),
-                            "q_norm": ("layers", None),
-                            "k_norm": ("layers", None)}
-        if ff == "sparse":
-            axes["mlp"] = {name: ("layers",) + ax for name, ax
-                           in dropless_logical_axes().items()}
-        else:
+                            "wo": ("layers", "heads", "kv", "embed")}
+            if cfg.qk_norm:
+                axes["attn"]["q_norm"] = ("layers", None)
+                axes["attn"]["k_norm"] = ("layers", None)
+        if ff in ("sparse", "capacity"):
+            expert_axes = (dropless_logical_axes() if ff == "sparse"
+                           else moe_logical_axes())
+            axes["mlp"] = {name: ("layers",) + ax
+                           for name, ax in expert_axes.items()}
+        elif cfg.gated_mlp:
             axes["mlp"] = {"w1": ("layers", "embed", "mlp"),
                            "w3": ("layers", "embed", "mlp"),
                            "w2": ("layers", "mlp", "embed")}
+        else:
+            axes["mlp"] = {"wi": ("layers", "embed", "mlp"),
+                           "wo": ("layers", "mlp", "embed")}
         return axes
 
     def param_logical_axes(self) -> Dict[str, Any]:
         """Logical axis names per leaf; consumed by the accelerator to build
         mesh shardings (parallel/sharding.py rules)."""
-        runs = self.cfg.layer_runs()
-        if runs is not None:
-            axes = {"embed": ("vocab", "embed"), "ln_f": (None,)}
-            for i, (op, ff, _) in enumerate(runs):
-                axes[f"layers_{i}"] = self._kind_logical_axes(op, ff)
-            if not self.cfg.tie_embeddings:
-                axes["unembed"] = ("embed", "vocab")
-            return axes
-        if self.cfg.num_experts > 1:
-            mlp_axes = {name: ("layers",) + ax
-                        for name, ax in moe_logical_axes().items()}
-        else:
-            mlp_axes = {
-                "wi": ("layers", "embed", "mlp"),
-                "wo": ("layers", "mlp", "embed"),
-            }
-        axes = {
-            "embed": ("vocab", "embed"),
-            "layers": {
-                "attn": {
-                    "wq": ("layers", "embed", "heads", "kv"),
-                    "wk": ("layers", "embed", "heads", "kv"),
-                    "wv": ("layers", "embed", "heads", "kv"),
-                    "wo": ("layers", "heads", "kv", "embed"),
-                },
-                "mlp": mlp_axes,
-                "ln1": ("layers", None),
-                "ln2": ("layers", None),
-            },
-            "ln_f": (None,),
-        }
-        if not self.cfg.tie_embeddings:
+        cfg = self.cfg
+        axes = {"embed": ("vocab", "embed"), "ln_f": (None,)}
+        for key, (op, ff, _) in zip(cfg.run_keys(), cfg.layer_runs()):
+            axes[key] = self._layer_logical_axes(op, ff)
+        if not cfg.tie_embeddings:
             axes["unembed"] = ("embed", "vocab")
         return axes
 
     def scanned_param_subtrees(self) -> Tuple[str, ...]:
         """The layer stack is scanned — the overlap-aware FSDP gather
         (``Trainer(gather_mode="scan")``) keeps it fsdp-sharded as scan
-        operands and all-gathers each layer inside the scan body.  A
-        mixed stack is one stacked subtree per run."""
-        runs = self.cfg.layer_runs()
-        if runs is None:
-            return ("layers",)
-        return tuple(f"layers_{i}" for i in range(len(runs)))
+        operands and all-gathers each layer inside the scan body: one
+        stacked subtree per run."""
+        return self.cfg.run_keys()
 
-    def _uniform_stack_only(self, what: str) -> None:
-        """Every walker of ``params["layers"]`` as ONE uniform stack
-        calls this first: a mixed stack has no such subtree (and a conv
-        layer's serving state is not a KV cache)."""
-        if self.cfg.layer_runs() is not None:
+    def _uniform_stack_only(self, what: str) -> str:
+        """Every walker of ``params["layers"]`` as ONE run of attention
+        blocks with a KV cache calls this first and gets the run's
+        feed-forward kind: any other stack has no such subtree (a conv
+        layer's serving state is not a KV cache, and the decode blocks
+        know neither QK-norm nor rotate-half rotary)."""
+        if self.cfg.run_keys() != ("layers",):
             raise NotImplementedError(
                 f"{what} walks params['layers'] as one uniform stack of "
                 "attention blocks; a mixed layer stack (TransformerConfig"
                 ".layer_types / moe_router='sigmoid') trains only")
+        return self.cfg.layer_runs()[0][1]
 
     # ------------------------------------------------------------------ #
     # Forward                                                            #
@@ -618,6 +559,8 @@ class GPT(TpuModule):
             attend, self.mesh, (qkv, qkv, qkv), qkv)(q, k, v)
 
     def _dropout(self, x, rng):
+        if rng is None:
+            return x
         p = self.cfg.dropout
         keep = jax.random.bernoulli(rng, 1.0 - p, x.shape)
         return jnp.where(keep, x / (1.0 - p), 0.0).astype(x.dtype)
@@ -643,18 +586,23 @@ class GPT(TpuModule):
         out = _int8_ste_matmul(mode, x.reshape(b * s, din).astype(dt), w)
         return out.reshape(b, s, w.shape[1])
 
-    def _block(self, h, layer_params, positions, return_kv: bool = False,
-               dropout_rng=None):
+    def _self_attention(self, x, a, positions):
+        """The attention operator on normed rows ``x`` [b, s, d]:
+        ``(out [b, s, d], (k, v))`` with k / v rotated, one per KV head
+        (what ``_prefill`` caches)."""
         cfg = self.cfg
         dt = self.compute_dtype
-        a = layer_params["attn"]
-        x = self._rms_norm(h, layer_params["ln1"])
         with jax.named_scope("gpt/attn"):
+            def rotated(t, scale):
+                if cfg.qk_norm:     # per-head RMSNorm before the rotation
+                    t = rms_norm(t, a[scale], cfg.norm_eps)
+                return _rope(t, positions, cfg.rope_theta, cfg.rope_style)
+
             q = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wq"], dt))
             k = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wk"], dt))
             v = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wv"], dt))
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = rotated(q, "q_norm")
+            k = rotated(k, "k_norm")
             q = self._constrain(q, mesh_lib.BATCH_AXES,
                                 mesh_lib.TENSOR_AXIS,
                                 mesh_lib.SEQUENCE_AXIS, None)
@@ -677,120 +625,148 @@ class GPT(TpuModule):
                 vr = jnp.repeat(v, groups, axis=1)
             else:
                 kr, vr = k, v
-            attn = self._attention(q, kr, vr)
-            attn_out = jnp.einsum("bhsk,hkd->bsd", attn,
-                                  self._wt(a["wo"], dt))
-            if dropout_rng is not None and cfg.dropout > 0:
-                dropout_rng, r_attn = jax.random.split(dropout_rng)
-                attn_out = self._dropout(attn_out, r_attn)
-            h = h + attn_out
+            out = jnp.einsum("bhsk,hkd->bsd", self._attention(q, kr, vr),
+                             self._wt(a["wo"], dt))
+        return out, (k, v)
 
-        x = self._rms_norm(h, layer_params["ln2"])
-        with jax.named_scope("gpt/mlp"):
-            m = self._dequant_q8_leaves(layer_params["mlp"], dt)
-            if cfg.num_experts > 1:
-                y, aux = moe_mlp(x, m, top_k=cfg.moe_top_k,
-                                 capacity_factor=cfg.moe_capacity_factor,
-                                 compute_dtype=dt, mesh=self.mesh)
-            else:
-                aux = jnp.zeros((), jnp.float32)
-                up = jax.nn.gelu(self._mlp_train_matmul(x, m["wi"], dt))
-                up = self._constrain(up, mesh_lib.BATCH_AXES,
-                                     mesh_lib.SEQUENCE_AXIS,
-                                     mesh_lib.TENSOR_AXIS)
-                y = self._mlp_train_matmul(up, m["wo"], dt)
-            if dropout_rng is not None and cfg.dropout > 0:
-                y = self._dropout(y, dropout_rng)
-            h = h + y
-            h = self._constrain(h, mesh_lib.BATCH_AXES,
-                                mesh_lib.SEQUENCE_AXIS, None)
-        if return_kv:
-            return h, aux, k, v
-        return h, aux
-
-    def _block_kind(self, h, lp, positions, op: str, ff: str):
-        """One layer of a mixed stack: ``h + op(norm(h))``, then
-        ``+ ff(norm(.))``; attention with per-head RMSNorm on q and k and
-        rotate-half rotary, the dense feed-forward SwiGLU.  Returns
-        ``(h, stats)``: the expert layer's counters for a sparse
-        feed-forward, ``{}`` for a dense one."""
+    def _feed_forward(self, x, m, ff: str, proj):
+        """The feed-forward of kind ``ff`` on normed rows ``x``:
+        ``(out, stats)``.  A dense one (GELU, or SwiGLU under
+        ``gated_mlp``) multiplies through ``proj(x, w, dtype)``, the
+        caller's projection (``_mlp_train_matmul`` in training,
+        ``_mlp_proj_decode`` in the decode blocks); the expert layers
+        bring their counters: the capacity path its auxiliary loss, the
+        dropless path (which names its own scopes) its row counters."""
         cfg = self.cfg
         dt = self.compute_dtype
-        x = self._rms_norm(h, lp["ln1"])
-        if op == "conv":
-            with jax.named_scope("gpt/conv"):
-                c = lp["conv"]
-                y = gated_short_conv(x, c["w_in"].astype(dt), c["conv_w"],
-                                     c["w_out"].astype(dt))
-        else:
-            with jax.named_scope("gpt/attn"):
-                a = lp["attn"]
-                q = jnp.einsum("bsd,dhk->bhsk", x, a["wq"].astype(dt))
-                k = jnp.einsum("bsd,dhk->bhsk", x, a["wk"].astype(dt))
-                v = jnp.einsum("bsd,dhk->bhsk", x, a["wv"].astype(dt))
-                q = _rope_half(rms_norm(q, a["q_norm"], cfg.norm_eps),
-                               positions, cfg.rope_theta)
-                k = _rope_half(rms_norm(k, a["k_norm"], cfg.norm_eps),
-                               positions, cfg.rope_theta)
-                q = self._constrain(q, mesh_lib.BATCH_AXES,
-                                    mesh_lib.TENSOR_AXIS,
-                                    mesh_lib.SEQUENCE_AXIS, None)
-                groups = cfg.n_heads // cfg.kv_heads
-                if groups > 1:  # GQA: each KV head serves its group
-                    k = jnp.repeat(k, groups, axis=1)
-                    v = jnp.repeat(v, groups, axis=1)
-                y = jnp.einsum("bhsk,hkd->bsd", self._attention(q, k, v),
-                               a["wo"].astype(dt))
-        h = h + y
-        x = self._rms_norm(h, lp["ln2"])
-        m, stats = lp["mlp"], {}
         if ff == "sparse":
-            y, stats = dropless_moe(
+            return dropless_moe(
                 x, m, top_k=cfg.moe_top_k, held=cfg.experts_held,
                 num_experts=cfg.num_experts, norm_topk=cfg.moe_norm_topk,
                 scale=cfg.moe_routed_scale, compute_dtype=dt,
                 mesh=self.mesh)
-        else:
-            with jax.named_scope("gpt/mlp"):
-                up = jax.nn.silu(jnp.einsum(
-                    "bsd,df->bsf", x, m["w1"].astype(dt))
-                    ) * jnp.einsum("bsd,df->bsf", x, m["w3"].astype(dt))
-                up = self._constrain(up, mesh_lib.BATCH_AXES,
-                                     mesh_lib.SEQUENCE_AXIS,
-                                     mesh_lib.TENSOR_AXIS)
-                y = jnp.einsum("bsf,fd->bsd", up, m["w2"].astype(dt))
-        h = self._constrain(h + y, mesh_lib.BATCH_AXES,
-                            mesh_lib.SEQUENCE_AXIS, None)
-        return h, stats
+        with jax.named_scope("gpt/mlp"):
+            if ff == "capacity":
+                y, aux = moe_mlp(x, self._dequant_q8_leaves(m, dt),
+                                 top_k=cfg.moe_top_k,
+                                 capacity_factor=cfg.moe_capacity_factor,
+                                 compute_dtype=dt, mesh=self.mesh)
+                return y, {"moe_aux_loss": aux}
+            if cfg.gated_mlp:
+                up = jax.nn.silu(proj(x, m["w1"], dt)) * proj(x, m["w3"], dt)
+            else:
+                up = jax.nn.gelu(proj(x, m["wi"], dt))
+            up = self._constrain(up, mesh_lib.BATCH_AXES,
+                                 mesh_lib.SEQUENCE_AXIS,
+                                 mesh_lib.TENSOR_AXIS)
+            return proj(up, m["w2" if cfg.gated_mlp else "wo"], dt), {}
 
-    def _run_stacks(self, params, h, runs):
-        """The mixed stack: one ``lax.scan`` (with remat as the uniform
-        stack has it) per run, in order.  Returns ``(h, stats)`` with the
-        sparse layers' counters summed over layers (``rounds`` and
-        ``load_max_over_mean`` averaged) and their chosen expert ids,
-        empty without a sparse layer."""
-        pos = jnp.arange(h.shape[1])
-        per_layer = []
-        for i, (op, ff, _) in enumerate(runs):
-            key = f"layers_{i}"
+    def _block(self, h, lp, positions, op: str, ff: str, dropout_rng=None):
+        """One layer: ``h + op(norm(h))``, then ``+ ff(norm(.))``.
+        Returns ``(h, stats, kv)``: the feed-forward's counters (``{}``
+        for a dense one) and the attention operator's ``(k, v)`` (None
+        for a conv).  The residual adds and dropout belong to this frame,
+        outside the operators' scopes."""
+        dt = self.compute_dtype
+        r_op = None
+        if dropout_rng is not None:
+            dropout_rng, r_op = jax.random.split(dropout_rng)
+        x = self._rms_norm(h, lp["ln1"])
+        if op == "conv":
+            with jax.named_scope("gpt/conv"):
+                c = lp["conv"]
+                y, kv = gated_short_conv(
+                    x, self._wt(c["w_in"], dt), c["conv_w"],
+                    self._wt(c["w_out"], dt)), None
+        else:
+            y, kv = self._self_attention(x, lp["attn"], positions)
+        h = h + self._dropout(y, r_op)
+        x = self._rms_norm(h, lp["ln2"])
+        y, stats = self._feed_forward(x, lp["mlp"], ff,
+                                      self._mlp_train_matmul)
+        h = self._constrain(h + self._dropout(y, dropout_rng),
+                            mesh_lib.BATCH_AXES, mesh_lib.SEQUENCE_AXIS,
+                            None)
+        return h, stats, kv
+
+    def _run_stacks(self, params, h, dropout_rng=None):
+        """The layer stack: one ``lax.scan`` per run, in order, each
+        layer under ``jax.checkpoint`` with ``cfg.remat``.  Returns
+        ``(h, aux)``; ``aux`` holds what the expert layers report and is
+        empty without one: the capacity path's ``moe_aux_loss`` summed
+        over layers, the dropless path's counters summed over layers
+        (``moe_rounds`` and ``moe_load_max_over_mean`` averaged) and its
+        chosen expert ids."""
+        cfg = self.cfg
+        keys = cfg.run_keys()
+        if dropout_rng is not None and keys != ("layers",):
+            raise NotImplementedError(
+                "dropout in a mixed layer stack (TransformerConfig"
+                ".layer_types ...) is not supported; set dropout=0")
+        piped = self.mesh is not None and mesh_lib.mesh_axis_size(
+            self.mesh, mesh_lib.PIPELINE_AXIS) > 1
+        if piped:
+            self._uniform_stack_only("pipeline parallelism")
+            if cfg.num_experts > 1:
+                raise NotImplementedError(
+                    "MoE layers under pipeline parallelism are not supported "
+                    "yet; use expert/tensor/data axes (set pipeline=1)")
+            if dropout_rng is not None:
+                raise NotImplementedError(
+                    "dropout under pipeline parallelism is not supported "
+                    "(per-stage rng would correlate masks); set dropout=0")
+        carry, per_layer = (h, dropout_rng), []
+        for key, (op, ff, _) in zip(keys, cfg.layer_runs()):
+            # overlap-aware FSDP (Trainer(gather_mode="scan")): inside the
+            # scan-gather train-step trace this hook all-gathers ONE
+            # layer's bf16 shards at the top of the scan body — XLA
+            # overlaps layer k+1's gather with layer k's matmuls, and the
+            # gather's autodiff transpose reduce-scatters the layer's
+            # gradient into its shard owner inside the backward.  It sits
+            # INSIDE the remat body, so a policy that drops the gathered
+            # weights re-gathers layer-by-layer in the backward instead
+            # of holding the replicated tree live.  None outside that
+            # trace (eval/decode/pipeline see plain params).
             gather = collectives_lib.current_layer_gather(key)
 
-            def block(carry, lp, op=op, ff=ff, gather=gather):
-                if gather is not None:
-                    lp = gather(lp)
-                return self._block_kind(carry, lp, pos, op, ff)
+            def run(carry, layers, op=op, ff=ff, gather=gather):
+                # positions derive from the (static) seq length; made
+                # here so the pipeline stage body closes over no
+                # outer-context tracers
+                pos = jnp.arange(carry[0].shape[1])
 
-            if self.cfg.remat:
-                block = jax.checkpoint(block, policy=_remat_policy(
-                    self.cfg.remat_policy))
-            with jax.named_scope("gpt/layers"):
-                h, stats = jax.lax.scan(block, h, params[key])
+                def block(carry, lp):
+                    h_c, r = carry      # the dropout key rides the carry
+                    if gather is not None:
+                        lp = gather(lp)
+                    sub = None
+                    if r is not None:
+                        r, sub = jax.random.split(r)
+                    h_c, stats, _ = self._block(h_c, lp, pos, op, ff, sub)
+                    return (h_c, r), stats
+
+                if cfg.remat:
+                    block = jax.checkpoint(block, policy=_remat_policy(
+                        cfg.remat_policy))
+                with jax.named_scope("gpt/layers"):
+                    return jax.lax.scan(block, carry, layers)
+
+            if piped:
+                from ..parallel.pipeline import pipeline_apply
+                carry = (pipeline_apply(
+                    lambda lp, hm: run((hm, None), lp)[0][0], params[key],
+                    carry[0], self.mesh, cfg.pipeline_microbatches), None)
+                continue
+            carry, stats = run(carry, params[key])
             if stats:
                 per_layer.append(stats)
+        h = carry[0]
         if not per_layer:
             return h, {}
         stats = {k: jnp.concatenate([s[k] for s in per_layer])
                  for k in per_layer[0]}
+        if "moe_aux_loss" in stats:
+            return h, {"moe_aux_loss": jnp.sum(stats["moe_aux_loss"])}
         return h, {
             "moe_rows_routed": jnp.sum(stats["rows_routed"]),
             "moe_rows_computed": jnp.sum(stats["rows_computed"]),
@@ -810,91 +786,14 @@ class GPT(TpuModule):
 
     def _trunk(self, params, tokens, dropout_rng=None):
         """Embedding and the layer stack, up to the final norm:
-        ``(hidden, moe aux loss)``; of a mixed stack ``(hidden, the
-        expert layers' counters)``."""
+        ``(hidden, what the expert layers report)``, the second a dict
+        (``_run_stacks``), empty for a dense stack."""
         if dropout_rng is not None and self.cfg.dropout <= 0:
             dropout_rng = None
         h = self._embed_lookup(params, tokens)
         h = self._constrain(h, mesh_lib.BATCH_AXES,
                             mesh_lib.SEQUENCE_AXIS, None)
-        runs = self.cfg.layer_runs()
-        if runs is not None:
-            if dropout_rng is not None:
-                raise NotImplementedError(
-                    "dropout in a mixed layer stack (TransformerConfig"
-                    ".layer_types ...) is not supported; set dropout=0")
-            if self.mesh is not None and mesh_lib.mesh_axis_size(
-                    self.mesh, mesh_lib.PIPELINE_AXIS) > 1:
-                self._uniform_stack_only("pipeline parallelism")
-            # the second result is the expert layers' counters here (a
-            # dict, empty without a sparse layer), not an auxiliary loss
-            return self._run_stacks(params, h, runs)
-
-        def stack(h_in, layers):
-            # positions derive from the (static) seq length; recomputed here
-            # so the pipeline stage body closes over no outer-context tracers
-            pos = jnp.arange(h_in.shape[1])
-            # overlap-aware FSDP (Trainer(gather_mode="scan")): inside the
-            # scan-gather train-step trace this hook all-gathers ONE
-            # layer's bf16 shards at the top of the scan body — XLA
-            # overlaps layer k+1's gather with layer k's matmuls, and the
-            # gather's autodiff transpose reduce-scatters the layer's
-            # gradient into its shard owner inside the backward.  It sits
-            # INSIDE the remat body, so a policy that drops the gathered
-            # weights re-gathers layer-by-layer in the backward instead
-            # of holding the replicated tree live.  None outside that
-            # trace (eval/decode/pipeline see plain params).
-            gather = collectives_lib.current_layer_gather("layers")
-
-            if dropout_rng is not None:
-                # rng rides the scan carry; each layer folds off its key
-                def block_do(carry, layer_params):
-                    h_c, r = carry
-                    if gather is not None:
-                        layer_params = gather(layer_params)
-                    r, sub = jax.random.split(r)
-                    h_new, aux = self._block(h_c, layer_params, pos,
-                                             dropout_rng=sub)
-                    return (h_new, r), aux
-
-                if self.cfg.remat:
-                    block_do = jax.checkpoint(block_do, policy=_remat_policy(
-                        self.cfg.remat_policy))
-                with jax.named_scope("gpt/layers"):
-                    (out, _), aux_per_layer = jax.lax.scan(
-                        block_do, (h_in, dropout_rng), layers)
-                return out, jnp.sum(aux_per_layer)
-
-            def block(carry, layer_params):
-                if gather is not None:
-                    layer_params = gather(layer_params)
-                return self._block(carry, layer_params, pos)
-
-            if self.cfg.remat:
-                block = jax.checkpoint(block, policy=_remat_policy(
-                    self.cfg.remat_policy))
-            with jax.named_scope("gpt/layers"):
-                out, aux_per_layer = jax.lax.scan(block, h_in, layers)
-            return out, jnp.sum(aux_per_layer)
-
-        if self.mesh is not None and mesh_lib.mesh_axis_size(
-                self.mesh, mesh_lib.PIPELINE_AXIS) > 1:
-            if self.cfg.num_experts > 1:
-                raise NotImplementedError(
-                    "MoE layers under pipeline parallelism are not supported "
-                    "yet; use expert/tensor/data axes (set pipeline=1)")
-            if dropout_rng is not None:
-                raise NotImplementedError(
-                    "dropout under pipeline parallelism is not supported "
-                    "(per-stage rng would correlate masks); set dropout=0")
-            from ..parallel.pipeline import pipeline_apply
-            h = pipeline_apply(lambda lp, hm: stack(hm, lp)[0],
-                               params["layers"], h, self.mesh,
-                               self.cfg.pipeline_microbatches)
-            aux = jnp.zeros((), jnp.float32)
-        else:
-            h, aux = stack(h, params["layers"])
-        return h, aux
+        return self._run_stacks(params, h, dropout_rng)
 
     def _head(self, params, h):
         """Final norm and LM head: f32 logits."""
@@ -963,15 +862,13 @@ class GPT(TpuModule):
 
     def training_step(self, params, batch, rng):
         loss, acc, aux = self._lm_loss(params, batch, rng=rng)
-        metrics = {"loss": loss, "accuracy": acc}
-        if isinstance(aux, dict):
-            # a mixed stack: no auxiliary loss (the dropless router is
-            # steered by its bias); the counters ride the logged metrics,
-            # through the scanned epoch too
-            metrics.update({k: v for k, v in aux.items() if v.ndim == 0})
-        elif self.cfg.num_experts > 1:
-            metrics["moe_aux_loss"] = aux
-            loss = loss + self.cfg.moe_aux_weight * aux
+        # the expert layers' scalars ride the logged metrics, through the
+        # scanned epoch too; only the capacity path has an auxiliary
+        # loss (the dropless router is steered by its bias)
+        metrics = {"loss": loss, "accuracy": acc,
+                   **{k: v for k, v in aux.items() if v.ndim == 0}}
+        if "moe_aux_loss" in aux:
+            loss = loss + self.cfg.moe_aux_weight * aux["moe_aux_loss"]
         return loss, metrics
 
     def validation_step(self, params, batch):
@@ -984,8 +881,7 @@ class GPT(TpuModule):
 
     def configure_optimizers(self):
         tx = optax.adamw(self.lr, weight_decay=0.01)
-        runs = self.cfg.layer_runs()
-        if runs is None or not any(ff == "sparse" for _, ff, _ in runs):
+        if not any(ff == "sparse" for _, ff, _ in self.cfg.layer_runs()):
             return tx
 
         # the selection bias is a buffer: no update (AdamW's decay would
@@ -1225,16 +1121,15 @@ class GPT(TpuModule):
         write garbage k/v beyond ``last_index``, which is safe for linear
         decode: slot p is rewritten by the decode step at position p
         before any mask ever lets it be attended."""
-        self._uniform_stack_only(
+        ff = self._uniform_stack_only(
             "GPT._prefill (generate, generate_beam, ServeEngine, "
             "speculative_generate)")
-        dt = self.compute_dtype
         h = self._embed_lookup(params, tokens)
         pos = jnp.arange(tokens.shape[1])
 
         def block(carry, lp):
-            h_new, _, k, v = self._block(carry, lp, pos, return_kv=True)
-            return h_new, (k, v)
+            h_new, _, kv = self._block(carry, lp, pos, "attn", ff)
+            return h_new, kv
 
         h, (ks, vs) = jax.lax.scan(block, h, params["layers"])
         s0 = tokens.shape[1]
@@ -1283,8 +1178,8 @@ class GPT(TpuModule):
         v = self._qkv_proj_decode(x, a["wv"], dt)
         W = ck.shape[2]
         if row_positions is not None:
-            q = _rope_rows(q, row_positions, cfg.rope_theta)
-            k = _rope_rows(k, row_positions, cfg.rope_theta)
+            q = _rope(q, row_positions[:, None], cfg.rope_theta)
+            k = _rope(k, row_positions[:, None], cfg.rope_theta)
 
             # per-row slot write: row b's k/v land at ITS position (a
             # batched scatter; joining/retiring is never a recompile)
@@ -1328,16 +1223,9 @@ class GPT(TpuModule):
         attn = jnp.einsum("bkgqt,bktd->bkgqd", p, cv.astype(jnp.float32))
         attn = attn.reshape(b, cfg.n_heads, n, cfg.head_dim).astype(dt)
         h = h + self._attn_out_proj_decode(attn, a["wo"], dt)
-        x = self._rms_norm(h, lp["ln2"])
-        if cfg.num_experts > 1:
-            m = self._dequant_q8_leaves(lp["mlp"], dt)
-            y, _ = moe_mlp(x, m, top_k=cfg.moe_top_k,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           compute_dtype=dt, mesh=self.mesh)
-        else:
-            m = lp["mlp"]
-            up = jax.nn.gelu(self._mlp_proj_decode(x, m["wi"], dt))
-            y = self._mlp_proj_decode(up, m["wo"], dt)
+        ff = self._uniform_stack_only("GPT._decode_attn_block")
+        y, _ = self._feed_forward(self._rms_norm(h, lp["ln2"]), lp["mlp"],
+                                  ff, self._mlp_proj_decode)
         return h + y, ck, cv
 
     def _decode_chunk(self, params, cache, tokens, pos0):
@@ -1534,8 +1422,8 @@ class GPT(TpuModule):
         q = self._qkv_proj_decode(x, a["wq"], dt)        # [B, H, n, D]
         k = self._qkv_proj_decode(x, a["wk"], dt)
         v = self._qkv_proj_decode(x, a["wv"], dt)
-        q = _rope_grid(q, positions, cfg.rope_theta)
-        k = _rope_grid(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
         # per-query scatter: query (b, i) writes its k/v at physical
         # block tables[b, pos // bl], offset pos % bl (a traced scatter;
         # distinct live rows own distinct blocks, so writes never
@@ -1564,16 +1452,9 @@ class GPT(TpuModule):
         attn = jnp.einsum("bkgqt,bktd->bkgqd", p, vb.astype(jnp.float32))
         attn = attn.reshape(b, cfg.n_heads, n, cfg.head_dim).astype(dt)
         h = h + self._attn_out_proj_decode(attn, a["wo"], dt)
-        x = self._rms_norm(h, lp["ln2"])
-        if cfg.num_experts > 1:
-            m = self._dequant_q8_leaves(lp["mlp"], dt)
-            y, _ = moe_mlp(x, m, top_k=cfg.moe_top_k,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           compute_dtype=dt, mesh=self.mesh)
-        else:
-            m = lp["mlp"]
-            up = jax.nn.gelu(self._mlp_proj_decode(x, m["wi"], dt))
-            y = self._mlp_proj_decode(up, m["wo"], dt)
+        ff = self._uniform_stack_only("GPT._paged_attn_block")
+        y, _ = self._feed_forward(self._rms_norm(h, lp["ln2"]), lp["mlp"],
+                                  ff, self._mlp_proj_decode)
         return h + y, pk, pv
 
     def decode_step_rows_paged(self, params, pool, tables, tokens,

@@ -12,6 +12,7 @@ tests; conftest guards the driver env regardless.
 
 import json
 import os
+import time
 
 import pytest
 
@@ -182,7 +183,7 @@ def test_chaos_hang_elastic_restart_resumes_from_checkpoint(tmp_path):
     the watchdog classifies rank 1 wedged within the configured timeout,
     its pending future fails with WorkerWedged, ElasticRunner restarts
     every rank, and the retry completes from the checkpoint rank 0 wrote
-    before the wedge was detected."""
+    before the restart."""
     ns = str(tmp_path / "chaos_ns")
     ckpt = str(tmp_path / "ckpt")
     os.makedirs(ckpt)
@@ -191,10 +192,32 @@ def test_chaos_hang_elastic_restart_resumes_from_checkpoint(tmp_path):
            "RLA_TPU_WORKER_HEARTBEAT_S": str(HB)}
     pool = ActorPool(2, env_per_worker=[dict(env), dict(env)])
     failures = []
+
+    def healthy_rank_drained(attempt, exc):
+        # The restart of every rank waits here for rank 0's checkpoint to
+        # show its last step: ordered by the file, not by the clock.  Rank
+        # 1 freezes at its dispatch, and the watchdog calls it wedged one
+        # wedge timeout later, while on a loaded host rank 0's process may
+        # still be importing: its six writes then raced the reap, and the
+        # retry's two ranks read a checkpoint that rank 0 was still
+        # writing ({0, 6} for one resume point).
+        failures.append(exc)
+        deadline = time.monotonic() + 120.0
+        state = os.path.join(ckpt, "state.json")
+        while time.monotonic() < deadline:
+            if os.path.exists(state):
+                with open(state) as f:
+                    if json.load(f)["step"] == 6:
+                        return
+            time.sleep(HB)
+        raise AssertionError("rank 0 never finished its steps")
+
     try:
+        # the wedge timeout scales from the heartbeat with room for a
+        # healthy rank's beat thread to be starved a while on a busy host
         runner = ElasticRunner(
-            pool, max_failures=2, wedge_timeout_s=0.6, watchdog_poll_s=HB,
-            on_failure=lambda a, e: failures.append(e))
+            pool, max_failures=2, wedge_timeout_s=20 * HB,
+            watchdog_poll_s=HB, on_failure=healthy_rank_drained)
         out = runner.run(
             _ckpt_train_body,
             args_per_worker=lambda a: [(r, ckpt, 6) for r in range(2)])

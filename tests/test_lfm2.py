@@ -8,6 +8,7 @@ uniform block's parameter tree and its one ``lax.scan`` left as they
 were; every walker of ``params["layers"]`` refusing a mixed stack by
 name; the scanned epoch carrying the expert layer's counters."""
 
+import hashlib
 import os
 
 import numpy as np
@@ -433,7 +434,10 @@ def test_config_without_the_new_fields_builds_todays_tree(case):
     over, pinned = TODAY[case]
     cfg = TransformerConfig(vocab_size=VOCAB, d_model=64, n_heads=4,
                             d_ff=128, n_layers=3, max_seq_len=64, **over)
-    assert cfg.layer_runs() is None
+    # one run of attention blocks, in the subtree today's checkpoints hold
+    assert cfg.layer_runs() == (
+        ("attn", "capacity" if over.get("num_experts") else "dense", 3),)
+    assert cfg.run_keys() == ("layers",)
     model = GPT(cfg)
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     got = {"/".join(k.key for k in path): leaf.shape for path, leaf in
@@ -477,6 +481,64 @@ def test_mixed_stack_is_one_scan_a_run(system):
     assert jax.tree.structure(
         model.param_logical_axes(), is_leaf=lambda x: isinstance(x, tuple)
     ) == jax.tree.structure(params)
+
+
+# --------------------------------------------------------------------- #
+# one layer stack: the three cells' kinds build the trees and run the    #
+# arithmetic they did when two blocks built them                         #
+# --------------------------------------------------------------------- #
+_SMALL = dict(vocab_size=VOCAB, d_model=64, n_heads=4, d_ff=128, n_layers=3,
+              max_seq_len=64)
+# name: (config, digest of init_params(key) eager / under jit, loss and
+# gradient norm of one training_step as float.hex(), scan lengths of its
+# jaxpr (the fused loss is the scan of 1)), all read off the tree that
+# still had the uniform block beside the mixed one (PR 28)
+ONE_STACK = {
+    "dense": (_SMALL, "bae5bc0b854c20e6", "c5cf35fbf2e6ace9",
+              "0x1.60f5180000000p+2", "0x1.cc19a00000000p+0", [1, 3]),
+    "gqa_capacity_moe": (
+        {**_SMALL, **TODAY["gqa_capacity_moe"][0]},
+        "8af981d53c36461b", "a49323c3c14de7fc",
+        "0x1.63e1400000000p+2", "0x1.17a4680000000p+1", [1, 3]),
+    "lfm2": (MODEL, "9728f03cda70c1b3", "af66504f958d5f44",
+             "0x1.62ecf40000000p+2", "0x1.9c87740000000p+2", [1, 1, 1, 3]),
+}
+
+
+def _digest(params) -> str:
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update("/".join(k.key for k in path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", sorted(ONE_STACK))
+def test_one_stack_keeps_each_kind_to_the_last_bit(case):
+    """The parameter tree is a format (a checkpoint, the benchmark's
+    ``weights_seed``: the routed rows of the LFM2 cell follow the
+    weights), and on the CPU the same operations in the same order give
+    the same float32 bits."""
+    kw, eager, jitted, loss_hex, norm_hex, scans = ONE_STACK[case]
+    model = GPT(TransformerConfig(**kw), lr=1e-3)
+    params = model.init_params(jax.random.PRNGKey(0))
+    assert _digest(params) == eager
+    assert _digest(jax.jit(model.init_params)(jax.random.PRNGKey(0))
+                   ) == jitted
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0, VOCAB)
+
+    def step(p):
+        return model.training_step(p, tokens, None)
+
+    (loss, _), grads = jax.jit(jax.value_and_grad(step, has_aux=True))(
+        params)
+    norm = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                        for g in jax.tree.leaves(grads)))
+    assert (float(loss).hex(), float(norm).hex()) == (loss_hex, norm_hex)
+    assert sorted(_count_scans(jax.make_jaxpr(step)(params).jaxpr)) == scans
+    # the trunk's second result has one meaning, whatever the stack
+    assert isinstance(model.forward(params, tokens, return_aux=True)[1],
+                      dict)
 
 
 # --------------------------------------------------------------------- #
