@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from ..analysis import knobs
@@ -235,22 +236,60 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     query at position p comes out the same on every path (token identity
     of the decode paths rests on it).  ``style``: "interleaved" pairs
     dimension 2i with 2i + 1, "half" i with i + d/2 (rotate-half, the
-    published checkpoints' convention)."""
+    published checkpoints' convention).
+
+    The head stays whole on the lanes: ``x * cos + partner(x) * sin`` in
+    float32 over all d lanes, where ``partner`` swaps the two members of
+    each pair and carries the sign (minus on the first member).  On the
+    TPU a stride-2 slice of the 64-wide lane dimension is a gather, its
+    gradient a scatter-add into a zero-filled buffer and the stack /
+    reshape that puts the halves back a relayout through ``[.., 32, 2]``
+    arrays: six passes over q and over k a layer where one does
+    (``PERF.md`` section 6, PR 30: on the chip the 355M step fell 4.3 %
+    and the 1.56B one 8.0 %).  ``partner`` is a d x d signed permutation
+    contracted on the MXU, which the compiler fuses with the rotation
+    into one matmul fusion; each output is one input times +-1, so it is
+    exact, for float32 inputs too at the highest precision.  Lane
+    rotations and a select by parity, the other exact spelling, lost on
+    the chip (slices and concatenates outside any fusion, more memory
+    and more all-gathers under FSDP)."""
+    return _rotate(x, positions, theta, style, False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _rotate(x, positions, theta, style, inverse):
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    lane = np.arange(d)
+    if style == "half":
+        pair, partner = lane % (d // 2), (lane + d // 2) % d
+    else:
+        pair, partner = lane // 2, lane ^ 1
+    # column j holds one entry, at row partner(j): minus where j is the
+    # first member of its pair (the other way round for the inverse)
+    sign = np.where((partner > lane) != inverse, -1.0, 1.0)
+    swap = sign[None, :] * (lane[:, None] == partner[None, :])
+    freqs = theta ** (-jnp.asarray(2 * pair, jnp.float32) / d)
     angles = (positions[..., None].astype(jnp.float32)
-              * freqs[(None,) * positions.ndim])            # [.., d/2]
+              * freqs[(None,) * positions.ndim])            # [.., d]
     if angles.ndim == 3:
         angles = angles[:, None]                    # the head axis
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    if style == "half":
-        x1, x2 = x[..., :d // 2], x[..., d // 2:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1).astype(x.dtype)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x2 * cos + x1 * sin
-    return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
+    swapped = jnp.einsum("...k,kj->...j", x, jnp.asarray(swap, x.dtype),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x * jnp.cos(angles) + swapped * jnp.sin(angles)).astype(x.dtype)
+
+
+def _rotate_fwd(x, positions, theta, style, inverse):
+    return _rotate(x, positions, theta, style, inverse), positions
+
+
+def _rotate_bwd(theta, style, inverse, positions, g):
+    # a rotation's transpose is the rotation back: the cotangent takes
+    # the same one pass, in its own dtype, with no residual of x
+    return _rotate(g, positions, theta, style, not inverse), None
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def _dense(key, shape, fan_in) -> jax.Array:

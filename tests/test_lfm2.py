@@ -21,7 +21,7 @@ from ray_lightning_accelerators_tpu import (ArrayDataset, DataLoader,
                                             RayTPUAccelerator, Trainer)
 from ray_lightning_accelerators_tpu.models import reference_lfm2 as ref
 from ray_lightning_accelerators_tpu.models.transformer import (
-    GPT, TransformerConfig)
+    GPT, TransformerConfig, _rope)
 from ray_lightning_accelerators_tpu.ops import moe
 from ray_lightning_accelerators_tpu.ops.conv import gated_short_conv
 from ray_lightning_accelerators_tpu.parallel import mesh as mesh_lib
@@ -492,14 +492,21 @@ _SMALL = dict(vocab_size=VOCAB, d_model=64, n_heads=4, d_ff=128, n_layers=3,
 # name: (config, digest of init_params(key) eager / under jit, loss and
 # gradient norm of one training_step as float.hex(), scan lengths of its
 # jaxpr (the fused loss is the scan of 1)), all read off the tree that
-# still had the uniform block beside the mixed one (PR 28)
+# still had the uniform block beside the mixed one (PR 28).  PR 30 left
+# every digest and loss as read and moved the gradient norm of the two
+# interleaved-rotary kinds by one unit in the last place (..a0 -> ..a2,
+# ..68 -> ..66): ``_rope`` and its transpose are spelled over the whole
+# head, and jitted on the CPU any respelling (a lane rotation and a
+# select too) rounds 13 % of the cotangent's elements the other way
+# (the compiler contracts a * c + b * s into another fused multiply-add;
+# op by op the gradient is the pairwise one bit for bit)
 ONE_STACK = {
     "dense": (_SMALL, "bae5bc0b854c20e6", "c5cf35fbf2e6ace9",
-              "0x1.60f5180000000p+2", "0x1.cc19a00000000p+0", [1, 3]),
+              "0x1.60f5180000000p+2", "0x1.cc19a20000000p+0", [1, 3]),
     "gqa_capacity_moe": (
         {**_SMALL, **TODAY["gqa_capacity_moe"][0]},
         "8af981d53c36461b", "a49323c3c14de7fc",
-        "0x1.63e1400000000p+2", "0x1.17a4680000000p+1", [1, 3]),
+        "0x1.63e1400000000p+2", "0x1.17a4660000000p+1", [1, 3]),
     "lfm2": (MODEL, "9728f03cda70c1b3", "af66504f958d5f44",
              "0x1.62ecf40000000p+2", "0x1.9c87740000000p+2", [1, 1, 1, 3]),
 }
@@ -709,6 +716,107 @@ def test_fsdp_places_every_run_and_trains(tmpdir, device_cache,
     a = float(one.callback_metrics["train_loss"])
     b = float(trainer.callback_metrics["train_loss"])
     assert abs(a - b) < (5e-2 if gather_mode == "scan" else 1e-3) * a
+
+
+# --------------------------------------------------------------------- #
+# rotary embeddings: the whole head on the lanes against the pairwise    #
+# definition                                                             #
+# --------------------------------------------------------------------- #
+def _rope_pairwise(x, positions, theta, style="interleaved"):
+    """The definition, as ``_rope`` spelled it until PR 30: split the
+    head into the two members of each pair, rotate, put them back."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = (positions[..., None].astype(jnp.float32)
+              * freqs[(None,) * positions.ndim])            # [.., d/2]
+    if angles.ndim == 3:
+        angles = angles[:, None]                    # the head axis
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if style == "half":
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    rx1 = x1 * cos - x2 * sin
+    rx2 = x2 * cos + x1 * sin
+    return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _bf16_steps(a, b):
+    """How many representable bfloat16 values lie between a and b."""
+    def rank(t):
+        bits = np.asarray(t).view(np.int16).astype(np.int32)
+        return np.where(bits < 0, -(bits & 0x7fff), bits)
+    return np.abs(rank(a) - rank(b))
+
+
+def _assert_same_rotation(got, want):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if got.dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-6)
+        return
+    steps = _bf16_steps(got, want)
+    assert steps.max() <= 1             # none further than one ulp
+    assert (steps == 0).mean() >= 0.999
+
+
+_ROPE_B, _ROPE_H, _ROPE_S, _ROPE_D = 3, 4, 24, 64
+_ROPE_POSITIONS = {
+    "[s]": lambda: jnp.arange(_ROPE_S),
+    "[b,s]": lambda: (jnp.arange(_ROPE_S)[None]
+                      + jnp.array([[0], [7], [1000]])),
+    "[b,1]": lambda: jnp.array([[5], [0], [4093]]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("positions", sorted(_ROPE_POSITIONS))
+@pytest.mark.parametrize("style", ["interleaved", "half"])
+def test_rope_equals_the_pairwise_definition(style, positions, dtype):
+    """``_rope`` never splits the head (a strided lane slice is a gather
+    on the TPU): value and gradient equal the pairwise definition, for
+    both pairings, every rank of ``positions`` and both dtypes.  The
+    definition is evaluated in float32 on the same values and cast once,
+    which is what ``_rope`` does with a bfloat16 head and with its
+    cotangent (the rotation back, one rounding; differentiating the
+    pairwise spelling in bfloat16 rounds each member's two cotangents
+    apart and adds them in bfloat16)."""
+    dtype = jnp.dtype(dtype)
+    pos = _ROPE_POSITIONS[positions]()
+    theta = 1e6 if style == "half" else 1e4
+    kx, kw = jax.random.split(jax.random.PRNGKey(7))
+    shape = (_ROPE_B, _ROPE_H, pos.shape[-1], _ROPE_D)
+    x = jax.random.normal(kx, shape, dtype)
+    w = jax.random.normal(kw, shape, dtype)     # exact in either dtype
+
+    def value_and_pullback(rope, t):
+        out, pull = jax.vjp(lambda u: rope(u, pos, theta, style), t)
+        return out, pull(w.astype(out.dtype))[0]
+
+    got = value_and_pullback(_rope, x)
+    want = value_and_pullback(_rope_pairwise, x.astype(jnp.float32))
+    for g, r in zip(got, want):
+        _assert_same_rotation(g, r.astype(dtype))
+
+
+@pytest.mark.parametrize("style", ["interleaved", "half"])
+def test_rope_gives_one_query_one_value_on_every_path(style):
+    """A query at position p through the ``[s]`` call (training,
+    prefill) and the ``[b, 1]`` call (a decode step, every row at its
+    own position): identical, which token identity of the decode paths
+    rests on."""
+    x = jax.random.normal(jax.random.PRNGKey(3),
+                          (_ROPE_B, _ROPE_H, _ROPE_S, _ROPE_D), jnp.float32)
+    whole = _rope(x, jnp.arange(_ROPE_S), 1e4, style)
+    at = jnp.array([[17], [0], [23]])
+    rows = jnp.take_along_axis(x, at[:, None, :, None], axis=2)
+    one = _rope(rows, at, 1e4, style)
+    want = jnp.take_along_axis(whole, at[:, None, :, None], axis=2)
+    assert np.array_equal(np.asarray(one), np.asarray(want))
+    grid = _rope(x, jnp.broadcast_to(jnp.arange(_ROPE_S),
+                                     (_ROPE_B, _ROPE_S)), 1e4, style)
+    assert np.array_equal(np.asarray(grid), np.asarray(whole))
 
 
 # --------------------------------------------------------------------- #

@@ -21,6 +21,7 @@ this one file for the same reason.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,8 @@ import chip_smoke
 from chip_smoke import kernels_in as _kernels
 from ray_lightning_accelerators_tpu import RayTPUAccelerator, Trainer
 from ray_lightning_accelerators_tpu.core.state import TrainState
-from ray_lightning_accelerators_tpu.models.transformer import GPT
+from ray_lightning_accelerators_tpu.models.transformer import (
+    GPT, TransformerConfig)
 from ray_lightning_accelerators_tpu.ops import quant
 from ray_lightning_accelerators_tpu.ops.attention import flash_attention
 from ray_lightning_accelerators_tpu.ops.norms import layer_norm, rms_norm
@@ -280,6 +282,54 @@ def test_four_chip_fsdp_train_step_compiles(topo, chip_dispatch,
     # params and Adam moments really are 1/4 per chip
     wq = trainer._state_shardings.params["layers"]["attn"]["wq"]
     assert wq.shard_shape((12, 768, 12, 64)) == (12, 192, 12, 64)
+
+
+# --------------------------------------------------------------------- #
+# The attention operator at the benchmark cells' shapes                  #
+# --------------------------------------------------------------------- #
+_LFM2_ATTENTION = dict(n_kv_heads=8, qk_norm=True, rope_style="half",
+                       gated_mlp=True, layer_types=("full_attention",),
+                       rope_theta=1e6, norm_eps=1e-5)
+
+
+@pytest.mark.parametrize("shape,heads,block", [
+    ((8, 1024, 1600), 25, {}),              # train-xl-fsdp4, one chip's
+    ((4, 1024, 1024), 16, {}),              # train-medium-1k
+    ((4, 1024, 2048), 32, _LFM2_ATTENTION),  # rotate-half, GQA 32 / 8
+], ids=["xl-25-heads", "medium-16-heads", "rotate-half-gqa"])
+def test_attention_operator_rotates_without_splitting_the_head(
+        one_chip, chip_dispatch, shape, heads, block):
+    """``GPT._self_attention`` forward + backward, bf16 over f32
+    weights, flash block 1024.  ``_rope`` keeps the 64-wide head whole
+    on the lanes: a strided lane slice compiles to a gather, its
+    gradient to a scatter-add into a zero-filled buffer, the stack /
+    reshape to relayouts through ``[.., 32, 2]`` arrays (six passes over
+    q and over k a layer where one does, PR 30).  None of them may come
+    back."""
+    b, s, d = shape
+    model = GPT(TransformerConfig(
+        vocab_size=512, d_model=d, n_heads=heads, d_ff=4 * d, n_layers=1,
+        max_seq_len=s, flash_block_q=1024, flash_block_k=1024, **block))
+    model.compute_dtype = jnp.bfloat16
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    attn = jax.tree.map(
+        lambda p: _sds(p.shape[1:], p.dtype, one_chip),
+        params[model.cfg.run_keys()[0]]["attn"])
+
+    def loss(a, x):
+        out, _ = model._self_attention(x, a, jnp.arange(s))
+        return out.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        attn, _sds(shape, jnp.bfloat16, one_chip))
+    assert set(_kernels(lowered)) >= {"flash_bwd_fused", "flash_fwd"}
+    text = lowered.compile().as_text()
+    under_attn = [line for line in text.splitlines() if "gpt/attn" in line]
+    assert under_attn                   # the scope reached the text
+    moved = [line.split(" = ")[0].strip() for line in under_attn
+             if re.search(r"\b(gather|scatter)", line.split("metadata")[0])]
+    assert moved == []
+    assert re.findall(r"\[[0-9,]*\b32,[12]\]", text) == []
 
 
 # --------------------------------------------------------------------- #
