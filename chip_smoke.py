@@ -26,6 +26,10 @@ prints no result:
               experts held, 2048 x 1792; the gated short convolution at
               [4, 8192, 2048]) against the float32 reference, forward
               and backward, rows and milliseconds per call printed.
+              Then the ``train-nemotron3-ssm-8k`` cell's two (the Mamba-2
+              mixer, 16 heads of 64 with state 128 in chunks of 128, and
+              the latent expert layer, 8 of 512 experts held, top-22, at
+              [1, 8192, 4096]) the same way.
 3. *train*    ``Trainer.fit(GPT, DataLoader)``: finite, falling loss,
               zero compiles in the second epoch, the flash forward and
               backward kernels in the train step's lowering, peak HBM.
@@ -341,6 +345,43 @@ def phase_flash_cell_shape(seed: int, iters: int = 100) -> None:
          bwd_us_per_head=(seconds["fwd_bwd"] - seconds["fwd"]) * per_head)
 
 
+def _operator_check(phase, name, system, reference, params, *, x, g, iters,
+                    on_chip, **fields):
+    """``system`` / ``reference``: (params, x) -> y.  Compared with their
+    VJPs at cotangent ``g`` on the first sequence, then the system timed
+    on the batch; one record under ``phase``."""
+    import jax
+    import jax.numpy as jnp
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / iters * 1e3
+
+    fwd = jax.jit(system)
+    both = jax.jit(lambda p, x_, g_: jax.vjp(system, p, x_)[1](g_))
+    if on_chip:
+        names = kernels_in(fwd.lower(params, x))
+        require("moe" not in name or names,
+                "the expert layer lowered without its kernel")
+        fields["kernels"] = names
+    y, vjp = jax.vjp(reference, params, x[:1])
+    errs = {"fwd": _rel_err(fwd(params, x[:1]), y)}
+    got, want = both(params, x[:1], g[:1]), vjp(g[:1])
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(
+            got)[0], jax.tree.leaves(want)):
+        if float(jnp.max(jnp.abs(b))) > 0:      # a buffer has none
+            errs["d" + jax.tree_util.keystr(path)] = _rel_err(a, b)
+    emit(phase, op=name, shape=list(x.shape), errs=errs,
+         fwd_ms=timed(fwd, params, x),
+         fwd_bwd_ms=timed(both, params, x, g), **fields)
+    worst = max(errs.values())
+    require(worst <= TOL_LFM2, f"{name}: error {worst} ({errs})")
+
+
 def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
                            d_model: int = 2048, expert_width: int = 1792,
                            experts: int = 32, held: int = 8, top_k: int = 4,
@@ -369,38 +410,8 @@ def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
              "moe_norm_topk": True, "moe_routed_scale": 1.0}
     x = jax.random.normal(next(keys), (batch, seq, d_model), jnp.float32)
     g = jax.random.normal(next(keys), x.shape, jnp.float32)
-
-    def timed(fn, *args):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e3
-
-    def check(name, system, reference, params, **fields):
-        """``system`` / ``reference``: (params, x) -> y.  Compared with
-        their VJPs at cotangent ``g`` on the first sequence, then the
-        system timed on the batch."""
-        fwd = jax.jit(system)
-        both = jax.jit(lambda p, x_, g_: jax.vjp(system, p, x_)[1](g_))
-        if on_chip:
-            names = kernels_in(fwd.lower(params, x))
-            require(not name.startswith("dropless_moe") or names,
-                    "the expert layer lowered without its kernel")
-            fields["kernels"] = names
-        y, vjp = jax.vjp(reference, params, x[:1])
-        errs = {"fwd": _rel_err(fwd(params, x[:1]), y)}
-        got, want = both(params, x[:1], g[:1]), vjp(g[:1])
-        for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(
-                got)[0], jax.tree.leaves(want)):
-            if float(jnp.max(jnp.abs(b))) > 0:      # the bias has none
-                errs["d" + jax.tree_util.keystr(path)] = _rel_err(a, b)
-        emit("lfm2_cell_shapes", op=name, shape=list(x.shape), errs=errs,
-             fwd_ms=timed(fwd, params, x),
-             fwd_bwd_ms=timed(both, params, x, g), **fields)
-        worst = max(errs.values())
-        require(worst <= TOL_LFM2, f"{name}: error {worst} ({errs})")
+    check = functools.partial(_operator_check, "lfm2_cell_shapes", x=x, g=g,
+                              iters=iters, on_chip=on_chip)
 
     p = moe.init_dropless_params(next(keys), d_model, expert_width, experts,
                                  held)
@@ -442,6 +453,67 @@ def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
               c_["conv_w"], c_["w_out"].astype(jnp.bfloat16)
               ).astype(jnp.float32),
           lambda c_, x_: ref.conv_operator(x_, c_), c)
+
+
+def phase_nemotron_cell_shapes(seed: int, *, batch: int = 1, seq: int = 8192,
+                               d_model: int = 4096, ssm_heads: int = 16,
+                               ssm_head_dim: int = 64, ssm_state: int = 128,
+                               chunk: int = 128, latent: int = 1024,
+                               expert_width: int = 2688,
+                               shared_width: int = 5376, experts: int = 512,
+                               held: int = 8, top_k: int = 22,
+                               iters: int = 5, on_chip: bool = True) -> None:
+    """The ``train-nemotron3-ssm-8k`` cell's two new operators at its
+    shapes (the Mamba-2 mixer: 16 heads of 64 = one of 8 groups held,
+    state 128, chunks of 128, at [1, 8192, 4096]; the latent expert
+    layer: 8 of 512 experts held, top-22, ReLU^2 experts of 2688 in a
+    latent of 1024, a shared expert of 5376) in bfloat16 against the
+    plain float32 reference (the recurrence one position at a time),
+    forward and VJP; rows and milliseconds per call printed."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_accelerators_tpu.models import (
+        reference_nemotron_h as ref)
+    from ray_lightning_accelerators_tpu.ops import moe, ssm
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 3), 4))
+    ids = tuple(range(held))
+    model = {"num_experts": experts, "moe_top_k": top_k,
+             "moe_norm_topk": True, "moe_routed_scale": 5.0,
+             "norm_eps": 1e-5, "ssm_heads": ssm_heads, "ssm_groups": 1,
+             "ssm_head_dim": ssm_head_dim, "ssm_state": ssm_state}
+    x = jax.random.normal(next(keys), (batch, seq, d_model), jnp.float32)
+    g = jax.random.normal(next(keys), x.shape, jnp.float32)
+    check = functools.partial(_operator_check, "nemotron_cell_shapes", x=x,
+                              g=g, iters=iters, on_chip=on_chip)
+
+    check("mamba2_mixer",
+          lambda p_, x_: ssm.mamba2_mixer(
+              x_.astype(jnp.bfloat16), p_, heads=ssm_heads,
+              head_dim=ssm_head_dim, groups=1, state=ssm_state, chunk=chunk,
+              eps=1e-5).astype(jnp.float32),
+          lambda p_, x_: ref.mamba_mixer(x_, p_, model),
+          ssm.init_mamba2_params(next(keys), d_model, ssm_heads,
+                                 ssm_head_dim, 1, ssm_state, 4))
+
+    def layer(p_, x_):
+        return moe.latent_moe(x_, p_, top_k=top_k, held=ids,
+                              num_experts=experts, norm_topk=True,
+                              scale=5.0)
+
+    p = moe.init_latent_moe_params(next(keys), d_model, latent,
+                                   expert_width, shared_width, experts,
+                                   held)
+    stats = jax.jit(lambda p_, x_: layer(p_, x_)[1])(p, x)
+    require(float(stats["rows_routed"]) == float(stats["rows_computed"])
+            and float(stats["rounds"]) == 1.0,
+            f"latent_moe: a routed row was not computed, or not in one "
+            f"window: {stats}")
+    check("latent_moe", lambda p_, x_: layer(p_, x_)[0],
+          lambda p_, x_: ref.latent_block(x_, p_, model, ids)[0], p,
+          rows_in=batch * seq, rows_routed=float(stats["rows_routed"]),
+          load_max_over_mean=float(stats["load_max_over_mean"]))
 
 
 # --------------------------------------------------------------------- #
@@ -847,6 +919,7 @@ def main(argv=None) -> None:
         phase_kernels(size, args.seed)
         phase_flash_cell_shape(args.seed)
         phase_lfm2_cell_shapes(args.seed)
+        phase_nemotron_cell_shapes(args.seed)
         phase_train(size, args.seed)
         model, params = phase_generate(size, args.seed)
         phase_serve(size, args.seed, model, params)

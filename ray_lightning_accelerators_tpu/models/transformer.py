@@ -35,9 +35,12 @@ from ..parallel.ring_attention import ring_attention_sharded
 from ..ops.attention import flash_attention
 from ..ops.conv import gated_short_conv
 from ..ops.moe import (dropless_logical_axes, dropless_moe,
-                       init_dropless_params, init_moe_params,
+                       init_dropless_params, init_latent_moe_params,
+                       init_moe_params, latent_moe, latent_moe_logical_axes,
                        moe_logical_axes, moe_mlp)
 from ..ops.norms import rms_norm
+from ..ops.ssm import (init_mamba2_params, mamba2_logical_axes,
+                       mamba2_mixer)
 from ..utils.logging import log
 from ..utils.scope import scoped
 
@@ -136,13 +139,49 @@ class TransformerConfig:
     gated_mlp: bool = False       # SwiGLU (silu(x W1) * (x W3)) W2
     qk_norm: bool = False         # per-head RMSNorm on q and k before rope
     # "interleaved": pairs (2i, 2i+1); "half": pairs (i, i + head_dim/2),
-    # the rotate-half convention of the published checkpoints
+    # the rotate-half convention of the published checkpoints; "none": no
+    # rotation (a hybrid stack whose state-space layers carry position)
     rope_style: str = "interleaved"
     norm_eps: float = 1e-6
+    # ---- a hybrid stack of single-part layers (nemotron_h) ------------ #
+    # The published pattern, one letter a layer, each layer ONE part with
+    # its own norm and residual: "M" a Mamba-2 mixer (ops/ssm.py), "E" a
+    # latent expert layer (ops/moe.latent_moe: ``num_experts`` ReLU^2
+    # experts of width ``moe_d_ff`` in a latent of ``moe_latent_dim``,
+    # one shared ReLU^2 expert of ``moe_shared_d_ff`` at full width, the
+    # sigmoid router's fields above), "*" attention (no QK-norm, no
+    # rotation).  A mixer followed by "E" is this file's block exactly
+    # (operator, then feed-forward, each with norm and residual), so the
+    # letters pair into blocks whose operator or feed-forward may be
+    # ABSENT, and consecutive blocks of one kind are one run:
+    # "EMEMEMEMEM*" is (none, latent), 4 x (mamba, latent), (mamba,
+    # none), (attn, none).  ``n_layers`` counts the letters.
+    hybrid_pattern: Optional[str] = None
+    # the head size as a value; None = d_model // n_heads
+    attn_head_dim: Optional[int] = None
+    # ids of the query heads this chip holds, whole groups of their KV
+    # heads' readers (``n_heads`` / ``n_kv_heads`` stay the model's
+    # counts; a KV head is held when a held query head reads it); None =
+    # all of them
+    attn_heads_held: Optional[Tuple[int, ...]] = None
+    # the Mamba-2 mixer: ``ssm_heads`` heads of ``ssm_head_dim`` in
+    # ``ssm_groups`` groups (a group's heads share its B and C and one
+    # gated norm), state ``ssm_state``, ``conv_kernel`` taps, scanned in
+    # chunks of ``ssm_chunk``; ids of the groups this chip holds (None =
+    # all of them)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_chunk: int = 128
+    ssm_groups_held: Optional[Tuple[int, ...]] = None
+    moe_latent_dim: Optional[int] = None
+    moe_shared_d_ff: Optional[int] = None
 
     def __post_init__(self):
         # lists from JSON (a benchmark config, a checkpoint's hparams)
-        for name in ("layer_types", "moe_experts_held"):
+        for name in ("layer_types", "moe_experts_held", "attn_heads_held",
+                     "ssm_groups_held"):
             value = getattr(self, name)
             if value is not None:
                 setattr(self, name, tuple(value))
@@ -157,17 +196,35 @@ class TransformerConfig:
                    ("norm_eps", 1e-6))
     _MIXED_BLOCK = (("gated_mlp", True), ("qk_norm", True),
                     ("rope_style", "half"))
+    # these belong to ``hybrid_pattern`` and keep their defaults without
+    # it; with it the block's switches take ``_HYBRID_BLOCK``'s values
+    _HYBRID_ONLY = (("attn_head_dim", None), ("attn_heads_held", None),
+                    ("ssm_heads", 0), ("ssm_groups_held", None),
+                    ("moe_latent_dim", None), ("moe_shared_d_ff", None))
+    _HYBRID_BLOCK = (("gated_mlp", False), ("qk_norm", False),
+                     ("rope_style", "none"), ("layer_types", None),
+                     ("num_dense_layers", 0), ("moe_router", "sigmoid"))
 
     @property
     def _mixed(self) -> bool:
-        return self.layer_types is not None or self.moe_router == "sigmoid"
+        return (self.layer_types is not None or self.moe_router == "sigmoid"
+                or self.hybrid_pattern is not None)
 
     def layer_runs(self) -> Tuple[Tuple[str, str, int], ...]:
-        """``((operator, feed_forward, n_layers), ...)`` in stack order.
-        operator: "conv" | "attn"; feed_forward: "dense" | "capacity"
-        (``ops/moe.moe_mlp``) | "sparse" (``ops/moe.dropless_moe``)."""
+        """``((operator, feed_forward, n_blocks), ...)`` in stack order.
+        operator: "conv" | "attn" | "mamba" (``ops/ssm.mamba2_mixer``) |
+        "none"; feed_forward: "dense" | "capacity" (``ops/moe.moe_mlp``)
+        | "sparse" (``ops/moe.dropless_moe``) | "latent"
+        (``ops/moe.latent_moe``) | "none"."""
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.hybrid_pattern is not None:
+            return self._hybrid_runs()
+        for name, default in self._HYBRID_ONLY:
+            if getattr(self, name) != default:
+                raise ValueError(
+                    f"TransformerConfig.{name} belongs to a hybrid stack: "
+                    "set hybrid_pattern with it")
         if not self._mixed:
             for name, default in self._MIXED_ONLY:
                 if getattr(self, name) != default:
@@ -204,6 +261,55 @@ class TransformerConfig:
                 runs.append([*key, 1])
         return tuple(tuple(r) for r in runs)
 
+    def _hybrid_runs(self) -> Tuple[Tuple[str, str, int], ...]:
+        """``layer_runs()`` of ``hybrid_pattern``: the letters paired
+        into blocks, the blocks grouped into runs.  What has no
+        reference behind it is refused by name."""
+        pattern = self.hybrid_pattern
+        for name, needed in self._HYBRID_BLOCK:
+            if getattr(self, name) != needed:
+                raise NotImplementedError(
+                    f"a hybrid stack (hybrid_pattern) runs {name}="
+                    f"{needed!r} only")
+        if len(pattern) != self.n_layers or set(pattern) - set("ME*"):
+            raise NotImplementedError(
+                f"hybrid_pattern must name n_layers={self.n_layers} layers,"
+                " each 'M' (Mamba-2), 'E' (latent expert layer) or '*' "
+                f"(attention); got {pattern!r} (a dense '-' layer has no "
+                "reference here)")
+        if "E" in pattern and not (
+                self.num_experts > 1 and self.moe_d_ff
+                and self.moe_latent_dim and self.moe_shared_d_ff):
+            raise NotImplementedError(
+                "an 'E' layer is the latent expert layer with one shared "
+                "expert: set num_experts, moe_d_ff, moe_latent_dim and "
+                "moe_shared_d_ff")
+        if "M" in pattern and (self.ssm_heads <= 0
+                               or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                f"ssm_heads ({self.ssm_heads}) must be a positive multiple "
+                f"of ssm_groups ({self.ssm_groups})")
+        per_kv = self.n_heads // self.kv_heads
+        held, kv_held = self.heads_held, self.kv_heads_held
+        if list(held) != sorted(set(held)) or [
+                h // per_kv for h in held] != [
+                kv for kv in kv_held
+                for _ in range(len(held) // len(kv_held))]:
+            raise NotImplementedError(
+                "attn_heads_held must be ascending and give every held KV "
+                f"head as many readers as the others; got {held}")
+        runs, i = [], 0
+        while i < len(pattern):
+            op = {"M": "mamba", "*": "attn", "E": "none"}[pattern[i]]
+            i += op != "none"
+            ff = "latent" if pattern[i:i + 1] == "E" else "none"
+            i += ff != "none"
+            if runs and runs[-1][:2] == [op, ff]:
+                runs[-1][2] += 1
+            else:
+                runs.append([op, ff, 1])
+        return tuple(tuple(r) for r in runs)
+
     def run_keys(self) -> Tuple[str, ...]:
         """The parameter subtree of each run, in stack order."""
         if not self._mixed:
@@ -217,8 +323,28 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim is not None:
+            return self.attn_head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def heads_held(self) -> Tuple[int, ...]:
+        held = self.attn_heads_held
+        return tuple(range(self.n_heads)) if held is None else held
+
+    @property
+    def kv_heads_held(self) -> Tuple[int, ...]:
+        """The KV heads the held query heads read."""
+        per_kv = self.n_heads // self.kv_heads
+        return tuple(sorted({h // per_kv for h in self.heads_held}))
+
+    @property
+    def ssm_held(self) -> Tuple[int, int]:
+        """(heads, groups) of the Mamba-2 mixer held here."""
+        groups = (self.ssm_groups if self.ssm_groups_held is None
+                  else len(self.ssm_groups_held))
+        return groups * (self.ssm_heads // self.ssm_groups), groups
 
     @property
     def kv_heads(self) -> int:
@@ -418,19 +544,25 @@ class GPT(TpuModule):
         split to 8.  A tree drawn with one count cannot be drawn again
         with the other (checkpoints, the benchmark's ``weights_seed``)."""
         cfg = self.cfg
-        d, h, kv, hd, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads,
-                           cfg.head_dim, cfg.d_ff)
+        d, h, kv, hd, f = (cfg.d_model, len(cfg.heads_held),
+                           len(cfg.kv_heads_held), cfg.head_dim, cfg.d_ff)
         ks = jax.random.split(key, 8 if cfg.gated_mlp else 6)
-        out = {"ln1": jnp.ones((d,), jnp.float32),
-               "ln2": jnp.ones((d,), jnp.float32)}
-        if op == "conv":
+        # an absent part has no norm either
+        out = {name: jnp.ones((d,), jnp.float32)
+               for name, kind in (("ln1", op), ("ln2", ff))
+               if kind != "none"}
+        if op == "mamba":
+            out["ssm"] = init_mamba2_params(
+                ks[0], d, cfg.ssm_held[0], cfg.ssm_head_dim,
+                cfg.ssm_held[1], cfg.ssm_state, cfg.conv_kernel)
+        elif op == "conv":
             out["conv"] = {
                 "w_in": _dense(ks[0], (d, 3 * d), d),
                 "conv_w": _dense(ks[1], (d, cfg.conv_kernel),
                                  cfg.conv_kernel),
                 "w_out": _dense(ks[2], (d, d), d),
             }
-        else:
+        elif op == "attn":
             out["attn"] = {
                 "wq": _dense(ks[0], (d, h, hd), d),
                 "wk": _dense(ks[1], (d, kv, hd), d),
@@ -444,13 +576,17 @@ class GPT(TpuModule):
             out["mlp"] = init_dropless_params(
                 ks[4], d, cfg.moe_d_ff or f, cfg.num_experts,
                 len(cfg.experts_held))
+        elif ff == "latent":
+            out["mlp"] = init_latent_moe_params(
+                ks[4], d, cfg.moe_latent_dim, cfg.moe_d_ff,
+                cfg.moe_shared_d_ff, cfg.num_experts, len(cfg.experts_held))
         elif ff == "capacity":
             out["mlp"] = init_moe_params(ks[4], d, f, cfg.num_experts)
-        elif cfg.gated_mlp:
+        elif ff == "dense" and cfg.gated_mlp:
             out["mlp"] = {"w1": _dense(ks[4], (d, f), d),
                           "w3": _dense(ks[5], (d, f), d),
                           "w2": _dense(ks[6], (f, d), f)}
-        else:
+        elif ff == "dense":
             out["mlp"] = {"wi": _dense(ks[4], (d, f), d),
                           "wo": _dense(ks[5], (f, d), f)}
         return out
@@ -458,13 +594,17 @@ class GPT(TpuModule):
     def _layer_logical_axes(self, op: str, ff: str) -> Dict[str, Any]:
         """The logical axes of ``_init_layer``'s leaves, stacked."""
         cfg = self.cfg
-        axes: Dict[str, Any] = {"ln1": ("layers", None),
-                                "ln2": ("layers", None)}
-        if op == "conv":
+        axes: Dict[str, Any] = {
+            name: ("layers", None)
+            for name, kind in (("ln1", op), ("ln2", ff)) if kind != "none"}
+        if op == "mamba":
+            axes["ssm"] = {name: ("layers",) + ax
+                           for name, ax in mamba2_logical_axes().items()}
+        elif op == "conv":
             axes["conv"] = {"w_in": ("layers", "embed", "mlp"),
                             "conv_w": ("layers", None, None),
                             "w_out": ("layers", "mlp", "embed")}
-        else:
+        elif op == "attn":
             axes["attn"] = {"wq": ("layers", "embed", "heads", "kv"),
                             "wk": ("layers", "embed", "heads", "kv"),
                             "wv": ("layers", "embed", "heads", "kv"),
@@ -477,11 +617,15 @@ class GPT(TpuModule):
                            else moe_logical_axes())
             axes["mlp"] = {name: ("layers",) + ax
                            for name, ax in expert_axes.items()}
-        elif cfg.gated_mlp:
+        elif ff == "latent":
+            axes["mlp"] = jax.tree.map(
+                lambda ax: ("layers",) + ax, latent_moe_logical_axes(),
+                is_leaf=lambda ax: isinstance(ax, tuple))
+        elif ff == "dense" and cfg.gated_mlp:
             axes["mlp"] = {"w1": ("layers", "embed", "mlp"),
                            "w3": ("layers", "embed", "mlp"),
                            "w2": ("layers", "mlp", "embed")}
-        else:
+        elif ff == "dense":
             axes["mlp"] = {"wi": ("layers", "embed", "mlp"),
                            "wo": ("layers", "mlp", "embed")}
         return axes
@@ -514,7 +658,9 @@ class GPT(TpuModule):
             raise NotImplementedError(
                 f"{what} walks params['layers'] as one uniform stack of "
                 "attention blocks; a mixed layer stack (TransformerConfig"
-                ".layer_types / moe_router='sigmoid') trains only")
+                ".layer_types / moe_router='sigmoid' / hybrid_pattern: "
+                "conv, Mamba-2 and expert layers, layers of one part) "
+                "trains only")
         return self.cfg.layer_runs()[0][1]
 
     # ------------------------------------------------------------------ #
@@ -635,6 +781,8 @@ class GPT(TpuModule):
             def rotated(t, scale):
                 if cfg.qk_norm:     # per-head RMSNorm before the rotation
                     t = rms_norm(t, a[scale], cfg.norm_eps)
+                if cfg.rope_style == "none":
+                    return t
                 return _rope(t, positions, cfg.rope_theta, cfg.rope_style)
 
             q = jnp.einsum("bsd,dhk->bhsk", x, self._wt(a["wq"], dt))
@@ -658,7 +806,7 @@ class GPT(TpuModule):
                                 mesh_lib.SEQUENCE_AXIS, None)
             v = self._constrain(v, mesh_lib.BATCH_AXES, kv_axis,
                                 mesh_lib.SEQUENCE_AXIS, None)
-            groups = cfg.n_heads // cfg.kv_heads
+            groups = len(cfg.heads_held) // len(cfg.kv_heads_held)
             if groups > 1:  # GQA: broadcast each KV head over its group
                 kr = jnp.repeat(k, groups, axis=1)
                 vr = jnp.repeat(v, groups, axis=1)
@@ -678,8 +826,8 @@ class GPT(TpuModule):
         dropless path (which names its own scopes) its row counters."""
         cfg = self.cfg
         dt = self.compute_dtype
-        if ff == "sparse":
-            return dropless_moe(
+        if ff in ("sparse", "latent"):
+            return (dropless_moe if ff == "sparse" else latent_moe)(
                 x, m, top_k=cfg.moe_top_k, held=cfg.experts_held,
                 num_experts=cfg.num_experts, norm_topk=cfg.moe_norm_topk,
                 scale=cfg.moe_routed_scale, compute_dtype=dt,
@@ -701,30 +849,41 @@ class GPT(TpuModule):
             return proj(up, m["w2" if cfg.gated_mlp else "wo"], dt), {}
 
     def _block(self, h, lp, positions, op: str, ff: str, dropout_rng=None):
-        """One layer: ``h + op(norm(h))``, then ``+ ff(norm(.))``.
+        """One layer: ``h + op(norm(h))``, then ``+ ff(norm(.))``; a
+        part of kind "none" is absent, with its norm and residual.
         Returns ``(h, stats, kv)``: the feed-forward's counters (``{}``
-        for a dense one) and the attention operator's ``(k, v)`` (None
-        for a conv).  The residual adds and dropout belong to this frame,
-        outside the operators' scopes."""
+        for a dense or absent one) and the attention operator's ``(k,
+        v)`` (None for any other).  The residual adds and dropout belong
+        to this frame, outside the operators' scopes."""
         dt = self.compute_dtype
-        r_op = None
+        cfg = self.cfg
+        r_op, kv, stats = None, None, {}
         if dropout_rng is not None:
             dropout_rng, r_op = jax.random.split(dropout_rng)
-        x = self._rms_norm(h, lp["ln1"])
-        if op == "conv":
-            with jax.named_scope("gpt/conv"):
-                c = lp["conv"]
-                y, kv = gated_short_conv(
-                    x, self._wt(c["w_in"], dt), c["conv_w"],
-                    self._wt(c["w_out"], dt)), None
-        else:
-            y, kv = self._self_attention(x, lp["attn"], positions)
-        h = h + self._dropout(y, r_op)
-        x = self._rms_norm(h, lp["ln2"])
-        y, stats = self._feed_forward(x, lp["mlp"], ff,
-                                      self._mlp_train_matmul)
-        h = self._constrain(h + self._dropout(y, dropout_rng),
-                            mesh_lib.BATCH_AXES, mesh_lib.SEQUENCE_AXIS,
+        if op != "none":
+            x = self._rms_norm(h, lp["ln1"])
+            if op == "conv":
+                with jax.named_scope("gpt/conv"):
+                    c = lp["conv"]
+                    y = gated_short_conv(
+                        x, self._wt(c["w_in"], dt), c["conv_w"],
+                        self._wt(c["w_out"], dt))
+            elif op == "mamba":
+                with jax.named_scope("gpt/ssm"):
+                    y = mamba2_mixer(
+                        x, lp["ssm"], heads=cfg.ssm_held[0],
+                        head_dim=cfg.ssm_head_dim, groups=cfg.ssm_held[1],
+                        state=cfg.ssm_state, chunk=cfg.ssm_chunk,
+                        eps=cfg.norm_eps, compute_dtype=dt)
+            else:
+                y, kv = self._self_attention(x, lp["attn"], positions)
+            h = h + self._dropout(y, r_op)
+        if ff != "none":
+            x = self._rms_norm(h, lp["ln2"])
+            y, stats = self._feed_forward(x, lp["mlp"], ff,
+                                          self._mlp_train_matmul)
+            h = h + self._dropout(y, dropout_rng)
+        h = self._constrain(h, mesh_lib.BATCH_AXES, mesh_lib.SEQUENCE_AXIS,
                             None)
         return h, stats, kv
 
@@ -919,8 +1078,19 @@ class GPT(TpuModule):
         return self.forward(params, batch)
 
     def configure_optimizers(self):
-        tx = optax.adamw(self.lr, weight_decay=0.01)
-        if not any(ff == "sparse" for _, ff, _ in self.cfg.layer_runs()):
+        runs = self.cfg.layer_runs()
+        if any(op == "mamba" for op, _, _ in runs):
+            # the state-space layers' decay rates, step biases and skips
+            # are no matrices: no weight decay (the family's convention)
+            def decayed(params):
+                return jax.tree_util.tree_map_with_path(
+                    lambda path, _: getattr(path[-1], "key", None) not in (
+                        "a_log", "dt_bias", "d_skip"), params)
+
+            tx = optax.adamw(self.lr, weight_decay=0.01, mask=decayed)
+        else:
+            tx = optax.adamw(self.lr, weight_decay=0.01)
+        if not any(ff in ("sparse", "latent") for _, ff, _ in runs):
             return tx
 
         # the selection bias is a buffer: no update (AdamW's decay would
@@ -975,7 +1145,8 @@ class GPT(TpuModule):
             raise NotImplementedError(
                 "GPT.quantize_weights walks params['layers'] as one "
                 "uniform stack; a mixed layer stack (TransformerConfig"
-                ".layer_types ...: params['layers_<i>']) trains only")
+                ".layer_types / hybrid_pattern ...: params['layers_<i>']) "
+                "trains only")
         out = {k: v for k, v in params.items()}
         out["layers"] = jax.tree.map(lambda a: quant(a, True),
                                      params["layers"])

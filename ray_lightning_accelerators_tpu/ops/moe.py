@@ -184,14 +184,14 @@ def moe_logical_axes() -> Dict[str, Any]:
 # Dropless path: the experts held here                                   #
 # --------------------------------------------------------------------- #
 def sigmoid_routing(x: jax.Array, router: jax.Array, bias: jax.Array, *,
-                    top_k: int, norm_topk: bool, scale: float
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    top_k: int, norm_topk: bool, scale: float,
+                    eps: float = 1e-6) -> Tuple[jax.Array, jax.Array]:
     """``s = sigmoid(x W_g)`` in float32 at full matmul precision (a
     near-tied choice flips on a rounded score and costs a whole expert);
     ``sel = top_k(s + bias)``, the bias a buffer that only steers the
     selection; weights ``s[sel]``, normalised over ALL chosen experts
-    (held or not) and scaled.  x: [t, d] -> (ids [t, k] int32,
-    weights [t, k] float32)."""
+    (held or not; ``eps`` beside their sum is the published model's) and
+    scaled.  x: [t, d] -> (ids [t, k] int32, weights [t, k] float32)."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -202,26 +202,39 @@ def sigmoid_routing(x: jax.Array, router: jax.Array, bias: jax.Array, *,
     chosen = ids[..., None] == jnp.arange(s.shape[-1])
     w = jnp.sum(jnp.where(chosen, s[..., None, :], 0.0), axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return ids.astype(jnp.int32), w * scale
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch_rows(x, pairs, pos, live, top_k):
+def _rows_to_tokens(rows, pairs, top_k: int, t: int):
+    """``out[token] = sum of rows[i] over the window's sorted positions i
+    of that token's pairs``: a scatter-add of the WINDOW's rows in
+    float32 (a row no group owns arrives as zeros and adds nothing)."""
+    out = jnp.zeros((t, rows.shape[1]), jnp.float32)
+    return out.at[pairs // top_k].add(rows.astype(jnp.float32)).astype(
+        rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _dispatch_rows(x, pairs, pos, live, top_k, scatter):
     """Row ``pairs[i] // top_k`` of ``x`` for the sorted pairs of one
     window.  Every (token, choice) pair sits at one sorted position, so
     the transpose is a gather by that position (``pos``, clipped to the
     window; ``live`` where the window holds the pair) and a sum over the
-    choices, not a scatter-add."""
+    choices, not a scatter-add -- unless ``scatter`` says the window is
+    a small part of the pairs (``_token_side_by_scatter``): then the
+    window's rows are added into their tokens."""
     return x[pairs // top_k]
 
 
-def _dispatch_fwd(x, pairs, pos, live, top_k):
-    return x[pairs // top_k], (pos, live, x.shape[0])
+def _dispatch_fwd(x, pairs, pos, live, top_k, scatter):
+    return x[pairs // top_k], (pairs, pos, live, x.shape[0])
 
 
-def _dispatch_bwd(top_k, res, g):
-    pos, live, t = res
+def _dispatch_bwd(top_k, scatter, res, g):
+    pairs, pos, live, t = res
+    if scatter:
+        return _rows_to_tokens(g, pairs, top_k, t), None, None, None
     g = jnp.where(live[:, None], g[pos], 0)
     return (g.reshape(t, top_k, -1).sum(1).astype(g.dtype), None, None,
             None)
@@ -235,24 +248,33 @@ def _rows_of_pairs(ys, w, pos):
     return ys[pos].reshape(*w.shape, -1)
 
 
-@jax.custom_vjp
-def _combine_rows(ys, w, pairs, pos):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_rows(ys, w, pairs, pos, scatter):
     """``y[t] = sum_k w[t, k] * ys[pos[t * top_k + k]]``: the window's
     output rows back at their tokens (``w`` is zero where the window
-    does not hold the pair).  Transposed on the sorted side:
-    ``d ys[i] = w[pair i] * g[token of pair i]``, a gather of the
-    window's rows from ``[t, d]``."""
+    does not hold the pair), gathered by the token side, or with
+    ``scatter`` weighted on the sorted side and added into their tokens.
+    Transposed on the sorted side: ``d ys[i] = w[pair i] * g[token of
+    pair i]``, a gather of the window's rows from ``[t, d]``."""
+    if scatter:
+        return _rows_to_tokens(w.reshape(-1)[pairs][:, None] * ys, pairs,
+                               w.shape[1], w.shape[0])
     return jnp.einsum("tk,tkd->td", w, _rows_of_pairs(ys, w, pos))
 
 
-def _combine_fwd(ys, w, pairs, pos):
-    return _combine_rows(ys, w, pairs, pos), (ys, w, pairs, pos)
+def _combine_fwd(ys, w, pairs, pos, scatter):
+    return _combine_rows(ys, w, pairs, pos, scatter), (ys, w, pairs, pos)
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(scatter, res, g):
     ys, w, pairs, pos = res
-    d_ys = w.reshape(-1)[pairs][:, None] * g[pairs // w.shape[1]]
-    d_w = jnp.einsum("td,tkd->tk", g, _rows_of_pairs(ys, w, pos))
+    g_rows = g[pairs // w.shape[1]]
+    d_ys = w.reshape(-1)[pairs][:, None] * g_rows
+    if scatter:     # each pair's product on the sorted side, then home
+        d_w = jnp.zeros((w.size,), w.dtype).at[pairs].add(jnp.sum(
+            g_rows.astype(w.dtype) * ys, -1)).reshape(w.shape)
+    else:
+        d_w = jnp.einsum("td,tkd->tk", g, _rows_of_pairs(ys, w, pos))
     return d_ys, d_w, None, None
 
 
@@ -351,6 +373,16 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
+def _token_side_by_scatter(m: int, pairs: int) -> bool:
+    """Whether a window of ``m`` sorted rows meets the tokens by a
+    scatter-add of its own rows rather than by a gather of every pair's
+    row: under a quarter of the pairs.  The gather reads ``pairs`` rows
+    whatever the window holds; with 8 of 512 experts held and 22 choices
+    a token a window is 4,608 of an 8k sequence's 180,224 pairs and two
+    such gathers a layer are 369 MB each."""
+    return 4 * m <= pairs
+
+
 def window_rows(pairs: int, n_held: int, num_experts: int) -> int:
     """Rows of one window of the sorted (token, choice) pairs: the held
     experts' nominal share of them with ``WINDOW_SPARE`` over it, in
@@ -360,20 +392,23 @@ def window_rows(pairs: int, n_held: int, num_experts: int) -> int:
 
 
 def _window(diff, ints, start, *, m: int, top_k: int, mesh):
-    """The held experts' SwiGLU over sorted positions ``[start, start +
-    m)`` and what those rows add to every token: ``(y [t, d], pairs
-    whose output row came back)``.
+    """The held experts over sorted positions ``[start, start + m)``
+    and what those rows add to every token: ``(y [t, d], pairs whose
+    output row came back)``.
 
     diff: ``rows`` [t, d], ``w`` [t, top_k] (zero for a pair whose
-    expert is absent), ``w1`` / ``w3`` / ``w2``, all in the compute
-    dtype but ``w``.  ints: ``order`` (the pairs by sorted position, at
+    expert is absent), then the experts' weights, all in the compute
+    dtype but ``w``: ``w1`` / ``w3`` / ``w2`` are SwiGLU's three
+    products, ``w1`` / ``w2`` alone ReLU squared's two.  ints:
+    ``order`` (the pairs by sorted position, at
     least ``start + m`` long), ``inverse`` (the sorted position of every
     pair), ``first`` / ``last`` (each held expert's interval of sorted
     positions), ``n_rows`` (pairs whose expert is held: they sort
     first)."""
-    rows, w, w1, w3, w2 = diff
+    rows, w, w_up, *w_gate, w_down = diff
     order, inverse, first, last, n_rows = ints
     t, dt = rows.shape[0], rows.dtype
+    scatter = _token_side_by_scatter(m, t * top_k)
     with jax.named_scope("gpt/moe_dispatch"):
         group_sizes = (jnp.clip(last, start, start + m)
                        - jnp.clip(first, start, start + m))
@@ -381,7 +416,7 @@ def _window(diff, ints, start, *, m: int, top_k: int, mesh):
         pos = inverse - start
         live = (pos >= 0) & (pos < jnp.minimum(m, n_rows - start))
         pos = jnp.clip(pos, 0, m - 1)
-        xs = _dispatch_rows(rows, pairs, pos, live, top_k)
+        xs = _dispatch_rows(rows, pairs, pos, live, top_k, scatter)
         pad = -m % GMM_ROW_TILE
         if pad:
             xs = jnp.pad(xs, ((0, pad), (0, 0)))
@@ -396,14 +431,18 @@ def _window(diff, ints, start, *, m: int, top_k: int, mesh):
 
         xs = masked(xs)
     with jax.named_scope("gpt/moe_experts"):
-        gate = masked(grouped_matmul(xs, w1, group_sizes, mesh))
-        up = masked(grouped_matmul(xs, w3, group_sizes, mesh))
-        act = (jax.nn.silu(gate) * up).astype(dt)
-        ys = masked(grouped_matmul(act, w2, group_sizes, mesh))[:m]
+        act = masked(grouped_matmul(xs, w_up, group_sizes, mesh))
+        if w_gate:      # SwiGLU: w_up is the gate's w1, w_gate the w3
+            act = jax.nn.silu(act) * masked(
+                grouped_matmul(xs, w_gate[0], group_sizes, mesh))
+        else:
+            act = jnp.square(jax.nn.relu(act))
+        ys = masked(grouped_matmul(act.astype(dt), w_down, group_sizes,
+                                   mesh))[:m]
     with jax.named_scope("gpt/moe_combine"):
         y = _combine_rows(
             ys, jnp.where(live.reshape(t, top_k), w, 0.0).astype(dt),
-            pairs, pos)
+            pairs, pos, scatter)
         # live: from the selection's count; computed: from the groups
         # the matmuls were given.  They agree unless a window is cut
         came_back = jnp.sum(live & computed[pos, 0], dtype=jnp.int32)
@@ -463,18 +502,25 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
                  top_k: int, held: Sequence[int], num_experts: int,
                  norm_topk: bool = True, scale: float = 1.0,
                  compute_dtype=jnp.bfloat16,
-                 mesh: Optional[jax.sharding.Mesh] = None
+                 mesh: Optional[jax.sharding.Mesh] = None,
+                 router_x: Optional[jax.Array] = None,
+                 norm_eps: float = 1e-6
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Sparse SwiGLU block over the experts held here.
+    """Sparse block over the experts held here.
 
     Args:
-      x: ``[b, s, d]`` activations.
-      params: ``router`` ``[d, num_experts]``, ``expert_bias``
-        ``[num_experts]``, ``w1`` / ``w3`` ``[len(held), d, f]``, ``w2``
-        ``[len(held), f, d]`` (slot i is expert ``held[i]``).
+      x: ``[b, s, d]`` activations, of whatever width the experts read.
+      params: ``router`` ``[router width, num_experts]``, ``expert_bias``
+        ``[num_experts]``, ``w1`` ``[len(held), d, f]``, ``w2``
+        ``[len(held), f, d]`` (slot i is expert ``held[i]``), and
+        SwiGLU's ``w3`` like ``w1``: with it an expert is
+        ``(silu(x w1) * (x w3)) w2``, without it ``relu(x w1)^2 w2``.
+      router_x: the rows the router reads where they are not the
+        experts' (a latent expert layer routes on the full-width rows);
+        None = ``x``.
 
     The router keeps its full width and its ``top_k`` whatever is held;
-    ``out = sum_{e in sel, e held} w_e * SwiGLU_e(x)``.  Where only some
+    ``out = sum_{e in sel, e held} w_e * expert_e(x)``.  Where only some
     of the experts are held the weights carry no gradient: the router's
     gradient is a sum over every chip's share (the exchange brings it),
     and one share alone teaches the router to prefer the experts that
@@ -507,9 +553,10 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
     t, n_held, dt = b * s, len(held), compute_dtype
     rows = x.reshape(t, d)
     with jax.named_scope("gpt/moe_route"):
-        ids, w = sigmoid_routing(rows, params["router"],
-                                 params["expert_bias"], top_k=top_k,
-                                 norm_topk=norm_topk, scale=scale)
+        ids, w = sigmoid_routing(
+            rows if router_x is None else router_x.reshape(t, -1),
+            params["router"], params["expert_bias"], top_k=top_k,
+            norm_topk=norm_topk, scale=scale, eps=norm_eps)
         # slot of each chosen expert among the held ones; n_held = absent
         slot_of = np.full((num_experts,), n_held, np.int32)
         slot_of[list(held)] = np.arange(n_held)
@@ -533,7 +580,8 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
         order = jnp.pad(order, (0, n_windows * m - t * top_k))
     window = functools.partial(_window, m=m, top_k=top_k, mesh=mesh)
     diff = (rows.astype(dt), w) + tuple(
-        params[name].astype(dt) for name in ("w1", "w3", "w2"))
+        params[name].astype(dt) for name in ("w1", "w3", "w2")
+        if name in params)
     ints = (order, inverse, last - group_sizes, last, n_rows)
     if n_windows == 1:
         y, came_back = window(diff, ints, 0)
@@ -552,8 +600,12 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
 
 
 def init_dropless_params(rng, d_model: int, d_ff: int, num_experts: int,
-                         n_held: int) -> Dict[str, jax.Array]:
-    """One layer's router, selection bias and held experts.  The bias is
+                         n_held: int, *, gated: bool = True,
+                         router_dim: Optional[int] = None
+                         ) -> Dict[str, jax.Array]:
+    """One layer's router (reading rows of ``router_dim``; None =
+    ``d_model``), selection bias and held experts (SwiGLU, or without
+    ``w3`` when not ``gated``).  The bias is
     a buffer (no gradient; ``GPT.configure_optimizers`` gives it no
     optimizer state); it is drawn small so that it moves some selections
     (the published models start it at zero and steer it by a balancing
@@ -563,22 +615,87 @@ def init_dropless_params(rng, d_model: int, d_ff: int, num_experts: int,
     def dense(key, shape, fan_in):
         return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
 
-    return {
-        "router": dense(kr, (d_model, num_experts), d_model),
+    router_dim = router_dim or d_model
+    out = {
+        "router": dense(kr, (router_dim, num_experts), router_dim),
         "expert_bias": 0.01 * jax.random.normal(kb, (num_experts,),
                                                 jnp.float32),
         "w1": dense(k1, (n_held, d_model, d_ff), d_model),
         "w3": dense(k3, (n_held, d_model, d_ff), d_model),
         "w2": dense(k2, (n_held, d_ff, d_model), d_ff),
     }
+    if not gated:
+        del out["w3"]
+    return out
 
 
-def dropless_logical_axes() -> Dict[str, Any]:
+def dropless_logical_axes(gated: bool = True) -> Dict[str, Any]:
     """Logical axis names for an `init_dropless_params` tree (one layer)."""
-    return {
+    axes = {
         "router": (None, None),
         "expert_bias": (None,),
         "w1": ("expert", "embed", "mlp"),
         "w3": ("expert", "embed", "mlp"),
         "w2": ("expert", "mlp", "embed"),
     }
+    if not gated:
+        del axes["w3"]
+    return axes
+
+
+# --------------------------------------------------------------------- #
+# Latent expert layer: the dropless path inside a narrower space         #
+# --------------------------------------------------------------------- #
+def latent_moe(x: jax.Array, params: Dict[str, Any], *, top_k: int,
+               held: Sequence[int], num_experts: int, norm_topk: bool,
+               scale: float, compute_dtype=jnp.bfloat16, mesh=None
+               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``(r W_fc2 + relu(x U_s)^2 V_s, stats)`` with ``r = sum_{e in sel,
+    e held} w_e * relu(l U_e)^2 V_e`` and ``l = x W_fc1``: the routed
+    experts live in a latent of width ``W_fc1.shape[1]`` (down- and
+    up-projection under scope ``gpt/moe_latent``), the router and the
+    shared expert (``gpt/moe_shared``) read the full-width rows.  The
+    routed part is ``dropless_moe`` itself, ungated ReLU squared, on the
+    latent rows; ``stats`` are its counters.  Router, projections and
+    shared expert are the same on every chip that shares the layer: a
+    sum over the shares counts them once."""
+    dt = compute_dtype
+    with jax.named_scope("gpt/moe_latent"):
+        latent = jnp.einsum("bsd,dl->bsl", x, params["fc1"].astype(dt))
+    routed, stats = dropless_moe(
+        latent, params["experts"], top_k=top_k, held=held,
+        num_experts=num_experts, norm_topk=norm_topk, scale=scale,
+        compute_dtype=dt, mesh=mesh, router_x=x, norm_eps=1e-20)
+    with jax.named_scope("gpt/moe_latent"):
+        y = jnp.einsum("bsl,ld->bsd", routed, params["fc2"].astype(dt))
+    with jax.named_scope("gpt/moe_shared"):
+        up = jnp.einsum("bsd,df->bsf", x, params["shared_w1"].astype(dt))
+        y = y + jnp.einsum("bsf,fd->bsd", jnp.square(jax.nn.relu(up)),
+                           params["shared_w2"].astype(dt))
+    return y, stats
+
+
+def init_latent_moe_params(rng, d_model: int, latent: int, d_ff: int,
+                           shared_d_ff: int, num_experts: int, n_held: int
+                           ) -> Dict[str, Any]:
+    """One latent expert layer: ``experts`` is ``init_dropless_params``
+    at the latent width, ungated, its router reading ``d_model`` rows."""
+    k_e, k1, k2, k3, k4 = jax.random.split(rng, 5)
+
+    def dense(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+
+    return {"experts": init_dropless_params(
+                k_e, latent, d_ff, num_experts, n_held, gated=False,
+                router_dim=d_model),
+            "fc1": dense(k1, (d_model, latent), d_model),
+            "fc2": dense(k2, (latent, d_model), latent),
+            "shared_w1": dense(k3, (d_model, shared_d_ff), d_model),
+            "shared_w2": dense(k4, (shared_d_ff, d_model), shared_d_ff)}
+
+
+def latent_moe_logical_axes() -> Dict[str, Any]:
+    """Logical axis names for an ``init_latent_moe_params`` tree."""
+    return {"experts": dropless_logical_axes(gated=False),
+            "fc1": ("embed", None), "fc2": (None, "embed"),
+            "shared_w1": ("embed", "mlp"), "shared_w2": ("mlp", "embed")}

@@ -65,15 +65,20 @@ def _ln_kernel(x_ref, s_ref, b_ref, o_ref, *, eps: float):
 
 # a whole-array block must fit VMEM next to its f32 working copy
 _MAX_WHOLE_ROWS = 1024
+# elements of one block: 512 rows of 2048 (in and out double-buffered
+# beside the f32 working copy is what the v5e's 16 MiB of VMEM takes; the
+# described compile refuses 512 rows of 4096)
+_MAX_BLOCK_ELEMENTS = 512 * 2048
 
 
-def _row_block(rows: int) -> Optional[int]:
-    """Rows per grid cell.  The TPU lowering takes a block whose row
-    count is a multiple of 8 (the sublane tile) or the whole array --
-    a prompt of 57 tokens is one 57-row block, not 57 one-row blocks.
-    None when neither fits (a long array with an odd row count)."""
+def _row_block(rows: int, d: int = 0) -> Optional[int]:
+    """Rows per grid cell, at most ``_MAX_BLOCK_ELEMENTS`` of width
+    ``d``.  The TPU lowering takes a block whose row count is a multiple
+    of 8 (the sublane tile) or the whole array -- a prompt of 57 tokens
+    is one 57-row block, not 57 one-row blocks.  None when neither fits
+    (a long array with an odd row count)."""
     for cand in (512, 256, 128, 64, 32, 16, 8):
-        if rows % cand == 0:
+        if rows % cand == 0 and cand * d <= _MAX_BLOCK_ELEMENTS:
             return cand
     return rows if rows <= _MAX_WHOLE_ROWS else None
 
@@ -81,7 +86,7 @@ def _row_block(rows: int) -> Optional[int]:
 def _norm_call(kernel, x2: jax.Array, params, eps: float, interpret: bool,
                name: str):
     rows, d = x2.shape
-    br = _row_block(rows)
+    br = _row_block(rows, d)
     in_specs = [pl.BlockSpec((br, d), lambda i: (i, 0))]
     # scale/bias are [1, d] rows shared by every block
     in_specs += [pl.BlockSpec((1, d), lambda i: (0, 0)) for _ in params]
@@ -102,7 +107,7 @@ def _use_pallas(x: jax.Array) -> bool:
     if jax.default_backend() != "tpu":
         return False
     d = x.shape[-1]
-    return d % 128 == 0 and _row_block(x.size // d) is not None
+    return d % 128 == 0 and _row_block(x.size // d, d) is not None
 
 
 # --------------------------------------------------------------------- #

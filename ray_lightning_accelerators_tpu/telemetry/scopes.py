@@ -15,8 +15,10 @@ Named scopes are metadata only: nothing here runs on the device.
 
 The scope contract (docs/API.md "Named scopes", PERF.md §3):
 ``gpt/embed``, ``gpt/layers``, ``gpt/attn``, ``gpt/mlp``, ``gpt/norm``,
-``gpt/conv``, ``gpt/moe_route``, ``gpt/moe_dispatch``, ``gpt/moe_experts``,
-``gpt/moe_combine``, ``gpt/loss``, ``optimizer``, ``guard``, ``exchange``,
+``gpt/conv``, ``gpt/ssm``, ``gpt/ssm_scan``, ``gpt/moe_route``,
+``gpt/moe_dispatch``, ``gpt/moe_experts``, ``gpt/moe_combine``,
+``gpt/moe_latent``, ``gpt/moe_shared``, ``gpt/loss``, ``optimizer``,
+``guard``, ``exchange``,
 ``kernel/<name>`` (``flash_fwd``, ``flash_bwd``, ``rms_norm``,
 ``q8_matmul``, ``moe_gmm``).
 

@@ -52,6 +52,16 @@ def test_lfm2_cell_shapes_phase_rehearsal(capsys):
     assert '"op": "gated_short_conv"' in out
 
 
+def test_nemotron_cell_shapes_phase_rehearsal(capsys):
+    chip_smoke.phase_nemotron_cell_shapes(
+        0, batch=2, seq=64, d_model=64, ssm_heads=4, ssm_head_dim=8,
+        ssm_state=16, chunk=16, latent=32, expert_width=24, shared_width=48,
+        experts=16, held=4, top_k=4, iters=1, on_chip=False)
+    out = capsys.readouterr().out
+    assert '"op": "mamba2_mixer"' in out
+    assert '"op": "latent_moe"' in out and '"rows_in": 128' in out
+
+
 def test_four_chip_phase_rehearsal(out_dir, capsys):
     chip_smoke.phase_four_chip(TINY, 0)
     out = capsys.readouterr().out

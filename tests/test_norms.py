@@ -82,6 +82,10 @@ def test_row_block_is_a_sublane_multiple_or_the_whole_array():
     assert _row_block(24) == 8
     assert _row_block(57) == 57 and _row_block(4) == 4
     assert _row_block(1031) is None  # long and odd: the jnp reference
+    # a block is at most 512 rows of 2048: at d_model 4096 the kernel's
+    # VMEM holds 256 rows (the described compile refused 512, PR 31)
+    assert _row_block(8192, 1024) == _row_block(8192, 2048) == 512
+    assert _row_block(8192, 4096) == 256 and _row_block(8192, 8192) == 128
     for rows in range(1, 1100):
         br = _row_block(rows)
         assert br is None or (rows % br == 0

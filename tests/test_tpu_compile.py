@@ -169,6 +169,58 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
             + mem.temp_size_in_bytes) < HBM_BYTES // 2
 
 
+def test_nemotron_operators_compile_at_the_benchmark_cells_shapes(
+        one_chip, chip_dispatch):
+    """``train-nemotron3-ssm-8k``: [1, 8192, 4096] rows.  The latent
+    expert layer (8 of 512 experts held, top-22: 180,224 pairs in windows
+    of 4,608 sorted rows, ReLU^2 experts of 2688 in a latent of 1024, a
+    shared expert of 5376) holds the grouped-matmul kernel forward and
+    backward and meets its tokens by scatter-add: no ``[180224, 1024]``
+    gather of every pair's row is left (two such stood at 369 MB each,
+    738 MB at two sequences, before the window's own rows were added into
+    their tokens).  The Mamba-2 mixer (16 heads of 64 in one group, state
+    128, chunks of 128) compiles forward and backward.  Temporaries of a
+    layer's gradient, by the compiler's account: under 0.4 GB each
+    (0.124 and 0.163 read)."""
+    from ray_lightning_accelerators_tpu.ops import moe, ssm
+
+    held = tuple(range(8))
+    x = _sds((1, 8192, 4096), jnp.bfloat16, one_chip)
+
+    def shapes(init, *args):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip),
+            jax.eval_shape(lambda k: init(k, *args), jax.random.PRNGKey(0)))
+
+    assert moe.window_rows(8192 * 22, 8, 512) == 4608
+    assert moe._token_side_by_scatter(4608, 8192 * 22)
+
+    def expert_loss(p, x):
+        y, stats = moe.latent_moe(x, p, top_k=22, held=held,
+                                  num_experts=512, norm_topk=True,
+                                  scale=5.0)
+        return y.astype(jnp.float32).sum(), stats["rows_computed"]
+
+    lowered = jax.jit(jax.grad(expert_loss, argnums=(0, 1), has_aux=True)
+                      ).lower(shapes(moe.init_latent_moe_params, 4096, 1024,
+                                     2688, 5376, 512, 8), x)
+    assert _kernels(lowered) == ["kernel"]
+    assert "stablehlo.ragged_dot" not in lowered.as_text()
+    compiled = lowered.compile()
+    assert "[180224,1024]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+    def mixer_loss(p, x):
+        return ssm.mamba2_mixer(x, p, heads=16, head_dim=64, groups=1,
+                                state=128, chunk=128, eps=1e-5
+                                ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(mixer_loss, argnums=(0, 1))).lower(
+        shapes(ssm.init_mamba2_params, 4096, 16, 64, 1, 128, 4), x
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
 @pytest.mark.parametrize("rows", [4, 57, 1000])
 def test_rms_norm_compiles_at_row_counts_off_the_sublane_tile(
         one_chip, chip_dispatch, rows):
@@ -178,6 +230,17 @@ def test_rms_norm_compiles_at_row_counts_off_the_sublane_tile(
     x = _sds((1, rows, 768), jnp.bfloat16, one_chip)
     s = _sds((768,), jnp.float32, one_chip)
     lowered = jax.jit(rms_norm).lower(x, s)
+    assert _kernels(lowered) == ["rms_norm"]
+    lowered.compile()
+
+
+def test_rms_norm_compiles_at_the_widest_cells_width(one_chip, chip_dispatch):
+    """``train-nemotron3-ssm-8k``: 8,192 rows of 4096.  A 512-row block
+    ran the kernel out of VMEM there (in and out double-buffered beside
+    the float32 working copy); the block shrinks with the width."""
+    x = _sds((1, 8192, 4096), jnp.bfloat16, one_chip)
+    s = _sds((4096,), jnp.float32, one_chip)
+    lowered = jax.jit(functools.partial(rms_norm, eps=1e-5)).lower(x, s)
     assert _kernels(lowered) == ["rms_norm"]
     lowered.compile()
 
