@@ -32,9 +32,9 @@ from ..parallel import collectives as collectives_lib
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as sharding_lib
 from ..parallel.ring_attention import ring_attention_sharded
-from ..ops.attention import flash_attention
+from ..ops.attention import FLASH_RESIDUALS, flash_attention
 from ..ops.conv import gated_short_conv
-from ..ops.moe import (dropless_logical_axes, dropless_moe,
+from ..ops.moe import (MOE_PLAN, dropless_logical_axes, dropless_moe,
                        init_dropless_params, init_latent_moe_params,
                        init_moe_params, latent_moe, latent_moe_logical_axes,
                        moe_logical_axes, moe_mlp)
@@ -57,9 +57,13 @@ class TransformerConfig:
     causal: bool = True
     remat: bool = False           # jax.checkpoint each layer
     # what the rematerialized backward may keep: "nothing" recomputes the
-    # whole layer (min HBM), "dots" saves matmul outputs (recompute only
-    # elementwise — the usual sweet spot: matmuls are the expensive part
-    # to redo on the MXU, activations are the expensive part to hold in HBM)
+    # whole layer (min HBM) but the named residuals, "dots" saves matmul
+    # outputs too (recompute only elementwise — the usual sweet spot:
+    # matmuls are the expensive part to redo on the MXU, activations are
+    # the expensive part to hold in HBM).  The named residuals, kept by
+    # every policy (``_KEPT_UNDER_REMAT``): the flash kernel's output and
+    # log-sum-exp where the key length spans several blocks, and the
+    # routing plan of a dropless expert layer
     remat_policy: str = "nothing"
     pipeline_microbatches: int = 4  # GPipe schedule when mesh has pipeline>1
     rope_theta: float = 10000.0
@@ -473,19 +477,34 @@ def _int8_ste_bwd(mode, res, g):
 _int8_ste_matmul.defvjp(_int8_ste_fwd, _int8_ste_bwd)
 
 
+# What every remat policy keeps of a block, by ``checkpoint_name``: dear
+# to run again and almost free to hold.  The flash k-walk's output and
+# log-sum-exp (tagged where the key length spans several blocks, not at
+# one block a sequence) and the plan the dropless expert layer's windows
+# read (masked weights, both permutations, the groups' intervals).  A
+# block that ran neither carries no name and keeps what the stock
+# policy keeps.
+_KEPT_UNDER_REMAT = (*FLASH_RESIDUALS, MOE_PLAN)
+
+
 def _remat_policy(name: str):
-    """Map a config string to a jax.checkpoint policy."""
-    policies = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
+    """Map a config string to a jax.checkpoint policy: the named
+    residuals, and what the stock policy of that name keeps."""
+    stock = {
         "dots": jax.checkpoint_policies.dots_saveable,
         "dots_with_no_batch_dims":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         "everything": jax.checkpoint_policies.everything_saveable,
     }
-    if name not in policies:
+    named = jax.checkpoint_policies.save_only_these_names(
+        *_KEPT_UNDER_REMAT)
+    if name == "nothing":
+        return named
+    if name not in stock:
         raise ValueError(f"unknown remat_policy {name!r}; choose from "
-                         f"{sorted(policies)}")
-    return policies[name]
+                         f"{sorted(('nothing', *stock))}")
+    return jax.checkpoint_policies.save_from_both_policies(
+        named, stock[name])
 
 
 class GPT(TpuModule):

@@ -35,6 +35,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,6 +43,8 @@ from ..analysis import knobs
 from ..utils.scope import scoped
 
 _NEG_INF = -1e30
+# checkpoint names of the k-walk's output and log-sum-exp (``_fa_fwd``)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 # --------------------------------------------------------------------- #
@@ -670,6 +673,15 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window):
     v3 = v.reshape(b * h, v.shape[2], d)
     out3, lse = _flash_forward(q3, k3, v3, scale_v, causal, eff_q, eff_k,
                                interpret=False, window=window)
+    if k.shape[2] > eff_k:
+        # the k-walk: what a layer's remat would pay to run it again
+        # grows with the sequence (2 s d FLOPs a row of output), what it
+        # pays to hold the output does not: named, so a remat policy
+        # keeps both (models/transformer.py ``_KEPT_UNDER_REMAT``).  One
+        # k block holds the sequences where running again is the cheaper
+        # side (PERF.md, PR 32); outside jax.checkpoint a name is the
+        # identity
+        out3, lse = map(checkpoint_name, (out3, lse), FLASH_RESIDUALS)
     return out3.reshape(b, h, q_len, d), (q, k, v, out3, lse)
 
 

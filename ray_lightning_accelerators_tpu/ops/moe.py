@@ -42,6 +42,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..analysis import knobs
 from ..parallel import mesh as mesh_lib
@@ -288,6 +289,8 @@ GMM_ROW_TILE = 512
 # nominal share of them (PERF.md section 6, PR 28: the largest
 # rows-a-layer seen on the chip against it).
 WINDOW_SPARE = 1.5
+# checkpoint name of what ``dropless_moe``'s windows read of the routing
+MOE_PLAN = "moe_plan"
 
 
 def _tile(extent: int, limit: int = 1024) -> int:
@@ -579,10 +582,18 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
         # there, on rows no group owns
         order = jnp.pad(order, (0, n_windows * m - t * top_k))
     window = functools.partial(_window, m=m, top_k=top_k, mesh=mesh)
+    # the plan: all the windows read of the routing, forward and
+    # backward.  Named, so a layer's remat keeps it (a few integers a
+    # pair; models/transformer.py ``_KEPT_UNDER_REMAT``) and its
+    # backward runs neither the router nor the sorts again.  A name is
+    # the identity on the primal and on the tangent: where ``w`` carries
+    # a gradient it still does
+    w, *ints = (checkpoint_name(a, MOE_PLAN) for a in (
+        w, order, inverse, last - group_sizes, last, n_rows))
     diff = (rows.astype(dt), w) + tuple(
         params[name].astype(dt) for name in ("w1", "w3", "w2")
         if name in params)
-    ints = (order, inverse, last - group_sizes, last, n_rows)
+    ints = tuple(ints)
     if n_windows == 1:
         y, came_back = window(diff, ints, 0)
         rounds = jnp.ones((), jnp.int32)

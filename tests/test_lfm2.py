@@ -680,12 +680,14 @@ def test_train_step_carries_the_new_scopes(tmpdir, device_cache):
                   or f"(gpt/{scope})" in n}
         # the scan itself is not rematted; on a chip that holds a share
         # of the experts the combine weights carry no gradient, so the
-        # route has no backward and the backward needs no second combine
+        # route has no backward and the backward needs no second combine;
+        # remat keeps the windows' plan by name, so the route runs once
         want = {"layers": {"fwd", "bwd"},
-                "moe_route": {"fwd", "recompute"},
+                "moe_route": {"fwd"},
                 "moe_combine": {"fwd", "bwd"}}.get(
                     scope, {"fwd", "bwd", "recompute"})
         assert passes >= want, (scope, passes)
+        assert scope != "moe_route" or passes == want
 
 
 @pytest.mark.parametrize("gather_mode", ["tree", "scan"])

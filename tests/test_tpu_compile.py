@@ -269,12 +269,14 @@ def test_int8_matmul_nt_unembed_compiles(one_chip):
 # The whole jitted train step                                            #
 # --------------------------------------------------------------------- #
 def _abstract_train_step(devices, per_chip_batch, use_fsdp=False,
-                         **trainer_kw):
+                         config=None, **trainer_kw):
     """The Trainer's own jitted train step for ``devices``, with every
     operand a shape: what ``Trainer._fit_local`` sets up before
     ``_compile``, with ``jax.eval_shape`` in place of real arrays (a
-    described device cannot hold one)."""
-    module = GPT(chip_smoke._model_config(SIZE), lr=3e-4)
+    described device cannot hold one).  ``config``: the 124M GPT unless
+    given."""
+    config = config or chip_smoke._model_config(SIZE)
+    module = GPT(config, lr=3e-4)
     trainer = Trainer(
         max_epochs=1, precision="bf16", enable_checkpointing=False, seed=0,
         accelerator=RayTPUAccelerator(num_workers=len(devices),
@@ -297,8 +299,8 @@ def _abstract_train_step(devices, per_chip_batch, use_fsdp=False,
         return state.replace(guard_ema=jnp.asarray(guardian.fresh_state()))
 
     state = jax.eval_shape(make_state)
-    batch = jax.ShapeDtypeStruct((per_chip_batch * len(devices), SEQ),
-                                 jnp.int32)
+    batch = jax.ShapeDtypeStruct(
+        (per_chip_batch * len(devices), config.max_seq_len), jnp.int32)
     trainer._compile(module, state, batch)
     state = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
                          state, trainer._state_shardings)
@@ -345,6 +347,58 @@ def test_four_chip_fsdp_train_step_compiles(topo, chip_dispatch,
     # params and Adam moments really are 1/4 per chip
     wq = trainer._state_shardings.params["layers"]["attn"]["wq"]
     assert wq.shard_shape((12, 768, 12, 64)) == (12, 192, 12, 64)
+
+
+def _forward_kernel_calls(hlo: str) -> int:
+    return sum("tpu_custom_call" in line and "flash_fwd" in line
+               for line in hlo.splitlines() if "custom-call(" in line)
+
+
+def test_four_chip_fsdp_remat_step_keeps_its_kernels_and_bytes(
+        topo, chip_dispatch):
+    """``train-xl-fsdp4``'s guard: one k block a sequence, no expert
+    layer, so no value in the block carries a name and a remat policy
+    that keeps the named residuals keeps what ``nothing_saveable``
+    kept: the forward kernel twice (forward and remat) and the bytes
+    this step had before any name existed (PR 31's tree, described
+    compile)."""
+    config = chip_smoke._model_config(SIZE)
+    config.remat = True
+    _, lowered = _abstract_train_step(topo.devices, PER_CHIP_BATCH // 4,
+                                      use_fsdp=True, config=config)
+    compiled = lowered.compile()
+    assert _forward_kernel_calls(compiled.as_text()) == 2
+    assert _per_device_bytes(compiled) == 1_493_542_912
+
+
+@pytest.mark.parametrize("block,forward_kernels", [(1024, 1), (2048, 2)],
+                         ids=["k-walk", "one-k-block"])
+def test_remat_step_runs_the_k_walk_and_the_routing_once(
+        topo, chip_dispatch, block, forward_kernels):
+    """A ``remat=True`` step with an attention layer and two sparse
+    runs (LFM2-shaped, three layers).  At two k blocks a sequence the
+    kernel's output and log-sum-exp are kept by name: ONE forward kernel
+    in the program.  At one block nothing is named and it runs twice.
+    Either way the plan of the expert layer's windows is kept: the
+    router's ``top_k`` and the two ``argsort``s stand once a sparse run
+    (six ``sort``s), none under the backward's or the remat's op names
+    (PR 31's tree: twelve, six of them there)."""
+    config = TransformerConfig(
+        vocab_size=2048, d_model=256, n_heads=4, n_kv_heads=2, d_ff=512,
+        n_layers=3, max_seq_len=2048, conv_kernel=3, moe_router="sigmoid",
+        layer_types=["conv", "full_attention", "conv"], num_dense_layers=1,
+        num_experts=8, moe_top_k=2, moe_d_ff=128, moe_experts_held=[0, 1],
+        gated_mlp=True, qk_norm=True, rope_style="half", norm_eps=1e-5,
+        remat=True, flash_block_q=block, flash_block_k=block,
+        loss_chunk_rows=2048)
+    _, lowered = _abstract_train_step(topo.devices[:1], 2, config=config)
+    hlo = lowered.compile().as_text()
+    assert _forward_kernel_calls(hlo) == forward_kernels
+    sorts = [line for line in hlo.splitlines()
+             if " sort(" in line.split("metadata")[0]]
+    assert len(sorts) == 6
+    assert [line for line in sorts
+            if "transpose(" in line or "rematted_computation" in line] == []
 
 
 # --------------------------------------------------------------------- #
