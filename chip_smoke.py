@@ -29,7 +29,9 @@ prints no result:
               Then the ``train-nemotron3-ssm-8k`` cell's two (the Mamba-2
               mixer, 16 heads of 64 with state 128 in chunks of 128, and
               the latent expert layer, 8 of 512 experts held, top-22, at
-              [1, 8192, 4096]) the same way.
+              [1, 8192, 4096]) the same way.  Then the
+              ``train-ouro-loop-8k`` cell's looped stack (6 sandwich-norm
+              layers run 4 times on the same weights at [1, 8192, 2048]).
 3. *train*    ``Trainer.fit(GPT, DataLoader)``: finite, falling loss,
               zero compiles in the second epoch, the flash forward and
               backward kernels in the train step's lowering, peak HBM.
@@ -76,6 +78,10 @@ TOL_INT8 = 1e-2
 # the LFM2 cell's operators, bf16 against float32, forward and VJP: three
 # chained matmuls and a gate round like the flash gradients do
 TOL_LFM2 = 4e-2
+# the looped stack of the Ouro cell, bf16 against float32: 24 layer
+# applications compound what one rounds (on the chip at the cell's
+# shapes: forward 0.019, the weights' gradients 0.023-0.040; PR 33)
+TOL_OURO = 1e-1
 # two correct bf16 programs for the same greedy decode (cached vs
 # re-forward, paged vs dense) can disagree where the top two logits
 # nearly tie; a chosen token may trail the re-forward argmax by at most
@@ -346,7 +352,7 @@ def phase_flash_cell_shape(seed: int, iters: int = 100) -> None:
 
 
 def _operator_check(phase, name, system, reference, params, *, x, g, iters,
-                    on_chip, **fields):
+                    on_chip, tol=TOL_LFM2, **fields):
     """``system`` / ``reference``: (params, x) -> y.  Compared with their
     VJPs at cotangent ``g`` on the first sequence, then the system timed
     on the batch; one record under ``phase``."""
@@ -379,7 +385,7 @@ def _operator_check(phase, name, system, reference, params, *, x, g, iters,
          fwd_ms=timed(fwd, params, x),
          fwd_bwd_ms=timed(both, params, x, g), **fields)
     worst = max(errs.values())
-    require(worst <= TOL_LFM2, f"{name}: error {worst} ({errs})")
+    require(worst <= tol, f"{name}: error {worst} ({errs})")
 
 
 def phase_lfm2_cell_shapes(seed: int, *, batch: int = 4, seq: int = 8192,
@@ -514,6 +520,56 @@ def phase_nemotron_cell_shapes(seed: int, *, batch: int = 1, seq: int = 8192,
           lambda p_, x_: ref.latent_block(x_, p_, model, ids)[0], p,
           rows_in=batch * seq, rows_routed=float(stats["rows_routed"]),
           load_max_over_mean=float(stats["load_max_over_mean"]))
+
+
+def phase_ouro_cell_shapes(seed: int, *, batch: int = 1, seq: int = 8192,
+                           d_model: int = 2048, heads: int = 16,
+                           head_dim: int = 128, d_ff: int = 5632,
+                           layers: int = 6, passes: int = 4,
+                           block: int = 1024, iters: int = 3,
+                           on_chip: bool = True) -> None:
+    """The ``train-ouro-loop-8k`` cell's layer stack at its shapes (6
+    sandwich-norm layers of 16 heads of 128 and a SwiGLU of 5632, run 4
+    times on the same weights with the final norm after every pass, at
+    [1, 8192, 2048], remat on, flash blocks of 1024) in bfloat16 against
+    the plain float32 reference, forward and VJP with respect to every
+    shared weight; the mean of the four passes' states is compared, so
+    that each pass answers.  Milliseconds per call printed."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_accelerators_tpu.models import reference_ouro as ref
+    from ray_lightning_accelerators_tpu.models.transformer import (
+        GPT, TransformerConfig)
+
+    model = GPT(TransformerConfig(
+        vocab_size=256, d_model=d_model, n_heads=heads,
+        attn_head_dim=head_dim, d_ff=d_ff, n_layers=layers,
+        max_seq_len=seq, tie_embeddings=False, rope_theta=1e6,
+        gated_mlp=True, rope_style="half", post_norms=True,
+        loop_passes=passes, exit_gate=passes > 1,
+        exit_beta=0.05 if passes > 1 else 0.0, remat=True,
+        flash_block_q=block, flash_block_k=block))
+    model.compute_dtype = jnp.bfloat16
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 4), 3))
+    params = jax.jit(model.init_params)(next(keys))
+    params = {"layers": params["layers"], "ln_f": params["ln_f"]}
+    # an embedding's rows are small: deviation 0.02
+    x = 0.02 * jax.random.normal(next(keys), (batch, seq, d_model),
+                                 jnp.float32)
+    g = jax.random.normal(next(keys), x.shape, jnp.float32)
+    about = {"rope_theta": 1e6, "norm_eps": 1e-6, "loop_passes": passes}
+
+    def system(p_, x_):
+        _, aux = model._run_stacks(p_, x_.astype(jnp.bfloat16))
+        return jnp.mean(aux["loop_hidden"].astype(jnp.float32), 0)
+
+    def reference(p_, x_):
+        return sum(ref.run_passes(x_, p_, about, remat=True)) / passes
+
+    _operator_check("ouro_cell_shapes", "looped_stack", system, reference,
+                    params, x=x, g=g, iters=iters, on_chip=on_chip,
+                    tol=TOL_OURO, layer_applications=passes * layers)
 
 
 # --------------------------------------------------------------------- #
@@ -920,6 +976,7 @@ def main(argv=None) -> None:
         phase_flash_cell_shape(args.seed)
         phase_lfm2_cell_shapes(args.seed)
         phase_nemotron_cell_shapes(args.seed)
+        phase_ouro_cell_shapes(args.seed)
         phase_train(size, args.seed)
         model, params = phase_generate(size, args.seed)
         phase_serve(size, args.seed, model, params)

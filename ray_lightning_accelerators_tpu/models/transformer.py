@@ -117,9 +117,11 @@ class TransformerConfig:
     # it acts; ``layer_runs()`` accepts the combinations that have a
     # reference behind them (GELU, interleaved rotary and no QK-norm
     # with one run; SwiGLU, QK-norm and rotate-half rotary with
-    # ``layer_types`` / the sigmoid router) and refuses the rest by
-    # name.  Decode, serving, quantize_weights and the pipeline walk
-    # the one run in ``params["layers"]`` and refuse any other by name.
+    # ``layer_types`` / the sigmoid router; SwiGLU, rotate-half rotary
+    # and no QK-norm with ``post_norms``, the looped stack below) and
+    # refuses the rest by name.  Decode, serving, quantize_weights and
+    # the pipeline walk the one run in ``params["layers"]`` once and
+    # refuse any other stack by name.
     #
     # per-layer sequence operator, "conv" (gated short convolution,
     # ops/conv.py) or "full_attention"; None = attention everywhere
@@ -181,6 +183,22 @@ class TransformerConfig:
     ssm_groups_held: Optional[Tuple[int, ...]] = None
     moe_latent_dim: Optional[int] = None
     moe_shared_d_ff: Optional[int] = None
+    # ---- a looped stack (ouro) ---------------------------------------- #
+    # ``post_norms``: the sandwich norm, an RMSNorm AFTER each part too
+    # (``h + N2(Attn(N1(h)))``, then ``+ N4(SwiGLU(N3(.)))``; four
+    # scales a layer), in one run of attention blocks with SwiGLU,
+    # rotate-half rotary and no QK-norm.  ``loop_passes``: the whole
+    # stack runs that many times on THE SAME weights, every pass ending
+    # in the final norm, whose output feeds the next pass and the head
+    # (1 = every layer once).  ``exit_gate``: one ``Linear(d_model, 1)``
+    # with bias on each pass's normed state; the training loss is the
+    # passes' cross-entropies weighted by the gates' exit distribution,
+    # less ``exit_beta`` times that distribution's entropy
+    # (``GPT._loop_loss``).  ``forward`` gives the last pass's logits.
+    post_norms: bool = False
+    loop_passes: int = 1
+    exit_gate: bool = False
+    exit_beta: float = 0.0
 
     def __post_init__(self):
         # lists from JSON (a benchmark config, a checkpoint's hparams)
@@ -191,9 +209,14 @@ class TransformerConfig:
                 setattr(self, name, tuple(value))
         self.layer_runs()       # a config no block runs fails here
 
-    # the combinations with a reference behind them: these fields keep
-    # their defaults unless ``layer_types`` or the sigmoid router is set,
-    # and with either the block's three switches take these values
+    # the combinations with a reference behind them (``benchmark/lib``
+    # ``reference.py``: GELU, interleaved rotary, no QK-norm;
+    # ``models/reference_lfm2.py``: ``_MIXED_BLOCK``; ``models/
+    # reference_nemotron_h.py``: ``_HYBRID_BLOCK``; ``models/
+    # reference_ouro.py``: ``_LOOP_BLOCK``).  These fields keep their
+    # defaults unless ``layer_types``, the sigmoid router or
+    # ``post_norms`` is set, and with either of the first two the
+    # block's three switches take ``_MIXED_BLOCK``'s values
     _MIXED_ONLY = (("num_dense_layers", 0), ("moe_d_ff", None),
                    ("moe_experts_held", None), ("gated_mlp", False),
                    ("qk_norm", False), ("rope_style", "interleaved"),
@@ -208,6 +231,18 @@ class TransformerConfig:
     _HYBRID_BLOCK = (("gated_mlp", False), ("qk_norm", False),
                      ("rope_style", "none"), ("layer_types", None),
                      ("num_dense_layers", 0), ("moe_router", "sigmoid"))
+    # these belong to the looped stack (``post_norms``) and keep their
+    # defaults without it; with it the block is ``_LOOP_BLOCK``'s: one
+    # run of full causal multi-head attention + dense SwiGLU blocks.
+    # ``attn_head_dim``, ``tie_embeddings`` and ``norm_eps`` are values
+    _LOOP_ONLY = (("loop_passes", 1), ("exit_gate", False),
+                  ("exit_beta", 0.0))
+    _LOOP_BLOCK = (("gated_mlp", True), ("qk_norm", False),
+                   ("rope_style", "half"), ("layer_types", None),
+                   ("hybrid_pattern", None), ("moe_router", "softmax"),
+                   ("num_experts", 1), ("n_kv_heads", None),
+                   ("sliding_window", None), ("causal", True),
+                   ("attn_heads_held", None))
 
     @property
     def _mixed(self) -> bool:
@@ -219,9 +254,23 @@ class TransformerConfig:
         operator: "conv" | "attn" | "mamba" (``ops/ssm.mamba2_mixer``) |
         "none"; feed_forward: "dense" | "capacity" (``ops/moe.moe_mlp``)
         | "sparse" (``ops/moe.dropless_moe``) | "latent"
-        (``ops/moe.latent_moe``) | "none"."""
+        (``ops/moe.latent_moe``) | "none".  Also the config's input
+        check: a stack may set, beside the sizes, exactly one of four
+        blocks -- nothing (GELU, interleaved rotary, no QK-norm; one
+        run, dense or capacity MoE), ``layer_types`` / ``moe_router=
+        "sigmoid"`` (SwiGLU, QK-norm, rotate-half), ``hybrid_pattern``
+        (``_hybrid_runs``) or ``post_norms`` (``_loop_runs``: SwiGLU,
+        rotate-half, no QK-norm, sandwich norms, ``loop_passes`` runs of
+        the one run) -- and anything else is refused by name."""
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.post_norms:
+            return self._loop_runs()
+        for name, default in self._LOOP_ONLY:
+            if getattr(self, name) != default:
+                raise ValueError(
+                    f"TransformerConfig.{name} belongs to the looped stack:"
+                    " set post_norms with it")
         if self.hybrid_pattern is not None:
             return self._hybrid_runs()
         for name, default in self._HYBRID_ONLY:
@@ -264,6 +313,40 @@ class TransformerConfig:
             else:
                 runs.append([*key, 1])
         return tuple(tuple(r) for r in runs)
+
+    def _loop_runs(self) -> Tuple[Tuple[str, str, int], ...]:
+        """``layer_runs()`` of the looped stack (``post_norms``): one
+        run of attention + dense SwiGLU blocks, which ``loop_passes``
+        says how often to run.  What has no reference behind it is
+        refused by name."""
+        for name, needed in self._LOOP_BLOCK:
+            if getattr(self, name) != needed:
+                raise NotImplementedError(
+                    f"a looped stack (post_norms) runs {name}={needed!r} "
+                    "only (set gated_mlp=True, rope_style='half'; "
+                    "attn_head_dim, tie_embeddings, norm_eps, rope_theta "
+                    "and loop_passes are values)")
+        if self.loop_passes < 1:
+            raise ValueError(
+                f"loop_passes must be at least 1; got {self.loop_passes}")
+        if self.exit_gate != (self.loop_passes > 1):
+            raise NotImplementedError(
+                "the exit gate weighs the passes of a looped stack: set "
+                "exit_gate=True with loop_passes > 1 and with nothing "
+                f"else; got exit_gate={self.exit_gate}, loop_passes="
+                f"{self.loop_passes}")
+        if self.exit_beta and not self.exit_gate:
+            raise ValueError("exit_beta belongs to exit_gate")
+        if self.dropout:
+            raise NotImplementedError(
+                "dropout in a looped stack (post_norms) is not wired (a "
+                "pass would need its own masks); set dropout=0")
+        if self.label_smoothing or self.z_loss:
+            raise NotImplementedError(
+                "a looped stack (post_norms) has a reference for the "
+                "plain cross-entropy only: set label_smoothing=0, "
+                "z_loss=0")
+        return (("attn", "dense", self.n_layers),)
 
     def _hybrid_runs(self) -> Tuple[Tuple[str, str, int], ...]:
         """``layer_runs()`` of ``hybrid_pattern``: the letters paired
@@ -552,6 +635,13 @@ class GPT(TpuModule):
             first += n
         if not cfg.tie_embeddings:
             params["unembed"] = _dense(k_out, (d, cfg.vocab_size), d)
+        if cfg.exit_gate:
+            # Linear(d_model, 1) with bias, shared by the passes; its key
+            # is folded from the head's, so that the three-way split
+            # above (a format) stays as it is
+            params["exit_gate"] = {
+                "w": _dense(jax.random.fold_in(k_out, 1), (d, 1), d),
+                "b": jnp.zeros((1,), jnp.float32)}
         return params
 
     def _init_layer(self, key, *, op: str, ff: str) -> Dict[str, Any]:
@@ -566,10 +656,12 @@ class GPT(TpuModule):
         d, h, kv, hd, f = (cfg.d_model, len(cfg.heads_held),
                            len(cfg.kv_heads_held), cfg.head_dim, cfg.d_ff)
         ks = jax.random.split(key, 8 if cfg.gated_mlp else 6)
-        # an absent part has no norm either
-        out = {name: jnp.ones((d,), jnp.float32)
+        # an absent part has no norm either; the sandwich norm has one
+        # after each part too
+        out = {name + post: jnp.ones((d,), jnp.float32)
                for name, kind in (("ln1", op), ("ln2", ff))
-               if kind != "none"}
+               if kind != "none"
+               for post in (("", "_post") if cfg.post_norms else ("",))}
         if op == "mamba":
             out["ssm"] = init_mamba2_params(
                 ks[0], d, cfg.ssm_held[0], cfg.ssm_head_dim,
@@ -614,8 +706,9 @@ class GPT(TpuModule):
         """The logical axes of ``_init_layer``'s leaves, stacked."""
         cfg = self.cfg
         axes: Dict[str, Any] = {
-            name: ("layers", None)
-            for name, kind in (("ln1", op), ("ln2", ff)) if kind != "none"}
+            name + post: ("layers", None)
+            for name, kind in (("ln1", op), ("ln2", ff)) if kind != "none"
+            for post in (("", "_post") if cfg.post_norms else ("",))}
         if op == "mamba":
             axes["ssm"] = {name: ("layers",) + ax
                            for name, ax in mamba2_logical_axes().items()}
@@ -658,6 +751,8 @@ class GPT(TpuModule):
             axes[key] = self._layer_logical_axes(op, ff)
         if not cfg.tie_embeddings:
             axes["unembed"] = ("embed", "vocab")
+        if cfg.exit_gate:
+            axes["exit_gate"] = {"w": ("embed", None), "b": (None,)}
         return axes
 
     def scanned_param_subtrees(self) -> Tuple[str, ...]:
@@ -669,17 +764,26 @@ class GPT(TpuModule):
 
     def _uniform_stack_only(self, what: str) -> str:
         """Every walker of ``params["layers"]`` as ONE run of attention
-        blocks with a KV cache calls this first and gets the run's
-        feed-forward kind: any other stack has no such subtree (a conv
-        layer's serving state is not a KV cache, and the decode blocks
-        know neither QK-norm nor rotate-half rotary)."""
+        blocks with a KV cache, run ONCE, calls this first and gets the
+        run's feed-forward kind: any other stack has no such subtree (a
+        conv layer's serving state is not a KV cache, and the decode
+        blocks know neither QK-norm nor rotate-half rotary), and a
+        looped stack is one run whose cache would hold ``loop_passes`` x
+        ``n_layers`` entries behind a block with four norms."""
+        if self.cfg.post_norms:
+            raise NotImplementedError(
+                f"{what} walks params['layers'] once, as one uniform "
+                "stack of pre-norm attention blocks (GELU, interleaved "
+                "rotary); a looped stack (TransformerConfig.post_norms / "
+                "loop_passes / exit_gate: sandwich norms, the stack run "
+                "several times on shared weights) trains only")
         if self.cfg.run_keys() != ("layers",):
             raise NotImplementedError(
                 f"{what} walks params['layers'] as one uniform stack of "
-                "attention blocks; a mixed layer stack (TransformerConfig"
-                ".layer_types / moe_router='sigmoid' / hybrid_pattern: "
-                "conv, Mamba-2 and expert layers, layers of one part) "
-                "trains only")
+                "pre-norm attention blocks (GELU, interleaved rotary); a "
+                "mixed layer stack (TransformerConfig.layer_types / "
+                "moe_router='sigmoid' / hybrid_pattern: conv, Mamba-2 and "
+                "expert layers, layers of one part) trains only")
         return self.cfg.layer_runs()[0][1]
 
     # ------------------------------------------------------------------ #
@@ -869,7 +973,9 @@ class GPT(TpuModule):
 
     def _block(self, h, lp, positions, op: str, ff: str, dropout_rng=None):
         """One layer: ``h + op(norm(h))``, then ``+ ff(norm(.))``; a
-        part of kind "none" is absent, with its norm and residual.
+        part of kind "none" is absent, with its norm and residual.  With
+        ``cfg.post_norms`` each part's output is normed too before it is
+        added (``h + norm(op(norm(h)))``).
         Returns ``(h, stats, kv)``: the feed-forward's counters (``{}``
         for a dense or absent one) and the attention operator's ``(k,
         v)`` (None for any other).  The residual adds and dropout belong
@@ -896,11 +1002,15 @@ class GPT(TpuModule):
                         eps=cfg.norm_eps, compute_dtype=dt)
             else:
                 y, kv = self._self_attention(x, lp["attn"], positions)
+            if cfg.post_norms:
+                y = self._rms_norm(y, lp["ln1_post"])
             h = h + self._dropout(y, r_op)
         if ff != "none":
             x = self._rms_norm(h, lp["ln2"])
             y, stats = self._feed_forward(x, lp["mlp"], ff,
                                           self._mlp_train_matmul)
+            if cfg.post_norms:
+                y = self._rms_norm(y, lp["ln2_post"])
             h = h + self._dropout(y, dropout_rng)
         h = self._constrain(h, mesh_lib.BATCH_AXES, mesh_lib.SEQUENCE_AXIS,
                             None)
@@ -913,7 +1023,14 @@ class GPT(TpuModule):
         empty without one: the capacity path's ``moe_aux_loss`` summed
         over layers, the dropless path's counters summed over layers
         (``moe_rounds`` and ``moe_load_max_over_mean`` averaged) and its
-        chosen expert ids."""
+        chosen expert ids.
+
+        A looped stack (``cfg.post_norms``) runs its one run
+        ``cfg.loop_passes`` times on the same weights, each pass ending
+        in the final norm: a ``lax.scan`` over the passes around the
+        run's scan, so the program holds one layer body whatever the
+        count.  It returns the LAST pass's normed state and ``aux =
+        {"loop_hidden": [passes, b, s, d]}``, every pass's."""
         cfg = self.cfg
         keys = cfg.run_keys()
         if dropout_rng is not None and keys != ("layers",):
@@ -922,6 +1039,15 @@ class GPT(TpuModule):
                 ".layer_types ...) is not supported; set dropout=0")
         piped = self.mesh is not None and mesh_lib.mesh_axis_size(
             self.mesh, mesh_lib.PIPELINE_AXIS) > 1
+        if cfg.post_norms and self.mesh is not None:
+            for axis in (mesh_lib.SEQUENCE_AXIS, mesh_lib.TENSOR_AXIS):
+                if mesh_lib.mesh_axis_size(self.mesh, axis) > 1:
+                    raise NotImplementedError(
+                        f"a looped stack (TransformerConfig.post_norms) "
+                        f"over a sharded {axis!r} mesh axis is not "
+                        "supported: its exit-weighted loss streams whole "
+                        "rows against the whole head; use data / fsdp "
+                        "axes")
         if piped:
             self._uniform_stack_only("pipeline parallelism")
             if cfg.num_experts > 1:
@@ -974,6 +1100,20 @@ class GPT(TpuModule):
                     lambda lp, hm: run((hm, None), lp)[0][0], params[key],
                     carry[0], self.mesh, cfg.pipeline_microbatches), None)
                 continue
+            if cfg.post_norms:
+                # the pass loop: a loop in the program.  The weights are
+                # closed over, so the backward adds each one's gradient
+                # over the passes, and what remat keeps of a layer (its
+                # input, the named residuals) is kept per application
+                def one_pass(h_c, _):
+                    (h_c, _), _ = run((h_c, None), params[key])
+                    h_c = self._rms_norm(h_c, params["ln_f"])
+                    return h_c, h_c
+
+                with jax.named_scope("gpt/loop"):
+                    h, hs = jax.lax.scan(one_pass, carry[0], None,
+                                         length=cfg.loop_passes)
+                return h, {"loop_hidden": hs}
             carry, stats = run(carry, params[key])
             if stats:
                 per_layer.append(stats)
@@ -1013,8 +1153,10 @@ class GPT(TpuModule):
         return self._run_stacks(params, h, dropout_rng)
 
     def _head(self, params, h):
-        """Final norm and LM head: f32 logits."""
-        h = self._rms_norm(h, params["ln_f"])
+        """Final norm and LM head: f32 logits.  A looped stack's state
+        comes normed out of its last pass."""
+        if not self.cfg.post_norms:
+            h = self._rms_norm(h, params["ln_f"])
         logits = jnp.einsum("bsd,dv->bsv", h,
                             self._unembed_w(params, self.compute_dtype))
         return logits.astype(jnp.float32)
@@ -1025,6 +1167,12 @@ class GPT(TpuModule):
         mode); None (eval/decode) makes the forward deterministic."""
         h, aux = self._trunk(params, self._tokens_of(batch), dropout_rng)
         logits = self._head(params, h)
+        if return_aux and "loop_hidden" in aux:
+            # the last pass's logits are the model's; for a comparison,
+            # the exit distribution at every position, [passes, b, s]
+            with jax.named_scope("gpt/loop_exit"):
+                aux = {"loop_exit_p": self._exit_distribution(
+                    params, aux["loop_hidden"])[0]}
         return (logits, aux) if return_aux else logits
 
     def _use_fused_loss(self) -> bool:
@@ -1048,6 +1196,8 @@ class GPT(TpuModule):
         feeds: scope ``gpt/loss`` here, none in ``forward``."""
         tokens = self._tokens_of(batch)
         h, aux = self._trunk(params, tokens, rng)
+        if "loop_hidden" in aux:
+            return self._loop_loss(params, aux["loop_hidden"], tokens)
         if self._use_fused_loss():
             from ..ops.losses import fused_linear_cross_entropy
             with jax.named_scope("gpt/loss"):
@@ -1077,6 +1227,82 @@ class GPT(TpuModule):
             acc = jnp.mean(jnp.argmax(logits, -1) == targets)
         return loss, acc, aux
 
+    def _exit_distribution(self, params, hs):
+        """``(p, log p)``, each ``[passes, b, s]`` float32, of the normed
+        states ``hs`` [passes, b, s, d]: the gate ``g_t = sigmoid(h_t .
+        w + b)`` of every pass but the last, ``p_t = g_t prod_{j<t} (1 -
+        g_j)`` and the survival ``p_T = prod_{j<T} (1 - g_j)``; made in
+        logarithms, so that the entropy's ``p log p`` needs no guard.
+        One pass: it takes everything."""
+        if hs.shape[0] == 1:
+            zero = jnp.zeros(hs.shape[:3], jnp.float32)
+            return zero + 1.0, zero
+        gate = params["exit_gate"]
+        z = jnp.einsum("tbsd,do->tbso", hs[:-1],
+                       self._wt(gate["w"], self.compute_dtype),
+                       preferred_element_type=jnp.float32)[..., 0] \
+            + gate["b"].astype(jnp.float32)[0]
+        stayed = jnp.cumsum(jax.nn.log_sigmoid(-z), 0)  # sum_{j<=t}
+        log_p = jnp.concatenate(
+            [jax.nn.log_sigmoid(z) + jnp.pad(stayed[:-1],
+                                             ((1, 0), (0, 0), (0, 0))),
+             stayed[-1:]], 0)
+        return jnp.exp(log_p), log_p
+
+    def _loop_loss(self, params, hs, tokens):
+        """A looped stack's training loss from its passes' normed states
+        ``hs`` [passes, b, s, d]: per position with a target, ``sum_t
+        p_t l_t - exit_beta H(p)`` with ``l_t`` pass t's cross-entropy
+        and ``p`` the exit distribution, mean over positions.  The head
+        runs over every pass's rows in ONE fused call (each row weighted
+        by its ``p_t``, whose gradient is the row's own loss), booked to
+        ``gpt/loss``; the gates, the entropy and the counters to
+        ``gpt/loop_exit``.  Returns ``(loss, the last pass's accuracy,
+        the logged scalars)``."""
+        cfg = self.cfg
+        passes, b, _, d = hs.shape
+        hs, targets = hs[:, :, :-1], tokens[:, 1:].astype(jnp.int32)
+        n = targets.size
+        with jax.named_scope("gpt/loop_exit"):
+            p, log_p = self._exit_distribution(params, hs)
+            entropy = -jnp.sum(p * log_p, 0)                    # [b, s-1]
+        w = self._unembed_w(params, self.compute_dtype)
+        if self._use_fused_loss():
+            from ..ops.losses import fused_linear_cross_entropy
+            with jax.named_scope("gpt/loss"):
+                # batch-major rows, so that a batch sharded over data /
+                # fsdp axes keeps its rows
+                weighted, row_loss, hit = fused_linear_cross_entropy(
+                    jnp.moveaxis(hs, 0, 1).reshape(-1, d), w,
+                    jnp.broadcast_to(targets[:, None], (b, passes)
+                                     + targets.shape[1:]).reshape(-1),
+                    cfg.loss_chunk_rows, mesh=self.mesh,
+                    row_weights=jnp.moveaxis(p, 0, 1).reshape(-1))
+            row_loss, hit = (jnp.moveaxis(x.reshape(b, passes, -1), 1, 0)
+                             for x in (row_loss, hit))
+        else:
+            with jax.named_scope("gpt/loss"):
+                logits = jnp.einsum("tbsd,dv->tbsv", hs, w
+                                    ).astype(jnp.float32)
+                row_loss = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+                    logits, jnp.broadcast_to(targets, logits.shape[:3])[
+                        ..., None], -1)[..., 0]
+                hit = jnp.argmax(logits, -1) == targets
+            with jax.named_scope("gpt/loop_exit"):
+                weighted = jnp.sum(p * row_loss)
+        with jax.named_scope("gpt/loop_exit"):
+            loss = (weighted - cfg.exit_beta * jnp.sum(entropy)) / n
+            row_loss, p = (jax.lax.stop_gradient(x) for x in (row_loss, p))
+            aux = {f"loop_loss_pass_{t + 1}": jnp.mean(row_loss[t])
+                   for t in range(passes)}
+            aux["loop_exit_mean_pass"] = jnp.sum(
+                p * jnp.arange(1, passes + 1, dtype=jnp.float32)[
+                    :, None, None]) / n
+            # as a share of its most, ln(passes); one pass has none
+            aux["loop_exit_entropy"] = jax.lax.stop_gradient(
+                jnp.sum(entropy)) / (n * float(np.log(max(passes, 2))))
+        return loss, jnp.mean(hit[-1]), aux
+
     def training_step(self, params, batch, rng):
         loss, acc, aux = self._lm_loss(params, batch, rng=rng)
         # the expert layers' scalars ride the logged metrics, through the
@@ -1098,13 +1324,20 @@ class GPT(TpuModule):
 
     def configure_optimizers(self):
         runs = self.cfg.layer_runs()
+        # leaves that are no matrices and take no weight decay, by the
+        # families' conventions: the state-space layers' decay rates,
+        # step biases and skips; a looped stack's norm scales and its
+        # gate's bias
+        spared = ()
         if any(op == "mamba" for op, _, _ in runs):
-            # the state-space layers' decay rates, step biases and skips
-            # are no matrices: no weight decay (the family's convention)
+            spared = ("a_log", "dt_bias", "d_skip")
+        elif self.cfg.post_norms:
+            spared = ("ln1", "ln1_post", "ln2", "ln2_post", "ln_f", "b")
+        if spared:
             def decayed(params):
                 return jax.tree_util.tree_map_with_path(
-                    lambda path, _: getattr(path[-1], "key", None) not in (
-                        "a_log", "dt_bias", "d_skip"), params)
+                    lambda path, _: getattr(path[-1], "key", None)
+                    not in spared, params)
 
             tx = optax.adamw(self.lr, weight_decay=0.01, mask=decayed)
         else:
@@ -1160,6 +1393,11 @@ class GPT(TpuModule):
                          -127, 127).astype(jnp.int8)
             return {"q8": q, "scale": scale.astype(jnp.float32)}
 
+        if "ln1_post" in params.get("layers", ()):
+            raise NotImplementedError(
+                "GPT.quantize_weights walks params['layers'] as one "
+                "uniform stack run once; a looped stack (TransformerConfig"
+                ".post_norms / loop_passes / exit_gate) trains only")
         if "layers" not in params:
             raise NotImplementedError(
                 "GPT.quantize_weights walks params['layers'] as one "

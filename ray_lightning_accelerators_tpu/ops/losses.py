@@ -27,6 +27,12 @@ sharded over data/fsdp axes, pass ``mesh=``: the op drops into
 ``jax.shard_map`` over those axes — each device streams only its local rows
 and the scalar sums are ``psum``'d, which is exactly the gradient
 all-reduce data parallelism needs anyway.
+
+**Row weights:** ``row_weights=`` turns the mean into ``sum_r w_r *
+loss_r`` with a gradient for ``w`` too (``d / d w_r`` is the row's own
+loss, kept as a ``[rows]`` float32 residual): a looped model's passes go
+through ONE streamed call against the head, each row weighted by its
+pass's exit probability (``models/transformer.GPT._loop_loss``).
 """
 
 from __future__ import annotations
@@ -67,9 +73,10 @@ def _pad_rows(h: jax.Array, targets: jax.Array, chunk: int):
     return h, targets, nc
 
 
-def _chunk_stats(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
-                 label_smoothing: float, z_loss: float):
-    """Per-chunk forward: returns (sum loss, sum correct, n valid).
+def _row_losses(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
+                label_smoothing: float, z_loss: float):
+    """Per-chunk forward, row by row: returns (loss [chunk], logits
+    [chunk, V], valid [chunk], targets with masked entries at 0).
 
     The matmul runs in the inputs' dtype (bf16 from the model) with f32
     accumulation — MXU-native — instead of upcasting the operands.
@@ -95,6 +102,14 @@ def _chunk_stats(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
         row_loss -= (label_smoothing / w.shape[1]) * jnp.sum(logits, -1)
     if z_loss:
         row_loss += z_loss * lse * lse
+    return row_loss, logits, valid, tgt
+
+
+def _chunk_stats(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
+                 label_smoothing: float, z_loss: float):
+    """Per-chunk forward: returns (sum loss, sum correct, n valid)."""
+    row_loss, logits, valid, tgt = _row_losses(h_c, w, tgt_c,
+                                               label_smoothing, z_loss)
     loss_sum = jnp.sum(jnp.where(valid, row_loss, 0.0))
     correct = jnp.sum(jnp.where(valid, jnp.argmax(logits, -1) == tgt, 0))
     return loss_sum, correct.astype(jnp.float32), \
@@ -136,16 +151,20 @@ def _sums_fwd(h, w, targets, chunk_rows, psum_axes, label_smoothing,
                                z_loss), (h, w, targets)
 
 
-def _sums_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
-    h, w, targets = res
-    scale = g[0].astype(jnp.float32)  # correct/valid counts carry no grad
+def _grad_scan(h, w, targets, scale, row_scale, chunk_rows, psum_axes,
+               label_smoothing, z_loss):
+    """``(dh, dw)`` of ``scale * sum_r row_scale_r * loss_r`` (``row_scale``
+    None: every row 1), streamed over the row chunks: each chunk's
+    softmax is made again and contracted at once into dh and dw."""
     rows, d = h.shape
     hp, tp, nc = _pad_rows(h, targets, chunk_rows)
-    hcs = hp.reshape(nc, chunk_rows, d)
-    tcs = tp.reshape(nc, chunk_rows)
+    xs = (hp.reshape(nc, chunk_rows, d), tp.reshape(nc, chunk_rows))
+    if row_scale is not None:
+        xs += (jnp.pad(row_scale, (0, nc * chunk_rows - rows)
+                       ).reshape(nc, chunk_rows),)
 
     def step(dw_acc, args):
-        h_c, t_c = args
+        h_c, t_c, *r_c = args
         valid = t_c >= 0
         tgt = jnp.where(valid, t_c, 0)
         logits = jnp.dot(h_c, w, preferred_element_type=jnp.float32)
@@ -159,7 +178,8 @@ def _sums_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
             tgt, w.shape[1], dtype=jnp.float32)
         if label_smoothing:
             gl -= label_smoothing / w.shape[1]
-        gl = jnp.where(valid[:, None], gl, 0.0) * scale
+        gl = jnp.where(valid[:, None], gl, 0.0) * (
+            scale * r_c[0][:, None] if r_c else scale)
         glc = gl.astype(h_c.dtype)  # grads ride the MXU in compute dtype
         dh_c = jnp.dot(glc, w.T, preferred_element_type=jnp.float32
                        ).astype(h_c.dtype)
@@ -172,14 +192,72 @@ def _sums_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
     # is free after fusion and a no-op outside shard_map)
     dw_init = jnp.zeros((d, w.shape[1]), jnp.float32) + \
         0.0 * hp[0, 0].astype(jnp.float32)
-    dw, dhcs = jax.lax.scan(step, dw_init, (hcs, tcs))
+    dw, dhcs = jax.lax.scan(step, dw_init, xs)
     dh = dhcs.reshape(nc * chunk_rows, d)[:rows].astype(h.dtype)
     if psum_axes:
         dw = jax.lax.psum(dw, psum_axes)
-    return dh, dw.astype(w.dtype), None
+    return dh, dw.astype(w.dtype)
+
+
+def _sums_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
+    h, w, targets = res
+    scale = g[0].astype(jnp.float32)  # correct/valid counts carry no grad
+    dh, dw = _grad_scan(h, w, targets, scale, None, chunk_rows, psum_axes,
+                        label_smoothing, z_loss)
+    return dh, dw, None
 
 
 _streamed_sums.defvjp(_sums_fwd, _sums_bwd)
+
+
+def _weighted_rows_impl(h, w, targets, weights, chunk_rows,
+                        label_smoothing, z_loss):
+    rows, d = h.shape
+    hp, tp, nc = _pad_rows(h, targets, chunk_rows)
+
+    def one(args):
+        h_c, t_c = args
+        row_loss, logits, valid, tgt = _row_losses(
+            h_c, w, t_c, label_smoothing, z_loss)
+        return (jnp.where(valid, row_loss, 0.0), jnp.where(
+            valid, jnp.argmax(logits, -1) == tgt, 0).astype(jnp.float32))
+
+    row_loss, correct = jax.lax.map(one, (hp.reshape(nc, chunk_rows, d),
+                                          tp.reshape(nc, chunk_rows)))
+    row_loss = row_loss.reshape(-1)[:rows]
+    return (jnp.sum(weights.astype(jnp.float32) * row_loss), row_loss,
+            correct.reshape(-1)[:rows])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _weighted_rows(h, w, targets, weights, chunk_rows, psum_axes=(),
+                   label_smoothing=0.0, z_loss=0.0):
+    """``(sum_r weights_r * loss_r, loss [rows], correct [rows])``
+    streamed over row chunks.  Only the sum carries gradient: to ``h``
+    and ``w`` through the rows' losses, each scaled by its weight, and
+    to ``weights``, whose cotangent is the row's own loss (the one
+    residual this keeps beside its operands).  ``psum_axes`` as
+    ``_streamed_sums``."""
+    return _weighted_rows_impl(h, w, targets, weights, chunk_rows,
+                               label_smoothing, z_loss)
+
+
+def _weighted_fwd(h, w, targets, weights, chunk_rows, psum_axes,
+                  label_smoothing, z_loss):
+    out = _weighted_rows_impl(h, w, targets, weights, chunk_rows,
+                              label_smoothing, z_loss)
+    return out, (h, w, targets, weights, out[1])
+
+
+def _weighted_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
+    h, w, targets, weights, row_loss = res
+    scale = g[0].astype(jnp.float32)
+    dh, dw = _grad_scan(h, w, targets, scale, weights.astype(jnp.float32),
+                        chunk_rows, psum_axes, label_smoothing, z_loss)
+    return dh, dw, None, (scale * row_loss).astype(weights.dtype)
+
+
+_weighted_rows.defvjp(_weighted_fwd, _weighted_bwd)
 
 
 def _batch_axes_in(mesh) -> Tuple[str, ...]:
@@ -192,11 +270,18 @@ def fused_linear_cross_entropy(h: jax.Array, w: jax.Array,
                                targets: jax.Array,
                                chunk_rows: int = DEFAULT_CHUNK_ROWS,
                                mesh=None, label_smoothing: float = 0.0,
-                               z_loss: float = 0.0
-                               ) -> Tuple[jax.Array, jax.Array]:
+                               z_loss: float = 0.0,
+                               row_weights: Optional[jax.Array] = None):
     """Streaming LM-head loss.  h: [rows, d], w: [d, V], targets: [rows]
     int32 (negative entries masked).  Returns (mean_loss f32, accuracy f32);
     only ``mean_loss`` is differentiable (accuracy grad is zero).
+
+    With ``row_weights`` ([rows] float) it returns ``(sum_r weights_r *
+    loss_r, loss [rows], correct [rows])`` instead: the weighted SUM
+    over all rows (the caller's to normalise) with a gradient to ``h``,
+    ``w`` and ``row_weights``; the rows' own losses and hits come out as
+    values (no gradient through them).  Without it the program is the
+    unweighted one, op for op.
 
     Logits are computed chunk-by-chunk and never materialized whole — see
     module docstring.  ``chunk_rows`` bounds the live logits block
@@ -214,10 +299,17 @@ def fused_linear_cross_entropy(h: jax.Array, w: jax.Array,
     if mesh is not None and _batch_axes_in(mesh):
         from ..parallel.sharding import manual_axes
         axes = _batch_axes_in(mesh)
+        if row_weights is not None:
+            return _weighted_sharded(h, w, targets, row_weights, chunk_rows,
+                                     mesh, axes, set(axes) <= manual_axes(),
+                                     label_smoothing, z_loss)
         if set(axes) <= manual_axes():
             return _streamed_psum_mean(h, w, targets, chunk_rows, axes,
                                        label_smoothing, z_loss)
         return _fused_sharded(h, w, targets, chunk_rows, mesh,
+                              label_smoothing, z_loss)
+    if row_weights is not None:
+        return _weighted_rows(h, w, targets, row_weights, chunk_rows, (),
                               label_smoothing, z_loss)
     ls, cs, n = _streamed_sums(h, w, targets, chunk_rows, (),
                                label_smoothing, z_loss)
@@ -255,3 +347,26 @@ def _fused_sharded(h, w, targets, chunk_rows, mesh, label_smoothing=0.0,
         in_specs=(P(axes, None), P(None, None), P(axes)),
         # graftlint: ok(sharding-inventory) — scalar replicated outputs
         out_specs=(P(), P()))(h, w, targets)
+
+
+def _weighted_sharded(h, w, targets, weights, chunk_rows, mesh, axes,
+                      bound: bool, label_smoothing=0.0, z_loss=0.0):
+    """The weighted loss over rows sharded on ``axes``: each device
+    streams its own rows, the weighted sum is psum'd, the rows' losses
+    and hits stay where their rows are.  ``bound``: the axes are manual
+    already (the caller runs inside a shard_map)."""
+    P = jax.sharding.PartitionSpec
+
+    def body(h_l, w_r, t_l, r_l):
+        total, row_loss, correct = _weighted_rows(
+            h_l, w_r, t_l, r_l, chunk_rows, axes, label_smoothing, z_loss)
+        return jax.lax.psum(total, axes), row_loss, correct
+
+    if bound:
+        return body(h, w, targets, weights)
+    return jax.shard_map(
+        body, mesh=mesh,
+        # graftlint: ok(sharding-inventory) — fused-loss shard_map specs
+        in_specs=(P(axes, None), P(None, None), P(axes), P(axes)),
+        # graftlint: ok(sharding-inventory) — a replicated sum, local rows
+        out_specs=(P(), P(axes), P(axes)))(h, w, targets, weights)

@@ -14,7 +14,11 @@ metrics; ``rla-tpu trace`` prints each op's name beside its time).
 Named scopes are metadata only: nothing here runs on the device.
 
 The scope contract (docs/API.md "Named scopes", PERF.md §3):
-``gpt/embed``, ``gpt/layers``, ``gpt/attn``, ``gpt/mlp``, ``gpt/norm``,
+``gpt/embed``, ``gpt/layers``, ``gpt/loop`` (a looped stack's pass
+loop around the layer scan), ``gpt/loop_exit`` (its exit gate, exit
+distribution and entropy term, outside ``gpt/loss``; the counters
+``loop_loss_pass_<t>``, ``loop_exit_mean_pass`` and ``loop_exit_entropy``
+ride the step's logged metrics), ``gpt/attn``, ``gpt/mlp``, ``gpt/norm``,
 ``gpt/conv``, ``gpt/ssm``, ``gpt/ssm_scan``, ``gpt/moe_route``,
 ``gpt/moe_dispatch``, ``gpt/moe_experts``, ``gpt/moe_combine``,
 ``gpt/moe_latent``, ``gpt/moe_shared``, ``gpt/loss``, ``optimizer``,

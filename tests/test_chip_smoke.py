@@ -62,6 +62,15 @@ def test_nemotron_cell_shapes_phase_rehearsal(capsys):
     assert '"op": "latent_moe"' in out and '"rows_in": 128' in out
 
 
+def test_ouro_cell_shapes_phase_rehearsal(capsys):
+    chip_smoke.phase_ouro_cell_shapes(
+        0, batch=2, seq=64, d_model=64, heads=4, head_dim=24, d_ff=160,
+        layers=2, passes=3, block=32, iters=1, on_chip=False)
+    out = capsys.readouterr().out
+    assert '"op": "looped_stack"' in out
+    assert '"layer_applications": 6' in out and "['layers']['ln1_post']" in out
+
+
 def test_four_chip_phase_rehearsal(out_dir, capsys):
     chip_smoke.phase_four_chip(TINY, 0)
     out = capsys.readouterr().out

@@ -401,6 +401,38 @@ def test_remat_step_runs_the_k_walk_and_the_routing_once(
             if "transpose(" in line or "rematted_computation" in line] == []
 
 
+def test_looped_step_fits_the_chip_and_holds_one_layer_body(
+        topo, chip_dispatch):
+    """``train-ouro-loop-8k``: 6 sandwich-norm layers of 16 heads of 128
+    run 4 times on the same weights at [1, 8192], remat, flash blocks
+    1024, the whole 49,152-id vocabulary through the weighted fused loss.
+    The pass loop is a loop in the program: ONE forward kernel (its
+    output kept by name for all 24 applications, so none under the
+    backward) and one of each backward kernel; the step stays under the
+    chip's 15.75 GiB with room for the scanned epoch's 1.4 GB more
+    (15.00 GB described, PR 33; 8 layers read 18.48)."""
+    config = TransformerConfig(
+        vocab_size=49152, d_model=2048, n_heads=16, attn_head_dim=128,
+        d_ff=5632, n_layers=6, max_seq_len=8192, tie_embeddings=False,
+        rope_theta=1e6, gated_mlp=True, rope_style="half", post_norms=True,
+        loop_passes=4, exit_gate=True, exit_beta=0.05, remat=True,
+        flash_block_q=1024, flash_block_k=1024, loss_chunk_rows=2048)
+    _, lowered = _abstract_train_step(topo.devices[:1], 1, config=config)
+    assert _kernels(lowered) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                 "flash_fwd", "rms_norm"]
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert _forward_kernel_calls(hlo) == 1
+    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum("tpu_custom_call" in line and kernel in line
+                   for line in hlo.splitlines()
+                   if "custom-call(" in line) == 1, kernel
+    assert _per_device_bytes(compiled) < 15.3e9
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    assert any(re.search(r"gpt/loop\b.*gpt/layers", n) for n in names)
+    assert any("gpt/loop_exit" in n for n in names)
+
+
 # --------------------------------------------------------------------- #
 # The attention operator at the benchmark cells' shapes                  #
 # --------------------------------------------------------------------- #
