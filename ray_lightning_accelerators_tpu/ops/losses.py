@@ -12,13 +12,21 @@ thousands: for a 4k-token batch and 50k vocab, logits + saved softmax
 residuals are ~1.6 GB of HBM that exists only to be reduced to one scalar.
 
 ``fused_linear_cross_entropy`` streams row chunks through the unembedding
-matmul with ``lax.map``: each chunk computes its logits [chunk, V] in VMEM,
-reduces to per-row loss/correctness, and discards them.  The backward pass
-(``jax.custom_vjp``) recomputes each chunk's softmax and contracts it
-immediately into dH and dW, so the full logits tensor never exists in either
-direction.  Peak extra memory drops from O(rows*V) to O(chunk*V), trading
-one extra pass of MXU matmul FLOPs — the classic TPU bandwidth-for-FLOPs
-trade (HBM is the bottleneck, the MXU is not).
+matmul: each chunk computes its logits [chunk, V], reduces them to per-row
+loss/correctness, and discards them, so the full logits tensor never
+exists.  Peak extra memory drops from O(rows*V) to O(chunk*V).
+
+**Under differentiation the forward makes the gradient** (the forward
+rule of the ``jax.custom_vjp``): the cotangent that reaches the loss is one
+scalar, so while a chunk's logits are held its ``softmax - onehot`` is
+contracted at once into ``dh`` and ``dw`` — THREE ``rows x d x V``
+products a step (logits, dh, dw), not a fourth to make the logits again
+in the backward: on the chip the products are 85 % of this op's time, the
+MXU is its bottleneck.  Between forward and backward it holds ``dh``
+(float32 ``[rows, d]``) and ``dw`` (float32 ``[d, V]``), not ``h``, ``w``
+or the targets; the backward multiplies both by the cotangent and rounds
+each to its operand's dtype, once.  A call nobody differentiates
+(validation, serving) runs the one product and no gradient work.
 
 **Sharded batches:** chunking the globally-flattened row dim under GSPMD
 would force an all-gather of the hidden states and replicate the whole head
@@ -76,7 +84,8 @@ def _pad_rows(h: jax.Array, targets: jax.Array, chunk: int):
 def _row_losses(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
                 label_smoothing: float, z_loss: float):
     """Per-chunk forward, row by row: returns (loss [chunk], logits
-    [chunk, V], valid [chunk], targets with masked entries at 0).
+    [chunk, V], valid [chunk], targets with masked entries at 0, lse
+    [chunk]).
 
     The matmul runs in the inputs' dtype (bf16 from the model) with f32
     accumulation — MXU-native — instead of upcasting the operands.
@@ -102,33 +111,98 @@ def _row_losses(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
         row_loss -= (label_smoothing / w.shape[1]) * jnp.sum(logits, -1)
     if z_loss:
         row_loss += z_loss * lse * lse
-    return row_loss, logits, valid, tgt
+    return row_loss, logits, valid, tgt, lse
 
 
-def _chunk_stats(h_c: jax.Array, w: jax.Array, tgt_c: jax.Array,
-                 label_smoothing: float, z_loss: float):
-    """Per-chunk forward: returns (sum loss, sum correct, n valid)."""
-    row_loss, logits, valid, tgt = _row_losses(h_c, w, tgt_c,
-                                               label_smoothing, z_loss)
-    loss_sum = jnp.sum(jnp.where(valid, row_loss, 0.0))
-    correct = jnp.sum(jnp.where(valid, jnp.argmax(logits, -1) == tgt, 0))
-    return loss_sum, correct.astype(jnp.float32), \
-        jnp.sum(valid).astype(jnp.float32)
+def _stream(h, w, targets, row_scale, chunk_rows, label_smoothing, z_loss,
+            grad: bool):
+    """The row chunks through the head, ONCE.  ``row_scale`` None: per
+    chunk ``(loss sum, hits, valid rows)``; given: per row ``(loss,
+    hit)``, ``[chunks, chunk_rows]`` each.
 
-
-def _streamed_sums_impl(h, w, targets, chunk_rows, label_smoothing,
-                        z_loss):
+    With ``grad`` also ``dh f32[rows, d]`` and ``dw f32[d, V]`` of ``sum_r
+    row_scale_r * loss_r`` (None: every row 1), made while the chunk's
+    logits are held: its softmax is contracted at once into dh and dw, in
+    the products' accumulator type, so that the backward can scale them
+    before their one rounding."""
     rows, d = h.shape
     hp, tp, nc = _pad_rows(h, targets, chunk_rows)
-    hcs = hp.reshape(nc, chunk_rows, d)
-    tcs = tp.reshape(nc, chunk_rows)
+    xs = (hp.reshape(nc, chunk_rows, d), tp.reshape(nc, chunk_rows))
+    per_row = row_scale is not None
 
-    def one(args):
-        h_c, t_c = args
-        return _chunk_stats(h_c, w, t_c, label_smoothing, z_loss)
+    def forward(h_c, t_c):
+        row_loss, logits, valid, tgt, lse = _row_losses(
+            h_c, w, t_c, label_smoothing, z_loss)
+        # in this order: a call nobody differentiates traces the program
+        # it traced before the gradient moved here (tests/test_ouro.py)
+        loss = jnp.where(valid, row_loss, 0.0)
+        if not per_row:
+            loss = jnp.sum(loss)
+        hit = jnp.where(valid, jnp.argmax(logits, -1) == tgt, 0)
+        if per_row:
+            out = loss, hit.astype(jnp.float32)
+        else:
+            out = (loss, jnp.sum(hit).astype(jnp.float32),
+                   jnp.sum(valid).astype(jnp.float32))
+        return out, (logits, valid, tgt, lse)
 
-    loss_sums, corrects, valids = jax.lax.map(one, (hcs, tcs))
-    return jnp.sum(loss_sums), jnp.sum(corrects), jnp.sum(valids)
+    def step(dw_acc, args):
+        h_c, t_c, *r_c = args
+        out, (logits, valid, tgt, lse) = forward(h_c, t_c)
+        # d row_loss / d logits = p*(1 + 2*z*lse) - (1-eps)*onehot - eps/V
+        p = jnp.exp(logits - lse[:, None])
+        if z_loss:
+            p *= 1.0 + 2.0 * z_loss * lse[:, None]
+        gl = p - (1.0 - label_smoothing) * jax.nn.one_hot(
+            tgt, w.shape[1], dtype=jnp.float32)
+        if label_smoothing:
+            gl -= label_smoothing / w.shape[1]
+        gl = jnp.where(valid[:, None], gl, 0.0)
+        if r_c:
+            gl *= r_c[0][:, None]
+        glc = gl.astype(h_c.dtype)  # grads ride the MXU in compute dtype
+        dh_c = jnp.dot(glc, w.T, preferred_element_type=jnp.float32)
+        dw_acc = dw_acc + jnp.dot(h_c.T, glc,
+                                  preferred_element_type=jnp.float32)
+        return dw_acc, (out, dh_c)
+
+    if not grad:
+        out = jax.lax.map(lambda args: forward(*args)[0], xs)
+    else:
+        if per_row:
+            xs += (jnp.pad(row_scale.astype(jnp.float32),
+                           (0, nc * chunk_rows - rows)
+                           ).reshape(nc, chunk_rows),)
+        # init carry inherits h's varying-manual-axes type so the scan
+        # carry stays consistent when this runs inside shard_map (the
+        # `+ 0*h[0,0]` is free after fusion and a no-op outside shard_map)
+        dw_init = jnp.zeros((d, w.shape[1]), jnp.float32) + \
+            0.0 * hp[0, 0].astype(jnp.float32)
+        dw, (out, dhcs) = jax.lax.scan(step, dw_init, xs)
+    return (out, dhcs.reshape(-1, d)[:rows], dw) if grad else out
+
+
+def _weighted_out(weights, row_loss, correct):
+    """``_weighted_rows``' returns from the chunks' rows."""
+    rows = weights.shape[0]
+    row_loss = row_loss.reshape(-1)[:rows]
+    return (jnp.sum(weights.astype(jnp.float32) * row_loss), row_loss,
+            correct.reshape(-1)[:rows])
+
+
+@jax.tree_util.register_static
+class _Dtypes(tuple):
+    """The operands' dtypes among a forward rule's residuals (no array:
+    the operands themselves do not cross to the backward)."""
+
+
+def _scaled(dh, dw, dtypes, scale, psum_axes):
+    """The backward: what the forward made, times the cotangent's scalar,
+    each rounded to its operand's dtype once."""
+    dw = dw * scale
+    if psum_axes:
+        dw = jax.lax.psum(dw, psum_axes)
+    return (dh * scale).astype(dtypes[0]), dw.astype(dtypes[1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -141,92 +215,24 @@ def _streamed_sums(h, w, targets, chunk_rows, psum_axes=(),
     those mesh axes, the backward all-reduces dW over them itself — the
     shard_map transpose cannot infer that the custom bwd's dW needs
     replication (it would reject the out_spec otherwise)."""
-    return _streamed_sums_impl(h, w, targets, chunk_rows, label_smoothing,
-                               z_loss)
+    return tuple(jnp.sum(o) for o in _stream(
+        h, w, targets, None, chunk_rows, label_smoothing, z_loss, False))
 
 
 def _sums_fwd(h, w, targets, chunk_rows, psum_axes, label_smoothing,
               z_loss):
-    return _streamed_sums_impl(h, w, targets, chunk_rows, label_smoothing,
-                               z_loss), (h, w, targets)
-
-
-def _grad_scan(h, w, targets, scale, row_scale, chunk_rows, psum_axes,
-               label_smoothing, z_loss):
-    """``(dh, dw)`` of ``scale * sum_r row_scale_r * loss_r`` (``row_scale``
-    None: every row 1), streamed over the row chunks: each chunk's
-    softmax is made again and contracted at once into dh and dw."""
-    rows, d = h.shape
-    hp, tp, nc = _pad_rows(h, targets, chunk_rows)
-    xs = (hp.reshape(nc, chunk_rows, d), tp.reshape(nc, chunk_rows))
-    if row_scale is not None:
-        xs += (jnp.pad(row_scale, (0, nc * chunk_rows - rows)
-                       ).reshape(nc, chunk_rows),)
-
-    def step(dw_acc, args):
-        h_c, t_c, *r_c = args
-        valid = t_c >= 0
-        tgt = jnp.where(valid, t_c, 0)
-        logits = jnp.dot(h_c, w, preferred_element_type=jnp.float32)
-        p = jax.nn.softmax(logits, axis=-1)
-        # d row_loss / d logits = p*(1 + 2*z*lse) - (1-eps)*onehot - eps/V
-        coef = 1.0
-        if z_loss:
-            lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
-            coef = 1.0 + 2.0 * z_loss * lse
-        gl = p * coef - (1.0 - label_smoothing) * jax.nn.one_hot(
-            tgt, w.shape[1], dtype=jnp.float32)
-        if label_smoothing:
-            gl -= label_smoothing / w.shape[1]
-        gl = jnp.where(valid[:, None], gl, 0.0) * (
-            scale * r_c[0][:, None] if r_c else scale)
-        glc = gl.astype(h_c.dtype)  # grads ride the MXU in compute dtype
-        dh_c = jnp.dot(glc, w.T, preferred_element_type=jnp.float32
-                       ).astype(h_c.dtype)
-        dw_acc = dw_acc + jnp.dot(h_c.T, glc,
-                                  preferred_element_type=jnp.float32)
-        return dw_acc, dh_c
-
-    # init carry inherits h's varying-manual-axes type so the scan carry
-    # stays consistent when this bwd runs inside shard_map (the `+ 0*h[0,0]`
-    # is free after fusion and a no-op outside shard_map)
-    dw_init = jnp.zeros((d, w.shape[1]), jnp.float32) + \
-        0.0 * hp[0, 0].astype(jnp.float32)
-    dw, dhcs = jax.lax.scan(step, dw_init, xs)
-    dh = dhcs.reshape(nc * chunk_rows, d)[:rows].astype(h.dtype)
-    if psum_axes:
-        dw = jax.lax.psum(dw, psum_axes)
-    return dh, dw.astype(w.dtype)
+    out, dh, dw = _stream(h, w, targets, None, chunk_rows, label_smoothing,
+                          z_loss, True)
+    return tuple(jnp.sum(o) for o in out), (dh, dw,
+                                            _Dtypes((h.dtype, w.dtype)))
 
 
 def _sums_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
-    h, w, targets = res
-    scale = g[0].astype(jnp.float32)  # correct/valid counts carry no grad
-    dh, dw = _grad_scan(h, w, targets, scale, None, chunk_rows, psum_axes,
-                        label_smoothing, z_loss)
-    return dh, dw, None
+    # correct/valid counts carry no grad
+    return *_scaled(*res, g[0].astype(jnp.float32), psum_axes), None
 
 
 _streamed_sums.defvjp(_sums_fwd, _sums_bwd)
-
-
-def _weighted_rows_impl(h, w, targets, weights, chunk_rows,
-                        label_smoothing, z_loss):
-    rows, d = h.shape
-    hp, tp, nc = _pad_rows(h, targets, chunk_rows)
-
-    def one(args):
-        h_c, t_c = args
-        row_loss, logits, valid, tgt = _row_losses(
-            h_c, w, t_c, label_smoothing, z_loss)
-        return (jnp.where(valid, row_loss, 0.0), jnp.where(
-            valid, jnp.argmax(logits, -1) == tgt, 0).astype(jnp.float32))
-
-    row_loss, correct = jax.lax.map(one, (hp.reshape(nc, chunk_rows, d),
-                                          tp.reshape(nc, chunk_rows)))
-    row_loss = row_loss.reshape(-1)[:rows]
-    return (jnp.sum(weights.astype(jnp.float32) * row_loss), row_loss,
-            correct.reshape(-1)[:rows])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -236,25 +242,26 @@ def _weighted_rows(h, w, targets, weights, chunk_rows, psum_axes=(),
     streamed over row chunks.  Only the sum carries gradient: to ``h``
     and ``w`` through the rows' losses, each scaled by its weight, and
     to ``weights``, whose cotangent is the row's own loss (the one
-    residual this keeps beside its operands).  ``psum_axes`` as
+    residual this keeps beside dh and dw).  ``psum_axes`` as
     ``_streamed_sums``."""
-    return _weighted_rows_impl(h, w, targets, weights, chunk_rows,
-                               label_smoothing, z_loss)
+    return _weighted_out(weights, *_stream(
+        h, w, targets, weights, chunk_rows, label_smoothing, z_loss, False))
 
 
 def _weighted_fwd(h, w, targets, weights, chunk_rows, psum_axes,
                   label_smoothing, z_loss):
-    out = _weighted_rows_impl(h, w, targets, weights, chunk_rows,
-                              label_smoothing, z_loss)
-    return out, (h, w, targets, weights, out[1])
+    rows, dh, dw = _stream(h, w, targets, weights, chunk_rows,
+                           label_smoothing, z_loss, True)
+    out = _weighted_out(weights, *rows)
+    return out, (dh, dw, _Dtypes((h.dtype, w.dtype, weights.dtype)),
+                 out[1])
 
 
 def _weighted_bwd(chunk_rows, psum_axes, label_smoothing, z_loss, res, g):
-    h, w, targets, weights, row_loss = res
+    dh, dw, dtypes, row_loss = res
     scale = g[0].astype(jnp.float32)
-    dh, dw = _grad_scan(h, w, targets, scale, weights.astype(jnp.float32),
-                        chunk_rows, psum_axes, label_smoothing, z_loss)
-    return dh, dw, None, (scale * row_loss).astype(weights.dtype)
+    return *_scaled(dh, dw, dtypes, scale, psum_axes), None, \
+        (scale * row_loss).astype(dtypes[2])
 
 
 _weighted_rows.defvjp(_weighted_fwd, _weighted_bwd)

@@ -499,16 +499,20 @@ _SMALL = dict(vocab_size=VOCAB, d_model=64, n_heads=4, d_ff=128, n_layers=3,
 # head, and jitted on the CPU any respelling (a lane rotation and a
 # select too) rounds 13 % of the cotangent's elements the other way
 # (the compiler contracts a * c + b * s into another fused multiply-add;
-# op by op the gradient is the pairwise one bit for bit)
+# op by op the gradient is the pairwise one bit for bit).  PR 34 left
+# every digest and loss again and moved the gradient norm of the dense
+# and the LFM2 kind by one unit in the last place (..a2 -> ..a0, ..74 ->
+# ..76): the fused loss multiplies dh and dw by 1 / valid rows AFTER
+# their products where it multiplied the softmax's gradient before them
 ONE_STACK = {
     "dense": (_SMALL, "bae5bc0b854c20e6", "c5cf35fbf2e6ace9",
-              "0x1.60f5180000000p+2", "0x1.cc19a20000000p+0", [1, 3]),
+              "0x1.60f5180000000p+2", "0x1.cc19a00000000p+0", [1, 3]),
     "gqa_capacity_moe": (
         {**_SMALL, **TODAY["gqa_capacity_moe"][0]},
         "8af981d53c36461b", "a49323c3c14de7fc",
         "0x1.63e1400000000p+2", "0x1.17a4660000000p+1", [1, 3]),
     "lfm2": (MODEL, "9728f03cda70c1b3", "af66504f958d5f44",
-             "0x1.62ecf40000000p+2", "0x1.9c87740000000p+2", [1, 1, 1, 3]),
+             "0x1.62ecf40000000p+2", "0x1.9c87760000000p+2", [1, 1, 1, 3]),
 }
 
 

@@ -196,3 +196,98 @@ def test_gpt_loss_shaping_fused_matches_naive():
         loss, _ = model.training_step(params, toks, jax.random.PRNGKey(1))
         losses[fused] = float(loss)
     assert losses[True] == pytest.approx(losses[False], rel=1e-4)
+
+
+# --------------------------------------------------------------------- #
+# The forward rule makes the gradient: dh and dw are residuals, the      #
+# backward a scale                                                       #
+# --------------------------------------------------------------------- #
+def _materialised(h, w, t, weights, eps, zl):
+    """The fused op's first return from whole logits, for autodiff."""
+    valid = t >= 0
+    logits = h @ w
+    lse = jax.nn.logsumexp(logits, -1)
+    tgt = jnp.take_along_axis(logits, jnp.where(valid, t, 0)[:, None],
+                              -1)[:, 0]
+    row = jnp.where(valid, lse - (1 - eps) * tgt
+                    - (eps / w.shape[1]) * logits.sum(-1) + zl * lse ** 2,
+                    0.0)
+    if weights is None:
+        return row.sum() / jnp.maximum(valid.sum(), 1)
+    return (weights * row).sum()
+
+
+def _paths(t, chunk, eps=0.0, zl=0.0):
+    """{path: (fused, reference, number of differentiated operands)}"""
+    kw = dict(label_smoothing=eps, z_loss=zl)
+    return {
+        "mean": (lambda h, w, r: fused_linear_cross_entropy(
+            h, w, t, chunk, **kw)[0],
+            lambda h, w, r: _materialised(h, w, t, None, eps, zl), 2),
+        "row-weights": (lambda h, w, r: fused_linear_cross_entropy(
+            h, w, t, chunk, row_weights=r, **kw)[0],
+            lambda h, w, r: _materialised(h, w, t, r, eps, zl), 3),
+    }
+
+
+def _masked_case(dtype=jnp.float32):
+    """100 rows in chunks of 32 (the last one padded by 28), ten rows
+    masked, a weight a row."""
+    h, w, t = _case(rows=100, d=32, v=257, seed=3, dtype=dtype)
+    r = jnp.asarray(np.random.default_rng(4).uniform(0.1, 1.0, 100),
+                    jnp.float32)
+    return h, w, t.at[40:50].set(-1), r
+
+
+@pytest.mark.parametrize("shaping", [(0.0, 0.0), (0.1, 1e-2)],
+                         ids=["plain", "smoothing+z"])
+@pytest.mark.parametrize("path", ["mean", "row-weights"])
+def test_vjp_with_a_cotangent_that_is_not_one_matches_autodiff(path,
+                                                               shaping):
+    h, w, t, r = _masked_case()
+    fused, reference, n = _paths(t, 32, *shaping)[path]
+    out_f, vjp_f = jax.vjp(fused, h, w, r)
+    out_r, vjp_r = jax.vjp(reference, h, w, r)
+    np.testing.assert_allclose(out_f, out_r, rtol=1e-5)
+    grads = vjp_f(jnp.float32(0.37))[:n]
+    for got, want in zip(grads, vjp_r(jnp.float32(0.37))):
+        assert got.dtype == want.dtype and np.abs(want).max() > 1e-4
+        np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(grads[0][40:50], 0.0)
+
+
+def _vocab_products(jaxpr, v):
+    """The ``dot_general``s of a jaxpr, its sub-jaxprs included, that
+    have a ``v``-sized dimension among their operands or their result."""
+    return sum(
+        (e.primitive.name == "dot_general" and any(
+            v in x.aval.shape for x in (*e.invars, *e.outvars)))
+        + sum(_vocab_products(j, v)
+              for j in jax.core.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("path", ["mean", "row-weights"])
+def test_training_traces_three_vocabulary_products_and_a_call_one(path):
+    """Logits, dh and dw: no second forward under differentiation, and no
+    gradient work in a call nobody differentiates."""
+    h, w, t, r = _masked_case(jnp.bfloat16)
+    fused, _, n = _paths(t, 32, 0.1, 1e-2)[path]
+    trained = jax.make_jaxpr(jax.value_and_grad(
+        fused, argnums=tuple(range(n))))(h, w, r)
+    assert _vocab_products(trained.jaxpr, 257) == 3
+    assert _vocab_products(jax.make_jaxpr(fused)(h, w, r).jaxpr, 257) == 1
+
+
+@pytest.mark.parametrize("path", ["mean", "row-weights"])
+def test_residuals_are_dh_and_dw_not_the_operands(path):
+    """What crosses from the forward to the backward: one float32
+    ``[rows, d]`` (dh) and one float32 ``[d, V]`` (dw); neither the
+    bfloat16 ``h`` nor ``w``, nor a chunk's logits."""
+    h, w, t, r = _masked_case(jnp.bfloat16)
+    _, vjp = jax.vjp(_paths(t, 32)[path][0], h, w, r)
+    held = sorted((x.shape, str(x.dtype)) for x in jax.tree.leaves(vjp)
+                  if getattr(x, "ndim", 0) >= 2)
+    assert held == [((32, 257), "float32"), ((100, 32), "float32")]
+    dh, dw = vjp(jnp.float32(0.37))[:2]
+    assert (dh.dtype, dw.dtype) == (jnp.bfloat16, jnp.bfloat16)

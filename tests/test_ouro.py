@@ -476,10 +476,14 @@ def test_weight_decay_spares_norm_scales_and_the_gates_bias():
 # equation (the GPT-2-shaped stacks: loss and gradient; the two mixed
 # stacks: the loss, whose gradient takes a quarter of a minute to
 # trace).  A PR that changes one of these programs on purpose pins its
-# own digest here.
+# own digest here: PR 34 the two that trace the gradient (the fused
+# loss's forward rule makes dh and dw; 47f5cf62c04ddcd1 and
+# 1d456fc52e8cb887 before it).  The other three trace the loss alone or
+# the materialised one, and kept theirs: the call nobody differentiates
+# is the program it was.
 _PARENTS = {
-    "gpt2": (GPT2, True, "47f5cf62c04ddcd1"),
-    "gpt2-remat": (dict(GPT2, remat=True), True, "1d456fc52e8cb887"),
+    "gpt2": (GPT2, True, "623b250208429e4a"),
+    "gpt2-remat": (dict(GPT2, remat=True), True, "ae04d61271fdf68a"),
     "gpt2-materialised": (dict(GPT2, fused_loss=False), True,
                           "3b040f274eb296f1"),
     "lfm2": (dict(LFM2, remat=True), False, "06993cb687eb30cc"),
