@@ -942,23 +942,6 @@ def phase_four_chip(size: Size, seed: int) -> None:
 
 
 # --------------------------------------------------------------------- #
-def _count_cache_hits() -> dict:
-    """Persistent-compile-cache hits/misses of this process, from jax's
-    own monitoring events."""
-    import jax.monitoring
-
-    counts = {"hits": 0, "misses": 0}
-
-    def on_event(event: str, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            counts["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            counts["misses"] += 1
-
-    jax.monitoring.register_event_listener(on_event)
-    return counts
-
-
 def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -967,7 +950,6 @@ def main(argv=None) -> None:
 
     t0 = time.perf_counter()
     device = phase_device(args.chips)
-    cache = _count_cache_hits()
     size = Size()
     if args.chips == 4:
         phase_four_chip(size, args.seed)
@@ -980,9 +962,13 @@ def main(argv=None) -> None:
         phase_train(size, args.seed)
         model, params = phase_generate(size, args.seed)
         phase_serve(size, args.seed, model, params)
+    # the program's own compile ledger (one listener for the process)
+    from ray_lightning_accelerators_tpu.analysis import compile_guard
+    programs = compile_guard.summary(compile_guard.ledger())
     emit("done", seconds=time.perf_counter() - t0,
-         compile_cache_hits=cache["hits"],
-         compile_cache_misses=cache["misses"])
+         compile_cache_hits=programs["loaded"],
+         compile_cache_misses=programs["missed"],
+         built=programs["built"])
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

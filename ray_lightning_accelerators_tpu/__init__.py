@@ -36,6 +36,13 @@ from . import tune
 from .tune import TuneReportCallback, TuneReportCheckpointCallback
 from .utils import schedules
 
+# the compile ledger (analysis/compile_guard.py) names every program this
+# process compiles or loads: its listeners go in with the package, ahead
+# of the caller's first jit
+from .analysis import compile_guard as _compile_guard
+
+_compile_guard.install()
+
 __version__ = "0.1.0"
 
 __all__ = [

@@ -14,13 +14,17 @@ Two halves (see docs/API.md "Static analysis & compile guard"):
   (``scripts/sharding_audit.py``).  CLI: ``scripts/graftlint.py``
   (``--format json`` for CI / the audit script).
 - **compile-guard** (`compile_guard.py`): a runtime complement counting
-  XLA backend compiles via ``jax.monitoring``, so a test (or bench) can
-  assert "this block compiles at most N programs" — the serve engine's
-  3-program invariant and the trainer's no-retrace-after-warmup are
-  pinned this way in ``tests/test_analysis.py``.
+  the programs jax hands to the backend (compiles and persistent-cache
+  loads) via ``jax.monitoring``, so a test (or bench) can assert "this
+  block compiles at most N programs" — the serve engine's 3-program
+  invariant and the trainer's no-retrace-after-warmup are pinned this
+  way in ``tests/test_analysis.py`` — and keeping the compile ledger:
+  every program by name, with its seconds, its cache outcome and the
+  Trainer phase it fell in.
 
 ``knobs`` is imported eagerly (it is a leaf: stdlib only); the analyzer
-and guard load lazily so importing the package costs nothing at runtime.
+loads lazily, and the package's own ``__init__`` installs the guard's
+listeners (a registration: nothing runs until jax compiles).
 """
 
 from . import knobs  # noqa: F401  (leaf module: registry + typed getters)

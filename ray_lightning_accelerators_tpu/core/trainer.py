@@ -41,7 +41,7 @@ import optax
 
 from ..accelerators.base import Accelerator
 from ..accelerators.tpu import RayTPUAccelerator
-from ..analysis import knobs
+from ..analysis import compile_guard, knobs
 from ..data import prefetch as prefetch_lib
 from ..data.loader import DataLoader
 from ..parallel import mesh as mesh_lib
@@ -51,12 +51,15 @@ from ..telemetry import scopes as scopes_lib
 from ..utils import checkpoint as ckpt_lib
 from ..utils import compile_cache
 from ..utils.logging import CSVLogger, InMemoryLogger, Logger, log
-from ..utils.profiler import Profiler
+from ..utils.profiler import HostSpan, Profiler
 from ..utils.scope import scoped
 from ..utils.seed import rng_from_seed, seed_everything
 from .callbacks import Callback, ModelCheckpoint
 from .module import TpuModule
 from .state import TrainState
+
+# an `epoch_end` event names at most this many compiled programs
+COMPILED_NAMES = 32
 
 _PRECISION_DTYPES = {
     "bf16": jnp.bfloat16, "bf16-mixed": jnp.bfloat16,
@@ -412,6 +415,12 @@ class Trainer:
         # host seconds of the running epoch by phase (_epoch_span), moved
         # into its epoch_end event
         self._epoch_host: Dict[str, float] = {}
+        # the running fit's start-up phases (_setup_span) until its first
+        # epoch ends and `fit_ready` takes them; then None
+        self._fit_setup: Optional[Dict[str, float]] = None
+        # time.monotonic() where the running epoch began: what
+        # `epoch_end.compiled` asks the compile ledger for
+        self._epoch_mono: Optional[float] = None
         self._zero1_update_sh = None
         # param shardings when the compressed exchange runs in the FSDP
         # (reduce-scatter/all-gather) regime; None = replicated-DP regime
@@ -2343,155 +2352,166 @@ class Trainer:
                    ) -> None:
         self.accelerator.validate_process_topology()
         t0 = time.perf_counter()
-        live_resume = ckpt_path == "live"
-        if live_resume and (self._state is None or self.module is None):
-            raise ValueError(
-                "ckpt_path='live' continues from in-memory state; call "
-                "fit() (and optionally resize_in_memory()) first")
-        self.fitting = True
-        self.should_stop = False
-        if not live_resume:
-            self.current_epoch = 0
-            self.epochs_completed = 0
-            self.global_step = 0
-        else:
-            # a live continuation KEEPS its counters, but like a
-            # checkpoint restore it re-enters the epoch that was cut
-            # short: only COMPLETED epochs count, so the sampler replays
-            # the interrupted epoch's permutation rather than skipping
-            # to the next one (keeps the live path's trajectory
-            # identical to the restore path's)
-            self.current_epoch = self.epochs_completed
-        self._last_val_step = -1  # stale values skip epoch-end validation
-        self.module = module
-        module.trainer = self
-        module.compute_dtype = self.compute_dtype
-        if self.int8_matmul:
-            module.int8_matmul = True
+        # the start-up ledger: four phases on the flight recorder's clock,
+        # taken by `fit_ready` when the first epoch ends (_emit_fit_ready)
+        self._fit_setup = {"fit_start": time.monotonic()}
+        with self._setup_span("setup_init"):
+            live_resume = ckpt_path == "live"
+            if live_resume and (self._state is None or self.module is None):
+                raise ValueError(
+                    "ckpt_path='live' continues from in-memory state; call "
+                    "fit() (and optionally resize_in_memory()) first")
+            self.fitting = True
+            self.should_stop = False
+            if not live_resume:
+                self.current_epoch = 0
+                self.epochs_completed = 0
+                self.global_step = 0
+            else:
+                # a live continuation KEEPS its counters, but like a
+                # checkpoint restore it re-enters the epoch that was cut
+                # short: only COMPLETED epochs count, so the sampler replays
+                # the interrupted epoch's permutation rather than skipping
+                # to the next one (keeps the live path's trajectory
+                # identical to the restore path's)
+                self.current_epoch = self.epochs_completed
+            self._last_val_step = -1  # stale values skip epoch-end validation
+            self.module = module
+            module.trainer = self
+            module.compute_dtype = self.compute_dtype
+            if self.int8_matmul:
+                module.int8_matmul = True
 
-        if datamodule is not None:
-            datamodule.setup("fit")
-            train_dataloaders = train_dataloaders or datamodule.train_dataloader()
-            val_dataloaders = val_dataloaders or datamodule.val_dataloader()
-        if train_dataloaders is None:
-            raise ValueError("fit() needs train_dataloaders or a datamodule")
-        train_loader = train_dataloaders
-        self._val_loader = val_dataloaders
+            if datamodule is not None:
+                datamodule.setup("fit")
+                train_dataloaders = train_dataloaders or datamodule.train_dataloader()
+                val_dataloaders = val_dataloaders or datamodule.val_dataloader()
+            if train_dataloaders is None:
+                raise ValueError("fit() needs train_dataloaders or a datamodule")
+            train_loader = train_dataloaders
+            self._val_loader = val_dataloaders
 
-        self.accelerator.setup_environment()
-        self._mesh = self.accelerator.build_mesh()
-        self._bind_preemption()
-        # numeric anomaly guardian (runtime/guardian.py): host companion
-        # for blame attribution + the quarantine ledger; chaos numeric
-        # faults (testing/chaos.py) parsed once per fit
-        from ..runtime import guardian as guardian_lib
-        from ..testing import chaos as chaos_lib
-        self._chaos_numeric = chaos_lib.numeric_faults()
-        self._guardian = (guardian_lib.Guardian(self.guard,
-                                                self.default_root_dir)
-                          if self.guard is not None else None)
-        # live telemetry plane: the per-process server starts once (when
-        # RLA_TPU_METRICS_PORT is configured — on workers it was already
-        # started at boot) and this fit's trainer becomes its live
-        # source, so /metrics answers with the run's CURRENT registry
-        # while steps are still running
-        self._live_server = live_lib.maybe_start_from_env()
-        if self._live_server is not None:
-            self._live_server.sources.bind_trainer(self)
-        telemetry.emit("fit_start", step=self.global_step,
-                       processes=jax.process_count())
+            self.accelerator.setup_environment()
+            self._mesh = self.accelerator.build_mesh()
+            self._bind_preemption()
+            # numeric anomaly guardian (runtime/guardian.py): host companion
+            # for blame attribution + the quarantine ledger; chaos numeric
+            # faults (testing/chaos.py) parsed once per fit
+            from ..runtime import guardian as guardian_lib
+            from ..testing import chaos as chaos_lib
+            self._chaos_numeric = chaos_lib.numeric_faults()
+            self._guardian = (guardian_lib.Guardian(self.guard,
+                                                    self.default_root_dir)
+                              if self.guard is not None else None)
+            # live telemetry plane: the per-process server starts once (when
+            # RLA_TPU_METRICS_PORT is configured — on workers it was already
+            # started at boot) and this fit's trainer becomes its live
+            # source, so /metrics answers with the run's CURRENT registry
+            # while steps are still running
+            self._live_server = live_lib.maybe_start_from_env()
+            if self._live_server is not None:
+                self._live_server.sources.bind_trainer(self)
+            telemetry.emit("fit_start", step=self.global_step,
+                           processes=jax.process_count())
 
-        # sampler auto-injection (reference: ray_ddp.py:280-295)
-        if self.accelerator.require_distributed_sampler:
-            kwargs = self.accelerator.distributed_sampler_kwargs()
-            if isinstance(train_loader, DataLoader):
-                # preserve the user's shuffle intent (PTL-style replacement)
-                train_loader._inject_sampler(shuffle=train_loader.shuffle,
-                                             **kwargs)
-            if isinstance(self._val_loader, DataLoader):
-                self._val_loader._inject_sampler(shuffle=False, **kwargs)
+            # sampler auto-injection (reference: ray_ddp.py:280-295)
+            if self.accelerator.require_distributed_sampler:
+                kwargs = self.accelerator.distributed_sampler_kwargs()
+                if isinstance(train_loader, DataLoader):
+                    # preserve the user's shuffle intent (PTL-style replacement)
+                    train_loader._inject_sampler(shuffle=train_loader.shuffle,
+                                                 **kwargs)
+                if isinstance(self._val_loader, DataLoader):
+                    self._val_loader._inject_sampler(shuffle=False, **kwargs)
 
-        # state init / restore
-        if live_resume:
-            # continue from the LIVE state (a prior fit, possibly after
-            # resize_in_memory): no fresh TrainState, no disk read —
-            # self._tx is kept because the live opt_state was built
-            # against it
-            state = self._state
-        else:
-            rng = rng_from_seed(self.seed)
-            init_rng, state_rng = jax.random.split(rng)
-            self._tx = self._build_tx(module)
-            # a module that already carries weights (prior fit / manual
-            # load) continues from them -- the reference's re-hydrated
-            # driver model behaves the same way on a second fit
-            # (ray_ddp.py:185-189)
-            init_params = (module.params if module.params is not None
-                           else module.init_params(init_rng))
-            state = TrainState.create(init_params, self._tx, state_rng)
-            if self.grad_compression is not None:
-                residual, grad_accum = self._fresh_exchange_buffers(
-                    module, init_params, self._mesh)
-                state = state.replace(residual=residual,
-                                      grad_accum=grad_accum)
-        if self.guard is not None and \
-                getattr(state, "guard_ema", None) is None:
-            # fresh guard vector; a restore below reconciles against this
-            # template (older guard-less checkpoints keep it fresh)
-            state = state.replace(
-                guard_ema=jnp.asarray(guardian_lib.fresh_state()))
-        for c in self.callbacks:
-            c.setup(self, module, "fit")
-        if not live_resume:
-            if ckpt_path == "last":
-                # crash-recovery anchor: resume from the newest
-                # checkpoint under the run dir, or start fresh when none
-                # exists yet (capability the reference lacks, SURVEY.md
-                # §5.4)
-                ckpt_path = ckpt_lib.latest_checkpoint(
-                    self.default_root_dir)
-                if ckpt_path is None:
-                    log.warning("ckpt_path='last': no checkpoint under "
-                                "%s; starting fresh",
-                                self.default_root_dir)
-            if ckpt_path is not None:
-                with self._perf_phase("ckpt"):  # restore cost is a phase
-                    state = self._restore(ckpt_path, state)
-                if self.guard is not None and \
-                        getattr(state, "guard_ema", None) is not None:
-                    # a restore (including the guardian's own rewind)
-                    # restarts the guard fresh: a sticky trip that was
-                    # checkpointed must not re-raise on the first post-
-                    # rewind readback
-                    state = state.replace(
-                        guard_ema=jnp.asarray(guardian_lib.fresh_state()))
+            # state init / restore
+            if live_resume:
+                # continue from the LIVE state (a prior fit, possibly after
+                # resize_in_memory): no fresh TrainState, no disk read —
+                # self._tx is kept because the live opt_state was built
+                # against it
+                state = self._state
+            else:
+                rng = rng_from_seed(self.seed)
+                init_rng, state_rng = jax.random.split(rng)
+                self._tx = self._build_tx(module)
+                # a module that already carries weights (prior fit / manual
+                # load) continues from them -- the reference's re-hydrated
+                # driver model behaves the same way on a second fit
+                # (ray_ddp.py:185-189)
+                init_params = (module.params if module.params is not None
+                               else module.init_params(init_rng))
+                state = TrainState.create(init_params, self._tx, state_rng)
+                if self.grad_compression is not None:
+                    residual, grad_accum = self._fresh_exchange_buffers(
+                        module, init_params, self._mesh)
+                    state = state.replace(residual=residual,
+                                          grad_accum=grad_accum)
+            if self.guard is not None and \
+                    getattr(state, "guard_ema", None) is None:
+                # fresh guard vector; a restore below reconciles against this
+                # template (older guard-less checkpoints keep it fresh)
+                state = state.replace(
+                    guard_ema=jnp.asarray(guardian_lib.fresh_state()))
+            for c in self.callbacks:
+                c.setup(self, module, "fit")
+            if not live_resume:
+                if ckpt_path == "last":
+                    # crash-recovery anchor: resume from the newest
+                    # checkpoint under the run dir, or start fresh when none
+                    # exists yet (capability the reference lacks, SURVEY.md
+                    # §5.4)
+                    ckpt_path = ckpt_lib.latest_checkpoint(
+                        self.default_root_dir)
+                    if ckpt_path is None:
+                        log.warning("ckpt_path='last': no checkpoint under "
+                                    "%s; starting fresh",
+                                    self.default_root_dir)
+                if ckpt_path is not None:
+                    with self._perf_phase("ckpt"):  # restore cost is a phase
+                        state = self._restore(ckpt_path, state)
+                    if self.guard is not None and \
+                            getattr(state, "guard_ema", None) is not None:
+                        # a restore (including the guardian's own rewind)
+                        # restarts the guard fresh: a sticky trip that was
+                        # checkpointed must not re-raise on the first post-
+                        # rewind readback
+                        state = state.replace(
+                            guard_ema=jnp.asarray(guardian_lib.fresh_state()))
 
-        example_batch = next(iter(train_loader))
-        self._example_batch = example_batch
-        self._check_batch(example_batch)
-        self._build_device_cache(train_loader)
-        self._compile(module, state, example_batch)
+        with self._setup_span("setup_data"):
+            example_batch = next(iter(train_loader))
+            self._example_batch = example_batch
+            self._check_batch(example_batch)
+            self._build_device_cache(train_loader)
+        # the jitted step programs are MADE here; jax traces, lowers and
+        # compiles (or loads) them inside the first epoch's dispatch
+        with self._setup_span("setup_build"):
+            self._compile(module, state, example_batch)
 
-        # place state on mesh with its shardings
-        state = jax.device_put(state, self._state_shardings)
-        self._state = state
-        if self.perf is not None:
-            self._register_hbm_pools()
+        with self._setup_span("setup_place"):
+            # place state on mesh with its shardings
+            state = jax.device_put(state, self._state_shardings)
+            self._state = state
+            if self.perf is not None:
+                self._register_hbm_pools()
 
-        for c in self.callbacks:
-            c.on_fit_start(self, module)
+            for c in self.callbacks:
+                c.on_fit_start(self, module)
 
-        # optional sanity val steps (reference Tune callback skips these,
-        # ray_lightning/tune.py:79-81)
-        if self.num_sanity_val_steps and self._val_loader is not None:
-            self.sanity_checking = True
-            self._run_eval(self._val_loader, self._eval_step_fn,
-                           limit=self.num_sanity_val_steps, prefix=None)
-            self.sanity_checking = False
+            # optional sanity val steps (reference Tune callback skips these,
+            # ray_lightning/tune.py:79-81)
+            if self.num_sanity_val_steps and self._val_loader is not None:
+                self.sanity_checking = True
+                self._run_eval(self._val_loader, self._eval_step_fn,
+                               limit=self.num_sanity_val_steps, prefix=None)
+                self.sanity_checking = False
 
         train_metrics: Dict[str, Any] = {}
         use_scan = self._can_scan_epoch()
         self._epoch_host = {}
+        self._fit_setup["loop_t0"] = time.perf_counter()
+        self._epoch_mono = time.monotonic()
         while not self._done():
             for c in self.callbacks:
                 c.on_train_epoch_start(self, module)
@@ -2766,7 +2786,8 @@ class Trainer:
                     kind, payload = ("cached",
                                      self._put_index_row(payload))
             if kind == "cached":
-                with self._span("train_step", phase="compute") as h:
+                with compile_guard.phase("train_step"), \
+                        self._span("train_step", phase="compute") as h:
                     state, train_metrics = self._train_step_cached_fn(
                         state, self._device_cache, payload)
                     if h is not None:
@@ -2777,7 +2798,8 @@ class Trainer:
                         batch = self._put_batch(payload)
                 else:
                     batch = payload  # placed by the pipeline
-                with self._span("train_step", phase="compute") as h:
+                with compile_guard.phase("train_step"), \
+                        self._span("train_step", phase="compute") as h:
                     state, train_metrics = self._train_step_fn(
                         state, batch)
                     if h is not None:
@@ -2830,7 +2852,8 @@ class Trainer:
         if run_val:
             for c in self.callbacks:
                 c.on_validation_start(self, module)
-            with self._span("validation", phase="validation"):
+            with compile_guard.phase("validation"), \
+                    self._span("validation", phase="validation"):
                 val_metrics = self._run_eval(self._val_loader,
                                              self._eval_step_fn,
                                              limit=self.limit_val_batches,
@@ -2862,9 +2885,20 @@ class Trainer:
         # host floats only (the emit path stays sync-free): where the
         # epoch's host time went -- plan_s / dispatch_s / log_s on the
         # scanned path, readback_s (device wait) and callbacks_s on both
+        fields = {k: round(v, 6) for k, v in host_s.items()}
+        # the programs jax compiled or loaded during this epoch, by name:
+        # the step programs in a fit's first epoch, nothing in a healthy
+        # later one (the field is then left out)
+        now = time.monotonic()
+        compiled = [row["name"] for row in compile_guard.ledger(
+            since=self._epoch_mono, until=now) if row["cache"]]
+        self._epoch_mono = now
+        if compiled:
+            fields["compiled"] = compiled[:COMPILED_NAMES]
         telemetry.emit("epoch_end", epoch=self.current_epoch,
-                       step=self.global_step,
-                       **{k: round(v, 6) for k, v in host_s.items()})
+                       step=self.global_step, **fields)
+        if self._fit_setup is not None:
+            self._emit_fit_ready()
         if self.enable_progress_bar:
             log.warning("epoch %d done (step %d) metrics=%s",
                         self.current_epoch, self.global_step,
@@ -2877,7 +2911,8 @@ class Trainer:
         early stopping / Tune reporting see mid-epoch metrics."""
         for c in self.callbacks:
             c.on_validation_start(self, module)
-        with self._span("validation", phase="validation"):
+        with compile_guard.phase("validation"), \
+                self._span("validation", phase="validation"):
             val_metrics = self._run_eval(self._val_loader,
                                          self._eval_step_fn,
                                          limit=self.limit_val_batches,
@@ -2889,42 +2924,70 @@ class Trainer:
             c.on_validation_end(self, module)
 
     @contextmanager
-    def _epoch_span(self, name: str, key: str):
-        """One host phase of an epoch: a ``fit/<name>`` profiler span
-        (a null context without a profiler) and, always, one
-        perf_counter pair summed into the ``epoch_end`` event's
-        ``<key>`` field."""
+    def _host_span(self, name: str, book: Dict[str, float], key: str):
+        """One host phase of a fit: a ``fit/<name>`` span (_span), the
+        compile ledger's open phase and, always, one perf_counter pair
+        summed into ``book[key]``."""
         t0 = time.perf_counter()
         try:
-            with self._span("fit/" + name):
+            with compile_guard.phase(name), self._span("fit/" + name):
                 yield
         finally:
-            self._epoch_host[key] = (self._epoch_host.get(key, 0.0)
-                                     + time.perf_counter() - t0)
+            book[key] = book.get(key, 0.0) + time.perf_counter() - t0
+
+    def _epoch_span(self, name: str, key: str):
+        """A phase of the running epoch: its seconds go into the
+        ``epoch_end`` event's ``<key>`` field."""
+        return self._host_span(name, self._epoch_host, key)
+
+    def _setup_span(self, name: str):
+        """A start-up phase of the fit: its seconds go into the
+        ``fit_ready`` event's ``<name>_s`` field."""
+        return self._host_span(name, self._fit_setup, name + "_s")
+
+    def _emit_fit_ready(self) -> None:
+        """The fit's start-up ledger, once, where its first epoch ends:
+        ``fit_start`` (time.monotonic() at the fit's entry), the four
+        ``setup_*`` phases' seconds, the first epoch's wall seconds
+        (first execution: jax traces, lowers and compiles or loads the
+        step programs inside its dispatch) and, by phase, the compile
+        ledger's summary of every program since ``fit_start``.  Host
+        floats and strings only."""
+        setup, self._fit_setup = self._fit_setup, None
+        first_epoch_s = time.perf_counter() - setup.pop("loop_t0")
+        by_phase: Dict[str, list] = {}
+        for row in compile_guard.ledger(since=setup["fit_start"]):
+            by_phase.setdefault(row["phase"] or "other", []).append(row)
+        telemetry.emit(
+            "fit_ready", first_epoch_s=round(first_epoch_s, 6),
+            compile={phase: {k: round(v, 6) for k, v in
+                             compile_guard.summary(rows).items()}
+                     for phase, rows in by_phase.items()},
+            **{k: round(v, 6) for k, v in setup.items()})
 
     def _span(self, name: str, phase: Optional[str] = None):
-        """Profiler span, or a null context when no profiler is attached
-        (XLA async dispatch makes spans the only honest timing surface --
-        SURVEY.md §5.1 build note).  ``phase`` additionally feeds the
-        perf observatory's step timeline (one extra perf_counter pair —
-        the <50us/emit budget the overhead test pins)."""
+        """Profiler span; without a profiler a bare ``rla:<name>``
+        annotation, so the program's host spans stand in ANY trace taken
+        of the process (an annotation costs a null context's time while
+        no trace runs).  XLA async dispatch makes spans the only honest
+        timing surface -- SURVEY.md §5.1 build note.  ``phase``
+        additionally feeds the perf observatory's step timeline (one
+        extra perf_counter pair — the <50us/emit budget the overhead
+        test pins)."""
         tl = self.perf.timeline if self.perf is not None else None
         if tl is None or phase is None:
             if self.profiler is not None:
                 return self.profiler.span(name)
-            import contextlib
-            return contextlib.nullcontext()
+            return HostSpan(name)
         return self._phased_span(name, tl, phase)
 
     @contextmanager
     def _phased_span(self, name: str, tl, phase: str):
         t0 = time.perf_counter()
         try:
-            if self.profiler is not None:
-                with self.profiler.span(name) as h:
-                    yield h
-            else:
-                yield None
+            with (self.profiler.span(name) if self.profiler is not None
+                  else HostSpan(name)) as h:
+                yield h
         finally:
             tl.observe(phase, time.perf_counter() - t0)
 
@@ -2938,12 +3001,9 @@ class Trainer:
 
     def _iter_profiled(self, loader):
         """Iterate a loader, timing each fetch under a 'data_fetch' span."""
-        if self.profiler is None:
-            yield from loader
-            return
         it = iter(loader)
         while True:
-            with self.profiler.span("data_fetch"):
+            with self._span("data_fetch"):
                 try:
                     batch = next(it)
                 except StopIteration:

@@ -73,6 +73,10 @@ EVENT_KINDS = frozenset({
     # trainer (core/trainer.py)
     "fit_start", "fit_end", "train_step", "epoch_end", "validation",
     "preempt_drain", "emergency_checkpoint",
+    # the fit's start-up ledger, once, where its first epoch ends: the
+    # four setup_* phases' seconds, the first epoch's, and the compile
+    # ledger's summary by phase (core/trainer.py::_emit_fit_ready)
+    "fit_ready",
     # input pipeline (data/prefetch.py)
     "prefetch_starved",
     # sharding resolution (accelerators/base.py): a large param leaf (or
@@ -127,6 +131,12 @@ def mint_trace_id() -> str:
     return secrets.token_hex(8)
 
 
+# kinds whose NEWEST event stays reachable through ``last(kind)`` after
+# the ring has rolled over it: what a fit was, for a reader that comes
+# when the run is hours in.  One event a kind, so bounded like the ring.
+PINNED_KINDS = frozenset({"fit_start", "fit_ready"})
+
+
 class FlightRecorder:
     """Bounded ring of structured events for ONE process.
 
@@ -150,6 +160,7 @@ class FlightRecorder:
         self.spill_min_s = max(0.0, float(spill_min_s))
         self.enabled = enabled
         self._ring: deque = deque(maxlen=self.capacity)
+        self._pinned: Dict[str, tuple] = {}     # kind -> newest event
         self._lock = threading.Lock()
         self._spill_lock = threading.Lock()
         self._last_spill = float("-inf")  # first emit always spills
@@ -170,8 +181,19 @@ class FlightRecorder:
                data or None)
         with self._lock:
             self._ring.append(evt)
+            if kind in PINNED_KINDS:
+                self._pinned[kind] = evt
         if self.spill_path is not None:
             self._maybe_spill()
+
+    @staticmethod
+    def _as_dict(evt: tuple) -> Dict[str, Any]:
+        ts, rank, kind, trace, data = evt
+        row: Dict[str, Any] = {"ts": round(ts, 6), "rank": rank,
+                               "kind": kind, "trace": trace}
+        if data:
+            row["data"] = dict(data)
+        return row
 
     def events(self, last_n: Optional[int] = None) -> List[Dict[str, Any]]:
         """The ring's events as JSON-able dicts, oldest first."""
@@ -179,14 +201,19 @@ class FlightRecorder:
             evts = list(self._ring)
         if last_n is not None:
             evts = evts[-last_n:]
-        out = []
-        for ts, rank, kind, trace, data in evts:
-            row: Dict[str, Any] = {"ts": round(ts, 6), "rank": rank,
-                                   "kind": kind, "trace": trace}
-            if data:
-                row["data"] = dict(data)
-            out.append(row)
-        return out
+        return [self._as_dict(e) for e in evts]
+
+    def last(self, kind: str) -> Optional[Dict[str, Any]]:
+        """The newest event of ``kind``, or None.  For a pinned kind
+        (``PINNED_KINDS``: ``fit_start``, ``fit_ready``) it is found even
+        after the ring has rolled over it; any other kind is looked for
+        in the ring."""
+        with self._lock:
+            evt = self._pinned.get(kind)
+            if evt is None:
+                evt = next((e for e in reversed(self._ring)
+                            if e[2] == kind), None)
+        return None if evt is None else self._as_dict(evt)
 
     def tail(self, n: int = EMBED_TAIL_N,
              kind: Optional[str] = None) -> List[Dict[str, Any]]:
@@ -218,6 +245,7 @@ class FlightRecorder:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._pinned.clear()
         self._last_spill = float("-inf")
 
     def snapshot(self, last_n: Optional[int] = None) -> Dict[str, Any]:

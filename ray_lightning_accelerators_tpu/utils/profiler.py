@@ -39,6 +39,27 @@ TRACE_PREFIX = "rla:"
 SCOPES_FILE = "scopes.json"
 
 
+class HostSpan:
+    """A bare ``rla:<name>`` annotation: the program's host span in
+    whatever profiler trace is being taken of the process, for callers
+    that hold no ``Profiler`` (no statistics, no nesting path).  Enters
+    as ``None``, where ``Profiler.span`` gives a handle.  While no trace
+    runs it costs what a null context costs."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str):
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(TRACE_PREFIX + name)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+
+
 class _SpanHandle:
     """Mutable holder for a span's device outputs (see Profiler.span)."""
 

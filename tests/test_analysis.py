@@ -521,6 +521,173 @@ def test_compile_guard_counts_and_budgets():
             raise RuntimeError("inner")
 
 
+# --------------------------------------------------------------------- #
+# the compile ledger: every program by name                             #
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """jax's persistent compilation cache in ``tmp_path`` for one test
+    (the suite runs with it off, tests/conftest.py), the two persistence
+    thresholds at zero; ``persistent_cache(default=True)`` puts jax's
+    defaults back.  Everything is restored afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+
+    def thresholds(default=False):
+        jax.config.update(keys[2], 1.0 if default else 0.0)
+        jax.config.update(keys[3], 0)
+
+    jax.config.update(keys[0], True)
+    jax.config.update(keys[1], str(tmp_path))
+    thresholds()
+    cc.reset_cache()
+    try:
+        yield thresholds
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def _fresh_program(name, shape):
+    """A new jitted function every call (same name, same program): jax's
+    in-memory caches do not know it, the persistent cache's key does."""
+    def fn(x):
+        return jnp.tanh(x) * 3 + 1
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn), jnp.ones(shape)
+
+
+def test_compile_ledger_tells_miss_hit_and_small_apart(persistent_cache):
+    from ray_lightning_accelerators_tpu.analysis import compile_guard as cg
+    cg.install()
+    x = jnp.ones((3, 41))       # its own small programs, before the clock
+    t0, c0 = time.monotonic(), compile_count()
+    for _ in range(2):          # compiled and written, then loaded
+        f, _ = _fresh_program("ledger_probe", (3, 41))
+        f(x)
+    persistent_cache(default=True)      # under jax's 1 s: never written
+    for _ in range(2):
+        g, _ = _fresh_program("ledger_small_probe", (5, 43))
+        g(jnp.zeros((5, 43)) + x[0, 0])
+    rows = cg.ledger(since=t0)
+    probe = [r for r in rows if r["name"] == "jit(ledger_probe)"]
+    small = [r for r in rows if r["name"] == "jit(ledger_small_probe)"]
+    assert [r["cache"] for r in probe] == ["miss", "hit"], rows
+    assert [r["cache"] for r in small] == ["small", "small"], rows
+    hit = probe[1]
+    assert hit["retrieval_s"] > 0 and 0 < hit["backend_s"] < 1.0
+    assert probe[0]["retrieval_s"] == 0.0
+    for r in probe + small:     # host floats and strings only
+        assert r["trace_s"] > 0 and r["lower_s"] > 0 and r["backend_s"] > 0
+        assert r["start"] == pytest.approx(r["end"] - r["backend_s"])
+        assert t0 <= r["start"] <= r["end"] <= time.monotonic()
+        assert r["thread"] == "MainThread" and r["phase"] is None
+        assert all(isinstance(v, (float, str, type(None)))
+                   for v in r.values())
+    s = cg.summary(probe + small)
+    assert (s["programs"], s["built"], s["loaded"], s["missed"]) == (
+        4, 3, 1, 1)
+    assert s["backend_s"] == pytest.approx(
+        sum(r["backend_s"] for r in probe + small))
+    assert s["retrieval_s"] == hit["retrieval_s"]
+    # the counter keeps its meaning: every backend event, loads included
+    assert compile_count() - c0 == len([r for r in rows if r["cache"]])
+    assert cg.ledger(since=t0, until=probe[1]["end"]) == [
+        r for r in rows if r["end"] < probe[1]["end"]]
+
+
+def test_compile_ledger_nesting_phase_and_uncached():
+    """The suite's own setting: no persistent cache, so every program
+    reads ``uncached``.  A jitted function traced inside another is no
+    row of its own, and a row carries the innermost open phase."""
+    from ray_lightning_accelerators_tpu.analysis import compile_guard as cg
+    cg.install()
+
+    @jax.jit
+    def ledger_inner(x):
+        return x @ x.T
+
+    def ledger_outer(x):
+        return ledger_inner(x).sum() + ledger_inner(x * 2).sum()
+
+    x = jnp.ones((7, 47))
+    t0 = time.monotonic()
+    with cg.phase("outer_phase"):
+        with cg.phase("inner_phase"):
+            jax.jit(ledger_outer)(x)
+        h, _ = _fresh_program("ledger_lowered", (7, 47))
+        h.lower(x)                              # traced, lowered, not built
+        f, y = _fresh_program("ledger_phase_probe", (7, 47))
+        f(y)
+    f2, y = _fresh_program("ledger_phase_probe", (7, 47))
+    f2(y)
+    rows = cg.ledger(since=t0)
+    by_name = {}
+    for r in rows:
+        by_name.setdefault(r["name"], []).append(r)
+    assert not any("ledger_inner" in name for name in by_name), by_name
+    (built,), (lowered,) = (by_name["jit(ledger_outer)"],
+                            by_name["jit(ledger_lowered)"])
+    assert built["cache"] == "uncached" and built["phase"] == "inner_phase"
+    assert built["trace_s"] > 0 and built["backend_s"] > 0
+    assert lowered["cache"] is None and lowered["backend_s"] == 0.0
+    assert lowered["lower_s"] > 0 and lowered["phase"] == "outer_phase"
+    assert [r["phase"] for r in by_name["jit(ledger_phase_probe)"]] == [
+        "outer_phase", None]
+    s = cg.summary(rows)
+    assert s["built"] == len([r for r in rows if r["cache"] == "uncached"])
+    assert s["loaded"] == s["missed"] == 0
+    assert s["programs"] == len(rows) > s["built"]
+
+
+def test_compile_ledger_is_bounded_and_counts_what_it_drops():
+    from ray_lightning_accelerators_tpu.analysis import compile_guard as cg
+    cg.install()
+    cg._reset_ledger_for_tests()
+    c0 = compile_count()
+    try:
+        for i in range(cg.LEDGER_ROWS + 76):    # jax's own three calls
+            name = f"jit(fake_{i})"
+            cg._on_scalar(cg.TRACE_EVENT, 0.0, fun_name=f"fake_{i}")
+            cg._on_event_duration(cg.TRACE_EVENT, 1e-4, fun_name=f"fake_{i}")
+            cg._on_scalar(cg.BACKEND_COMPILE_EVENT, 0.0, fun_name=name)
+            cg._on_event(cg.CACHE_REQUEST_EVENT)
+            cg._on_event_duration(cg.BACKEND_COMPILE_EVENT, 1e-3,
+                                  fun_name=name)
+        rows = cg.ledger()
+        assert len(rows) == cg.LEDGER_ROWS and cg.ledger_dropped() == 76
+        assert rows[0]["name"] == "jit(fake_76)"     # the newest are kept
+        assert rows[-1]["name"] == f"jit(fake_{cg.LEDGER_ROWS + 75})"
+        assert {r["cache"] for r in rows} == {"small"}
+        assert [r["end"] for r in rows] == sorted(r["end"] for r in rows)
+        # the counter is not the ledger's: it counts on past the bound
+        assert compile_count() - c0 == cg.LEDGER_ROWS + 76
+    finally:
+        cg._reset_ledger_for_tests()
+    assert cg.ledger() == [] and cg.ledger_dropped() == 0
+
+
+def test_one_place_listens_to_jax_monitoring():
+    """One listener set a process: a second one (chip_smoke.py had its
+    own for cache hits) counts the same events apart from the ledger."""
+    repo = os.path.dirname(PKG_DIR)
+    files = [os.path.join(repo, f) for f in ("bench.py", "chip_smoke.py")]
+    for dirpath, _, names in os.walk(PKG_DIR):
+        files += [os.path.join(dirpath, n) for n in names
+                  if n.endswith(".py")]
+    hits = []
+    for path in files:
+        with open(path) as f:
+            if "monitoring.register_" in f.read():
+                hits.append(os.path.relpath(path, repo))
+    assert hits == [os.path.join("ray_lightning_accelerators_tpu",
+                                 "analysis", "compile_guard.py")]
+
+
 def test_serve_engine_program_count_invariant():
     """The PR 2 prose, enforced through the paging indirection: a
     staggered join/retire workload over one prompt bucket runs the

@@ -561,6 +561,114 @@ def test_recorder_on_zero_retraces_and_bounded_overhead(tmp_path):
     assert per_emit < 5e-5, f"emit costs {per_emit * 1e6:.1f}us"
 
 
+def _scanned_fit(tmp_path, **kw):
+    """A two-epoch fit on the scanned-epoch path (device-resident data,
+    epoch hooks only), no Profiler attached."""
+    from ray_lightning_accelerators_tpu import DataLoader, Trainer
+    from ray_lightning_accelerators_tpu.data.loader import RandomDataset
+    from tests.utils import BoringModel
+
+    trainer = Trainer(max_epochs=2, precision="f32", seed=0,
+                      enable_checkpointing=False, log_every_n_steps=1,
+                      default_root_dir=str(tmp_path),
+                      cache_dataset_on_device=True, **kw)
+    trainer.fit(BoringModel(),
+                DataLoader(RandomDataset(32, 64), batch_size=8))
+    assert trainer._can_scan_epoch()
+    return trainer
+
+
+def test_fit_ready_is_the_start_up_ledger_of_a_fit(tmp_path):
+    """One ``fit_ready`` a fit, where its first epoch ends: the four
+    start-up phases and the first epoch, positive, inside fit_start ->
+    the first ``epoch_end`` on the recorder's clock; the first epoch
+    names the programs jax compiled in it, a healthy later one none;
+    and the event outlives the ring."""
+    _scanned_fit(tmp_path)
+    rec = R.get_recorder()
+    events = rec.events()
+    ready = [e for e in events if e["kind"] == "fit_ready"]
+    ends = [e for e in events if e["kind"] == "epoch_end"]
+    assert len(ready) == 1 and len(ends) == 2
+    assert "fit_ready" in R.EVENT_KINDS
+    data = ready[0]["data"]
+    phases = ("setup_init_s", "setup_data_s", "setup_build_s",
+              "setup_place_s", "first_epoch_s")
+    assert all(isinstance(data[k], float) and data[k] > 0 for k in phases)
+    start = [e for e in events if e["kind"] == "fit_start"][0]
+    assert data["fit_start"] <= start["ts"] <= ends[0]["ts"]
+    assert ends[0]["ts"] <= ready[0]["ts"] < ends[1]["ts"]
+    # the phases are disjoint stretches of fit's entry -> first epoch_end
+    assert sum(data[k] for k in phases) <= (
+        ends[0]["ts"] - data["fit_start"]) + 1e-3
+    # the step program is MADE in setup_build and compiled in the first
+    # epoch's dispatch: that is what the ledger's phases say
+    assert data["setup_build_s"] < data["first_epoch_s"]
+    compiled = ends[0]["data"]["compiled"]
+    assert "jit(scanned_epoch)" in compiled
+    assert "compiled" not in ends[1]["data"]
+    by_phase = data["compile"]
+    assert by_phase["epoch_dispatch"]["built"] >= 1
+    assert by_phase["epoch_dispatch"]["backend_s"] > 0
+    # (a process that has fitted before compiles nothing in setup_init)
+    assert set(by_phase) <= {
+        "setup_init", "setup_data", "setup_build", "setup_place",
+        "epoch_plan", "epoch_dispatch", "epoch_readback", "log_replay",
+        "callbacks", "other"}, by_phase
+    assert set(by_phase["epoch_dispatch"]) == {
+        "programs", "built", "loaded", "missed", "trace_s", "lower_s",
+        "backend_s", "retrieval_s"}
+    json.dumps(events)      # host floats, strings, lists and dicts only
+    for i in range(300):    # the ring (256) rolls over both
+        rec.emit("train_step", step=i)
+    assert not [e for e in rec.events()
+                if e["kind"] in ("fit_start", "fit_ready")]
+    assert rec.last("fit_ready") == ready[0]
+    assert rec.last("fit_start") == start
+    assert rec.last("train_step")["data"] == {"step": 299}    # from the ring
+    assert rec.last("validation") is None
+    rec.clear()
+    assert rec.last("fit_ready") is None
+
+
+def test_host_spans_reach_a_trace_without_a_profiler(tmp_path):
+    """A trace taken of the process by ANYONE (here: jax.profiler, as
+    the benchmark does) holds the fit's phases as ``rla:fit/*`` on the
+    host plane, with no ``Profiler`` attached to the Trainer."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"),
+                             profiler_options=options)
+    try:
+        trainer = _scanned_fit(tmp_path / "fit")
+    finally:
+        jax.profiler.stop_trace()
+    assert trainer.profiler is None
+    found = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    assert found
+    host = [p for p in ProfileData.from_file(found[-1]).planes
+            if p.name == "/host:CPU"]
+    if not host:
+        pytest.skip("this jax's CPU profiler writes no /host:CPU plane")
+    names = {}
+    for line in host[0].lines:
+        for e in line.events:
+            if e.name.startswith("rla:"):
+                names[e.name] = names.get(e.name, 0) + 1
+    assert names.get("rla:fit/epoch_dispatch") == 2, names
+    for phase in ("setup_init", "setup_data", "setup_build", "setup_place"):
+        assert names.get("rla:fit/" + phase) == 1, names
+    for phase in ("epoch_plan", "log_replay", "callbacks"):
+        assert names.get("rla:fit/" + phase) == 2, names
+    assert names.get("rla:fit/epoch_readback", 0) >= 2, names
+
+
 def test_fit_failure_writes_run_report(tmp_path):
     """Any uncaught fit exception leaves a run_report.json under the run
     dir — with the typed error and the driver timeline — and re-raises
