@@ -216,15 +216,27 @@ def _rows_to_tokens(rows, pairs, top_k: int, t: int):
         rows.dtype)
 
 
+def _rows_of_pairs(ys, pos, top_k: int):
+    """[top_k, t, d]: the row at every pair's (clipped) position, the
+    choices on the LEADING axis (``pos`` is choice-major).  Each choice
+    is a slab of ``t`` whole rows, so the split of the gathered
+    ``[top_k * t, d]`` moves nothing and the sum over the choices reads
+    the buffer as it was written; with the choices second-minor
+    (``[t, top_k, d]``: 4 rows against an 8- or 16-row tile) the chip
+    copies it into another tiling first."""
+    return ys[pos].reshape(top_k, -1, ys.shape[1])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _dispatch_rows(x, pairs, pos, live, top_k, scatter):
     """Row ``pairs[i] // top_k`` of ``x`` for the sorted pairs of one
     window.  Every (token, choice) pair sits at one sorted position, so
-    the transpose is a gather by that position (``pos``, clipped to the
-    window; ``live`` where the window holds the pair) and a sum over the
-    choices, not a scatter-add -- unless ``scatter`` says the window is
-    a small part of the pairs (``_token_side_by_scatter``): then the
-    window's rows are added into their tokens."""
+    the transpose is a gather by that position (``pos`` ``[top_k * t]``,
+    choice-major and clipped to the window; ``live`` where the window
+    holds the pair) and a sum over the choices, not a scatter-add --
+    unless ``scatter`` says the window is a small part of the pairs
+    (``_token_side_by_scatter``): then the window's rows are added into
+    their tokens."""
     return x[pairs // top_k]
 
 
@@ -236,31 +248,28 @@ def _dispatch_bwd(top_k, scatter, res, g):
     pairs, pos, live, t = res
     if scatter:
         return _rows_to_tokens(g, pairs, top_k, t), None, None, None
-    g = jnp.where(live[:, None], g[pos], 0)
-    return (g.reshape(t, top_k, -1).sum(1).astype(g.dtype), None, None,
-            None)
+    g = jnp.where(live.reshape(top_k, t, 1), _rows_of_pairs(g, pos, top_k),
+                  0)
+    return g.sum(0).astype(g.dtype), None, None, None
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _rows_of_pairs(ys, w, pos):
-    """[t, top_k, d]: the row at every pair's (clipped) position."""
-    return ys[pos].reshape(*w.shape, -1)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _combine_rows(ys, w, pairs, pos, scatter):
-    """``y[t] = sum_k w[t, k] * ys[pos[t * top_k + k]]``: the window's
-    output rows back at their tokens (``w`` is zero where the window
-    does not hold the pair), gathered by the token side, or with
+    """``y[i] = sum_k w[k, i] * ys[pos[k * t + i]]``: the window's
+    output rows back at their ``t`` tokens (``w`` ``[top_k, t]`` and
+    ``pos`` ``[top_k * t]``, choice-major; ``w`` is zero where the
+    window does not hold the pair), gathered by the token side, or with
     ``scatter`` weighted on the sorted side and added into their tokens.
-    Transposed on the sorted side: ``d ys[i] = w[pair i] * g[token of
-    pair i]``, a gather of the window's rows from ``[t, d]``."""
+    Transposed on the sorted side, which keeps token-major pair ids:
+    ``d ys[i] = w[pair i] * g[token of pair i]``, a gather of the
+    window's rows from ``[t, d]``."""
     if scatter:
-        return _rows_to_tokens(w.reshape(-1)[pairs][:, None] * ys, pairs,
-                               w.shape[1], w.shape[0])
-    return jnp.einsum("tk,tkd->td", w, _rows_of_pairs(ys, w, pos))
+        return _rows_to_tokens(w.T.reshape(-1)[pairs][:, None] * ys, pairs,
+                               *w.shape)
+    return jnp.einsum("kt,ktd->td", w, _rows_of_pairs(ys, pos, len(w)))
 
 
 def _combine_fwd(ys, w, pairs, pos, scatter):
@@ -269,13 +278,13 @@ def _combine_fwd(ys, w, pairs, pos, scatter):
 
 def _combine_bwd(scatter, res, g):
     ys, w, pairs, pos = res
-    g_rows = g[pairs // w.shape[1]]
-    d_ys = w.reshape(-1)[pairs][:, None] * g_rows
+    g_rows = g[pairs // w.shape[0]]
+    d_ys = w.T.reshape(-1)[pairs][:, None] * g_rows
     if scatter:     # each pair's product on the sorted side, then home
         d_w = jnp.zeros((w.size,), w.dtype).at[pairs].add(jnp.sum(
-            g_rows.astype(w.dtype) * ys, -1)).reshape(w.shape)
+            g_rows.astype(w.dtype) * ys, -1)).reshape(w.shape[::-1]).T
     else:
-        d_w = jnp.einsum("td,tkd->tk", g, _rows_of_pairs(ys, w, pos))
+        d_w = jnp.einsum("td,ktd->kt", g, _rows_of_pairs(ys, pos, len(w)))
     return d_ys, d_w, None, None
 
 
@@ -399,15 +408,18 @@ def _window(diff, ints, start, *, m: int, top_k: int, mesh):
     and what those rows add to every token: ``(y [t, d], pairs whose
     output row came back)``.
 
-    diff: ``rows`` [t, d], ``w`` [t, top_k] (zero for a pair whose
+    diff: ``rows`` [t, d], ``w`` [top_k, t] (zero for a pair whose
     expert is absent), then the experts' weights, all in the compute
     dtype but ``w``: ``w1`` / ``w3`` / ``w2`` are SwiGLU's three
     products, ``w1`` / ``w2`` alone ReLU squared's two.  ints:
-    ``order`` (the pairs by sorted position, at
-    least ``start + m`` long), ``inverse`` (the sorted position of every
-    pair), ``first`` / ``last`` (each held expert's interval of sorted
-    positions), ``n_rows`` (pairs whose expert is held: they sort
-    first)."""
+    ``order`` (the pairs, ``token * top_k + choice``, by sorted
+    position, at least ``start + m`` long), ``inverse`` [top_k * t] (the
+    sorted position of every pair, pair ``(token, choice)`` at ``choice
+    * t + token``), ``first`` / ``last`` (each held expert's interval of
+    sorted positions), ``n_rows`` (pairs whose expert is held: they sort
+    first).  What is indexed by token (``w``, ``inverse`` and the
+    ``pos`` / ``live`` made of it) is choice-major; the sorted side
+    keeps the token-major pair ids."""
     rows, w, w_up, *w_gate, w_down = diff
     order, inverse, first, last, n_rows = ints
     t, dt = rows.shape[0], rows.dtype
@@ -427,7 +439,8 @@ def _window(diff, ints, start, *, m: int, top_k: int, mesh):
         # the pad) are never visited by the grouped matmuls: whatever
         # the buffer held is masked on the way out, and by the mask's
         # transpose on the way back, so neither direction ever reads it
-        computed = (jnp.arange(m + pad) < jnp.sum(group_sizes))[:, None]
+        n_computed = jnp.sum(group_sizes)
+        computed = (jnp.arange(m + pad) < n_computed)[:, None]
 
         def masked(a):
             return jnp.where(computed, a, 0)
@@ -444,11 +457,13 @@ def _window(diff, ints, start, *, m: int, top_k: int, mesh):
                                    mesh))[:m]
     with jax.named_scope("gpt/moe_combine"):
         y = _combine_rows(
-            ys, jnp.where(live.reshape(t, top_k), w, 0.0).astype(dt),
+            ys, jnp.where(live.reshape(top_k, t), w, 0.0).astype(dt),
             pairs, pos, scatter)
         # live: from the selection's count; computed: from the groups
-        # the matmuls were given.  They agree unless a window is cut
-        came_back = jnp.sum(live & computed[pos, 0], dtype=jnp.int32)
+        # the matmuls were given.  They agree unless a window is cut.
+        # ``computed`` is a prefix of the window, so what it holds at a
+        # pair's position is a comparison, not a gather of every pair
+        came_back = jnp.sum(live & (pos < n_computed), dtype=jnp.int32)
     return y, came_back
 
 
@@ -537,10 +552,12 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
 
     Returns ``(y, stats)`` with ``rows_routed`` (pairs whose expert is
     held, counted from the selection), ``rows_computed`` (pairs whose
-    output row came back from the grouped matmuls: the mask of the rows
-    they visit, carried through the same cut and the same inverse
-    permutation as their result, summed over the windows that ran; fewer
-    than ``rows_routed`` if a window or a group were ever cut short),
+    output row came back from the grouped matmuls: the pairs a window
+    holds whose position is under the count of rows its groups own, by
+    comparison (the mask of the visited rows is a prefix of the window),
+    on the same clipped positions that gather their result, summed over
+    the windows that ran; fewer than ``rows_routed`` if a window or a
+    group were ever cut short),
     ``rounds`` (the windows that ran: 1 unless the load was over
     ``WINDOW_SPARE`` times nominal), ``load_max_over_mean`` (the fullest
     held expert's rows over the mean) and ``selected`` ``[b, s,
@@ -568,11 +585,15 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array], *,
         w = jnp.where(is_held.reshape(t, top_k), w, 0.0)
         if n_held < num_experts:
             w = jax.lax.stop_gradient(w)
+        w = w.T             # the token side is choice-major: ``_window``
     m = window_rows(t * top_k, n_held, num_experts)
     n_windows = -(-t * top_k // m)
     with jax.named_scope("gpt/moe_dispatch"):
         order = jnp.argsort(slots, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
+        # choice-major, and flat: one transposition a layer, and no
+        # axis of ``top_k`` for a tiling to pad
+        inverse = jnp.argsort(order).astype(jnp.int32).reshape(
+            t, top_k).T.reshape(-1)
         group_sizes = jnp.sum(
             slots[:, None] == jnp.arange(n_held, dtype=jnp.int32),
             axis=0, dtype=jnp.int32)

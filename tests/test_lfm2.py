@@ -266,11 +266,46 @@ def _steered(both: int, one: int):
     return x, router
 
 
-@pytest.mark.parametrize("case,steer,rounds", [
-    ("balanced", None, 1), ("window_full", (156, 200), 1),
-    ("one_over", (156, 201), 2), ("adversarial", (1024, 0), 4),
-    ("all_held", None, 1)])
-def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds):
+def plan_of(selected, held, num_experts):
+    """The layer's plan from the chosen ids, in numpy and token-major:
+    ``inverse`` (the sorted position of pair ``token * top_k + choice``),
+    each held expert's ``first`` / ``last`` sorted position, and the
+    count of pairs whose expert is held."""
+    n_held = len(held)
+    slot_of = np.full((num_experts,), n_held, np.int64)
+    slot_of[list(held)] = np.arange(n_held)
+    slots = slot_of[np.asarray(selected).reshape(-1)]
+    inverse = np.argsort(np.argsort(slots, kind="stable"))
+    last = np.cumsum(np.bincount(slots, minlength=n_held + 1)[:n_held])
+    first = np.concatenate([[0], last[:-1]])
+    return inverse, first, last, int(last[-1])
+
+
+def came_back_by_gather(inverse, first, last, n_rows, m):
+    """``rows_computed`` as the layer counted it until PR 36: in every
+    window of ``m`` sorted rows that holds a routed row (one at least),
+    ``sum(live & computed[pos])``: the mask of the rows the grouped
+    matmuls visit, GATHERED at every pair's clipped position."""
+    total = 0
+    for start in range(0, max(n_rows, 1), m):
+        sizes = (np.clip(last, start, start + m)
+                 - np.clip(first, start, start + m))
+        computed = np.arange(m) < sizes.sum()
+        pos = inverse - start
+        live = (pos >= 0) & (pos < min(m, n_rows - start))
+        total += int(np.sum(live & computed[np.clip(pos, 0, m - 1)]))
+    return total
+
+
+_LOADS = [("balanced", None, 1), ("window_full", (156, 200), 1),
+          ("one_over", (156, 201), 2), ("adversarial", (1024, 0), 4)]
+
+
+@pytest.mark.parametrize("case,steer,rounds,check", [
+    *[(*load, "layer") for load in _LOADS],
+    ("all_held", None, 1, "layer"), ("all_held_top_4", None, 1, "layer"),
+    *[(*load, "counter") for load in _LOADS]])
+def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds, check):
     """The expert layer walks its sorted pairs in windows; whatever the
     load, output and every gradient are those of the one-buffer
     formulation and of the reference's layer, every routed pair's row
@@ -278,15 +313,21 @@ def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds):
     balanced load and at exactly a window's rows, two at one row more,
     all four under a router that sends every pair to the held experts
     (the capacity path would drop 7 of 8 there).  With every expert
-    held the window is the buffer and the program has no loop."""
-    top_k, num_experts = WINDOWED["top_k"], WINDOWED["num_experts"]
-    held = tuple(range(16)) if case == "all_held" else WINDOWED["held"]
+    held the window is the buffer and the program has no loop, and the
+    combine weights carry the router's gradient (``d_w`` over the
+    choice-major rows; at top-2 and at the cell's top-4).  ``counter``:
+    ``rows_computed``, which the layer makes by comparing positions, is
+    the count the mask of visited rows gives when gathered at them."""
+    all_held = case.startswith("all_held")
+    top_k = 4 if case == "all_held_top_4" else WINDOWED["top_k"]
+    num_experts = WINDOWED["num_experts"]
+    held = tuple(range(16)) if all_held else WINDOWED["held"]
     p = _slice_experts(_layer_params(num_experts), held)
     x = jax.random.normal(jax.random.PRNGKey(11), (1, 1024, 64))
     if steer is not None:
         x, p["router"] = _steered(*steer)
-    assert moe.window_rows(2048, len(held), num_experts) == (
-        2048 if case == "all_held" else 512)
+    m = moe.window_rows(1024 * top_k, len(held), num_experts)
+    assert m == (1024 * top_k if all_held else 512)
     kw = dict(top_k=top_k, held=held, num_experts=num_experts)
     model = {**MODEL, "moe_top_k": top_k, "num_experts": num_experts}
     g = jax.random.normal(jax.random.PRNGKey(12), x.shape)
@@ -300,9 +341,13 @@ def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds):
     if steer is not None:
         assert float(stats["rows_routed"]) == 2 * steer[0] + steer[1]
     assert float(stats["rounds"]) == rounds
+    if check == "counter":
+        assert float(stats["rows_computed"]) == came_back_by_gather(
+            *plan_of(stats["selected"], held, num_experts), m)
+        return
     loops = [w for w in ("while", "cond")
              if w + "[" in str(jax.make_jaxpr(system)(p, x))]
-    assert loops == ([] if case == "all_held" else ["while"])
+    assert loops == ([] if all_held else ["while"])
     for name, plain in [
             ("full_buffer", lambda p_, x_: _full_buffer_layer(x_, p_, **kw)),
             ("reference", lambda p_, x_: ref.sparse_block(
@@ -311,12 +356,39 @@ def test_windows_add_up_to_the_full_buffer_layer(case, steer, rounds):
         assert float(jnp.max(jnp.abs(y - want))) < 1e-5 * float(
             jnp.max(jnp.abs(want))), (name, "y")
         (dp, dx), (want_dp, want_dx) = vjp(g), want_vjp(g)
-        names = ["w1", "w3", "w2"] + ["router"] * (case == "all_held")
+        names = ["w1", "w3", "w2"] + ["router"] * all_held
         for leaf, a, b in [("x", dx, want_dx)] + [
                 (n, dp[n], want_dp[n]) for n in names]:
             assert float(jnp.max(jnp.abs(b))) > 0, (name, leaf)
             assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * float(
                 jnp.max(jnp.abs(b))), (name, leaf)
+
+
+def test_rows_computed_falls_short_where_a_group_is_cut():
+    """The counter has two witnesses: ``live`` from the selection's
+    count, ``computed`` from the groups the matmuls are given.  A window
+    handed a last group that stops three rows short counts three pairs
+    fewer than were routed, by comparison as by gather."""
+    top_k, num_experts, held = (WINDOWED[k] for k in (
+        "top_k", "num_experts", "held"))
+    p = _slice_experts(_layer_params(num_experts), held)
+    x, p["router"] = _steered(100, 50)
+    _, stats = moe.dropless_moe(x, p, compute_dtype=jnp.float32,
+                                top_k=top_k, held=held,
+                                num_experts=num_experts)
+    inverse, first, last, n_rows = plan_of(stats["selected"], held,
+                                           num_experts)
+    assert n_rows == 250 == float(stats["rows_computed"])
+    last[-1] -= 3
+    t, m = x.shape[1], moe.window_rows(2048, len(held), num_experts)
+    ints = tuple(jnp.asarray(a, jnp.int32) for a in (
+        np.argsort(inverse), inverse.reshape(t, top_k).T.reshape(-1),
+        first, last, n_rows))
+    diff = (x.reshape(t, -1), jnp.full((top_k, t), 0.5), p["w1"], p["w3"],
+            p["w2"])
+    _, came_back = moe._window(diff, ints, 0, m=m, top_k=top_k, mesh=None)
+    assert int(came_back) == n_rows - 3 == came_back_by_gather(
+        inverse, first, last, n_rows, m)
 
 
 @pytest.mark.parametrize("mutation", ["no_bias", "unnormalised_topk",

@@ -24,6 +24,7 @@ from ray_lightning_accelerators_tpu.models import reference_nemotron_h as ref
 from ray_lightning_accelerators_tpu.models.transformer import (
     GPT, TransformerConfig)
 from ray_lightning_accelerators_tpu.ops import moe, ssm
+from tests.test_lfm2 import came_back_by_gather, plan_of
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB, SEQ = 256, 40        # 40 positions: two chunks of 16 and a tail
@@ -331,16 +332,24 @@ def test_expert_shares_add_up_in_the_latent():
 # 256 tokens, top-22 of 64: 5,632 pairs.  Six experts held: windows of
 # 1,024 sorted rows (1.5 x the nominal 528, in whole row tiles), under a
 # quarter of the pairs, so the token side is the scatter-add; eight held:
-# 1,536 rows, the gather
-@pytest.mark.parametrize("n_held,favoured,rounds", [
-    (6, 0, 1), (6, 5, 2), (8, 0, 1), (8, 8, 2)])
+# 1,536 rows, the gather; all 64 held: one window of all the pairs, and
+# the combine weights carry the router's gradient
+_LOADS = [(6, 0, 1), (6, 5, 2), (8, 0, 1), (8, 8, 2)]
+
+
+@pytest.mark.parametrize("n_held,favoured,rounds,check", [
+    *[(*load, "layer") for load in _LOADS], (64, 0, 1, "layer"),
+    *[(*load, "counter") for load in _LOADS]])
 def test_no_token_dropped_at_top_22_under_a_skewed_router(
-        n_held, favoured, rounds):
+        n_held, favoured, rounds, check):
     """``favoured`` held experts are every token's choice whatever the
     token: more rows than one window holds, as many windows as they
     need, every routed row computed, the result and its gradients the
-    reference's."""
-    held = tuple(range(0, 2 * n_held, 2))
+    reference's (the router's among them where every expert is held:
+    ``d_w`` over the choice-major rows).  ``counter``: ``rows_computed``,
+    made by comparing positions, is the count the mask of visited rows
+    gives when gathered at them, on either token side."""
+    held = tuple(range(64) if n_held == 64 else range(0, 2 * n_held, 2))
     p = _slice_experts(_latent_params(64), held)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(8), (1, 256, 64)))
     router = p["experts"]["router"]
@@ -361,17 +370,23 @@ def test_no_token_dropped_at_top_22_under_a_skewed_router(
 
     (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
         system, argnums=(0, 1), has_aux=True))(x, p)
-    (_, want), ref_grads = jax.jit(jax.value_and_grad(
-        reference, argnums=(0, 1), has_aux=True))(x, p)
     assert float(stats["rows_routed"]) == float(stats["rows_computed"])
     assert float(stats["rows_computed"]) >= 256 * favoured
     assert float(stats["rounds"]) == rounds
+    if check == "counter":
+        assert float(stats["rows_computed"]) == came_back_by_gather(
+            *plan_of(stats["selected"], held, 64), m)
+        return
+    (_, want), ref_grads = jax.jit(jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True))(x, p)
     assert float(jnp.max(jnp.abs(y - want))) < 1e-4 * float(
         jnp.max(jnp.abs(want)))
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(ref_grads)):
         assert float(jnp.max(jnp.abs(g - r))) <= 2e-4 * float(
             jnp.max(jnp.abs(r)) + 1e-12), jax.tree_util.keystr(path)
+    taught = float(jnp.max(jnp.abs(grads[1]["experts"]["router"])))
+    assert (taught > 0) == (n_held == 64)
 
 
 # --------------------------------------------------------------------- #

@@ -478,16 +478,18 @@ def test_weight_decay_spares_norm_scales_and_the_gates_bias():
 # trace).  A PR that changes one of these programs on purpose pins its
 # own digest here: PR 34 the two that trace the gradient (the fused
 # loss's forward rule makes dh and dw; 47f5cf62c04ddcd1 and
-# 1d456fc52e8cb887 before it).  The other three trace the loss alone or
-# the materialised one, and kept theirs: the call nobody differentiates
-# is the program it was.
+# 1d456fc52e8cb887 before it), PR 36 the two mixed stacks (the expert
+# layer's token side is choice-major and ``rows_computed`` compares
+# positions; 06993cb687eb30cc and d2e2caeaf8ac545a before it).  The
+# materialised one kept its own: the call nobody differentiates is the
+# program it was.
 _PARENTS = {
     "gpt2": (GPT2, True, "623b250208429e4a"),
     "gpt2-remat": (dict(GPT2, remat=True), True, "ae04d61271fdf68a"),
     "gpt2-materialised": (dict(GPT2, fused_loss=False), True,
                           "3b040f274eb296f1"),
-    "lfm2": (dict(LFM2, remat=True), False, "06993cb687eb30cc"),
-    "latent": (dict(LATENT, remat=True), False, "d2e2caeaf8ac545a"),
+    "lfm2": (dict(LFM2, remat=True), False, "654fcc1d6699bcc8"),
+    "latent": (dict(LATENT, remat=True), False, "936b251e67dd224f"),
 }
 
 

@@ -127,16 +127,32 @@ def test_norm_kernels_compile(one_chip, chip_dispatch):
     ln.compile()
 
 
+def _gathers_of(text: str, result: str) -> list:
+    """The compiled program's gathers (the instruction, or the fusion
+    the compiler wraps it in and names after it) with that result
+    type."""
+    return [line for line in text.splitlines()
+            if f"= {result}" in line and (" gather(" in line
+                                          or "/gather\"" in line)]
+
+
 def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
         one_chip, chip_dispatch):
     """``train-lfm2-moe-8k``: 32,768 rows, 8 of 32 experts held, each
     2048 x 1792, top-4 -- forward and backward hold the grouped-matmul
     kernel, never ``ragged_dot``, and fit the chip.  The sorted side is
     a window of 49,152 rows, not the 131,072 pairs: no gate, up or
-    product of that many rows is left (the token-side ``[32768, 4,
-    2048]`` pairs of the combine's forward and the dispatch's backward
-    remain), and the temporaries stand at 1.27 GB where the one-buffer
-    layer (PR 27) had 2.49 GB."""
+    product of that many rows is left.  The token side is choice-major:
+    the combine's forward and the dispatch's backward each gather
+    ``[4, 32768, 2048]`` (131,072 rows, every choice a slab of whole
+    tiles) and reduce it as written: no ``[32768, 4, 2048]`` copy into
+    another tiling is left, and ``rows_computed`` is a comparison of the
+    positions, so no gather of 131,072 scalars either.  The loss reads
+    ``y`` (a plain sum's gradient does not, and the compiler drops the
+    combine's forward with it): both gathers stand in the program, and
+    its temporaries read 1.305 GB where the token-major layer had 1.401
+    (1.170 and 1.266 under a plain sum; the one-buffer layer, PR 27,
+    2.49 there)."""
     from ray_lightning_accelerators_tpu.ops import moe
 
     held = tuple(range(8))
@@ -150,7 +166,8 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
     def loss(p, x):
         y, stats = moe.dropless_moe(x, p, top_k=4, held=held,
                                     num_experts=32)
-        return y.astype(jnp.float32).sum(), stats["rows_computed"]
+        return (jnp.square(y.astype(jnp.float32)).sum(),
+                stats["rows_computed"])
 
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)
                       ).lower(p, x)
@@ -162,9 +179,21 @@ def test_dropless_expert_layer_compiles_at_the_benchmark_cells_shapes(
     assert "stablehlo.ragged_dot" not in text
     assert "kernel/moe_gmm" in lowered.as_text(debug_info=True)
     compiled = lowered.compile()
-    assert "[131072,1792]" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "[131072,1792]" not in text
+    assert "[32768,4,2048]" not in text
+    # both token-side gathers stand in the program, and all that reads
+    # either is a bitcast (into the sum over the choices)
+    row_gathers = re.findall(r"(%fusion\.\d+) = bf16\[131072,2048\]\S* "
+                             r"fusion\(.*/gather\"", text)
+    assert len(row_gathers) == 2
+    for name in row_gathers:
+        readers = [line for line in text.splitlines()
+                   if re.search(re.escape(name) + "[,)]", line)]
+        assert readers and all(" bitcast(" in r for r in readers), readers
+    assert not _gathers_of(text, "pred[131072]")
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 1.6e9       # 2.485e9 before the windows
+    assert mem.temp_size_in_bytes < 1.44e9      # 1.305e9 read, + 10 %
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES // 2
 
@@ -178,10 +207,11 @@ def test_nemotron_operators_compile_at_the_benchmark_cells_shapes(
     backward and meets its tokens by scatter-add: no ``[180224, 1024]``
     gather of every pair's row is left (two such stood at 369 MB each,
     738 MB at two sequences, before the window's own rows were added into
-    their tokens).  The Mamba-2 mixer (16 heads of 64 in one group, state
-    128, chunks of 128) compiles forward and backward.  Temporaries of a
-    layer's gradient, by the compiler's account: under 0.4 GB each
-    (0.124 and 0.163 read)."""
+    their tokens), and ``rows_computed`` compares the positions: no
+    gather of 180,224 scalars is left.  The Mamba-2 mixer (16 heads of
+    64 in one group, state 128, chunks of 128) compiles forward and
+    backward.  Temporaries of a layer's gradient, by the compiler's
+    account: under 0.4 GB each (0.124 and 0.163 read)."""
     from ray_lightning_accelerators_tpu.ops import moe, ssm
 
     held = tuple(range(8))
@@ -208,6 +238,7 @@ def test_nemotron_operators_compile_at_the_benchmark_cells_shapes(
     assert "stablehlo.ragged_dot" not in lowered.as_text()
     compiled = lowered.compile()
     assert "[180224,1024]" not in compiled.as_text()
+    assert not _gathers_of(compiled.as_text(), "pred[180224]")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
 
     def mixer_loss(p, x):
