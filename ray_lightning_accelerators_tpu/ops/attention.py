@@ -18,11 +18,13 @@ hot op, so it gets a hand-written TPU kernel:
   the whole causal square (block == sequence) forward and backward walk
   only its causal triangle, in strips of sub-tiles (``_diag_tile``,
   ``causal_tiles``);
-- hand-written backward kernels (``jax.custom_vjp``): a dq pass and a
-  dk/dv pass -- or one fused pass when the key length is one block --
-  recompute score blocks from q/k and the saved lse in TRANSPOSED
-  [block_k, block_q] space (per-query rows broadcast along lanes), never
-  materializing [S, S] in HBM.
+- hand-written backward kernels (``jax.custom_vjp``) recompute score
+  blocks from q/k and the saved lse in TRANSPOSED [block_k, block_q]
+  space (per-query rows broadcast along lanes), never materializing
+  [S, S] in HBM.  One pass makes dq, dk and dv from each recomputed
+  block: over the one key block, or over the k-walk with one head's
+  float32 dq held in VMEM (``kwalk_fused``); past that budget a dq pass
+  and a dk/dv pass each recompute the blocks.
 
 On non-TPU backends (tests on the virtual CPU mesh), dispatch falls back to
 a reference jnp implementation with identical semantics.
@@ -364,7 +366,7 @@ def _flash_forward(q3: jax.Array, k3: jax.Array, v3: jax.Array, scale: float,
 # Backward kernels                                                       #
 # --------------------------------------------------------------------- #
 # Flash-style backward: recompute the score block from q/k and the saved
-# per-row log-sum-exp, never materializing [S, S] in HBM.  Both kernels
+# per-row log-sum-exp, never materializing [S, S] in HBM.  The kernels
 # work in the TRANSPOSED score space [block_k, block_q] so the per-QUERY
 # lse/delta rows broadcast along lanes ([1, block_q]) -- no sublane
 # broadcasts or in-kernel transposes in the hot loop.
@@ -532,6 +534,104 @@ def _flash_backward_fused(q3, k3, v3, g3, lse, delta, scale, causal,
     )(q3, k3, v3, g3, lse, delta)
 
 
+def _flash_bwd_kwalk_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                            dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                            scale, causal, block_q, block_k, window):
+    """The k-walk's backward in one pass: key blocks outer, q blocks
+    inner, each visited block's scores recomputed ONCE for all three
+    gradients (5 MXU matmuls a block where the split pair runs 3+4).
+    dk/dv accumulate over the q walk as in the dkv pass; dq accumulates
+    in ``dq_scr``, the whole query length of this head, and each q block
+    receives its key blocks in increasing order, as in the dq pass."""
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_scr[q_rows] = jnp.zeros((block_q, dq_scr.shape[1]), jnp.float32)
+
+    @pl.when(_block_needed(qi, ki, block_q, block_k, causal, window))
+    def _compute():
+        pT, dsT = _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                             qi, ki, scale=scale, causal=causal,
+                             block_q=block_q, block_k=block_k, window=window)
+        dv_scr[:] += jax.lax.dot_general(
+            pT.astype(do_ref.dtype), do_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[:] += jax.lax.dot_general(
+            dsT.astype(q_ref.dtype), q_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_scr[q_rows] += jax.lax.dot_general(
+            dsT.astype(k_ref.dtype), k_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finish_kv():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _finish_q():
+        dq_ref[0] = dq_scr[q_rows].astype(dq_ref.dtype)
+
+
+# One head's float32 dq accumulator stays in VMEM for the whole of its
+# k-walk: q_len x round_up(d, 128) x 4 B, 4 MiB at 8,192 positions (a
+# 64-wide head pads to the 128 lanes).  Beside it the kernel's blocks,
+# dk/dv accumulators and [block_k, block_q] float32 temporaries take
+# 11.5 MiB at 1024-blocks: 15.5 of the default 16 MiB scoped VMEM (the
+# v5e compiler refuses 10,240 positions at 16.49).  Past the budget the
+# split pair runs, whose working set does not grow with the sequence.
+_KWALK_DQ_BYTES = 4 * 2 ** 20
+
+
+def kwalk_fused(q_len: int, d: int) -> bool:
+    """Whether the k-walk's backward runs as one pass: one head's float32
+    dq accumulator fits ``_KWALK_DQ_BYTES``."""
+    return q_len * -(-d // _LANES) * _LANES * 4 <= _KWALK_DQ_BYTES
+
+
+def _flash_backward_kwalk(q3, k3, v3, g3, lse, delta, scale, causal,
+                          block_q, block_k, interpret, window):
+    """One-kernel backward for k_len > block_k."""
+    bh, q_len, d = q3.shape
+    k_len = k3.shape[1]
+    n_k = k_len // block_k
+    qspec = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
+    # dq's block stays at the head's first q block until the last key
+    # block, so the pipeline writes nothing back before a q block's sum
+    # is whole, and each block once
+    dqspec = pl.BlockSpec(
+        (1, block_q, d),
+        lambda b, j, i: (b, jnp.where(j == n_k - 1, i, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kwalk_kernel, scale=scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          window=window),
+        grid=(bh, n_k, q_len // block_q),
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=[dqspec, kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, k_len, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, k_len, d), v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((q_len, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # both walks carry accumulators: dk/dv over q, dq over k
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="flash_bwd_kwalk",
+    )(q3, k3, v3, g3, lse, delta)
+
+
 @scoped("kernel/flash_bwd")
 def _flash_backward(q3, k3, v3, o3, lse, g3, scale, causal, block_q,
                     block_k, interpret, window=None):
@@ -544,6 +644,10 @@ def _flash_backward(q3, k3, v3, o3, lse, g3, scale, causal, block_q,
                     axis=-1)[:, None, :]                   # [bh, 1, q_len]
     if block_k == k_len:
         return _flash_backward_fused(q3, k3, v3, g3, lse, delta, scale,
+                                     causal, block_q, block_k, interpret,
+                                     window)
+    if kwalk_fused(q_len, d):
+        return _flash_backward_kwalk(q3, k3, v3, g3, lse, delta, scale,
                                      causal, block_q, block_k, interpret,
                                      window)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))

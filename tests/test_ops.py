@@ -6,9 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_lightning_accelerators_tpu.ops import attention
 from ray_lightning_accelerators_tpu.ops.attention import (
     attention_reference, causal_tiles, flash_attention,
-    flash_attention_interpret)
+    flash_attention_grads_interpret, flash_attention_interpret,
+    kwalk_fused)
 
 
 # CPU runs both paths in strict f32; on real TPU the MXU's default matmul
@@ -149,22 +151,18 @@ def test_sliding_window_gradients():
                                    **_GRAD_TOL)
 
 
-@pytest.mark.parametrize("seq,block_q,block_k,causal,window", [
-    (256, 128, bk, c, w) for bk in (128, 256)
-    for c, w in ((False, None), (True, None), (True, 96))
-] + [(s, b, b, True, None) for s, b in _ONE_BLOCK + [(512, 256)]] + [
-    (512, 512, 512, False, None), (512, 512, 512, True, 48),
-    (512, 512, 512, True, 300)])
-def test_flash_backward_kernels_match(seq, block_q, block_k, causal, window):
-    """The hand-written backward kernels must reproduce XLA autodiff of
-    the reference: block_k < seq exercises the split dq + dkv passes,
-    block_k == seq the FUSED single-k-block kernel that shares the score
-    recompute -- as one masked square (block_q < block_k, a window,
-    non-causal) and, where one block holds the causal square, as the
-    triangle of strips."""
-    from ray_lightning_accelerators_tpu.ops.attention import (
-        flash_attention_grads_interpret)
+# (seq, block_q, block_k, causal, window) of the k-walk (several key
+# blocks): two blocks a side, none masked / causal / a window inside the
+# diagonal's neighbour; four key blocks; q blocks smaller and larger than
+# the key blocks; a window of 96 that skips whole blocks on the left
+_KWALK = [(256, 128, bk, c, w) for bk in (128, 256)
+          for c, w in ((False, None), (True, None), (True, 96))
+          if bk < 256] + [
+    (1024, 256, 256, True, None), (512, 128, 256, True, None),
+    (512, 256, 128, True, None), (512, 128, 128, True, 96)]
 
+
+def _grads_match(seq, block_q, block_k, causal, window):
     q, k, v = _qkv(b=1, h=2, s=seq, d=64)
     g = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
 
@@ -178,3 +176,42 @@ def test_flash_backward_kernels_match(seq, block_q, block_k, causal, window):
                                           window=window)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **_GRAD_TOL)
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,causal,window", _KWALK + [
+    (256, 128, 256, c, w)
+    for c, w in ((False, None), (True, None), (True, 96))
+] + [(s, b, b, True, None) for s, b in _ONE_BLOCK + [(512, 256)]] + [
+    (512, 512, 512, False, None), (512, 512, 512, True, 48),
+    (512, 512, 512, True, 300)])
+def test_flash_backward_kernels_match(seq, block_q, block_k, causal, window):
+    """The hand-written backward kernels must reproduce XLA autodiff of
+    the reference: block_k < seq exercises the one-pass k-walk kernel
+    (dq held for the whole head, dk/dv over the q walk), block_k == seq
+    the FUSED single-k-block kernel that shares the score recompute --
+    as one masked square (block_q < block_k, a window, non-causal) and,
+    where one block holds the causal square, as the triangle of
+    strips."""
+    _grads_match(seq, block_q, block_k, causal, window)
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,causal,window", _KWALK)
+def test_flash_backward_split_pair_matches(monkeypatch, seq, block_q,
+                                           block_k, causal, window):
+    """Past the k-walk kernel's VMEM budget a dq pass and a dk/dv pass
+    each recompute the blocks: the same walks through that pair."""
+    monkeypatch.setattr(attention, "kwalk_fused", lambda q_len, d: False)
+    _grads_match(seq, block_q, block_k, causal, window)
+
+
+@pytest.mark.parametrize("q_len,d,fused", [
+    (8192, 64, True),       # train-lfm2-moe-8k: 64 lanes pad to 128, 4 MiB
+    (8192, 128, True),      # train-ouro-loop-8k, train-nemotron3-ssm-8k
+    (1024, 64, True),
+    (16384, 128, False),    # 8 MiB of dq: the split pair
+    (16384, 64, False),
+    (8192, 256, False),
+])
+def test_kwalk_backward_is_one_pass_while_a_heads_dq_fits_vmem(q_len, d,
+                                                                fused):
+    assert kwalk_fused(q_len, d) is fused

@@ -100,7 +100,7 @@ def test_flash_forward_compiles(one_chip, chip_dispatch, block):
 
 
 @pytest.mark.parametrize("block,bwd_kernels", [
-    (512, ["flash_bwd_dkv", "flash_bwd_dq"]),
+    (512, ["flash_bwd_kwalk"]),   # the k-walk: one pass, dq held a head
     (1024, ["flash_bwd_fused"]),  # k_len == block_k: one pass
 ])
 def test_flash_backward_compiles(one_chip, chip_dispatch, block,
@@ -109,6 +109,28 @@ def test_flash_backward_compiles(one_chip, chip_dispatch, block,
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, True, None, block, block)
+        return out.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    assert _kernels(lowered) == sorted(["flash_fwd"] + bwd_kernels)
+    lowered.compile()
+
+
+@pytest.mark.parametrize("shape,bwd_kernels", [
+    ((4, 32, 8192, 64), ["flash_bwd_kwalk"]),     # train-lfm2-moe-8k
+    ((1, 16, 8192, 128), ["flash_bwd_kwalk"]),    # train-ouro-loop-8k
+    ((1, 8, 16384, 128), ["flash_bwd_dkv", "flash_bwd_dq"]),
+], ids=["lfm2-8k-64", "ouro-8k-128", "16k-128-split"])
+def test_flash_k_walk_backward_compiles_at_the_cells_operands(
+        one_chip, chip_dispatch, shape, bwd_kernels):
+    """Blocks 1024 over several key blocks: at 8,192 positions one head's
+    float32 dq (4 MiB) stays in VMEM and the backward is ONE kernel,
+    compiled inside the default scoped VMEM (15.5 of 16 MiB at 8,192;
+    10,240 positions were refused at 16.49); at 16,384 the split pair."""
+    q = _sds(shape, jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, 1024, 1024)
         return out.astype(jnp.float32).sum()
 
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
@@ -441,8 +463,9 @@ def test_looped_step_fits_the_chip_and_holds_one_layer_body(
     1024, the whole 49,152-id vocabulary through the weighted fused loss.
     The pass loop is a loop in the program: ONE forward kernel (its
     output kept by name for all 24 applications, so none under the
-    backward) and one of each backward kernel; the step stays under the
-    chip's 15.75 GiB with room for what the scanned epoch holds over it
+    backward) and ONE backward kernel, the k-walk's one pass; the step
+    stays under the chip's 15.75 GiB with room for what the scanned
+    epoch holds over it
     (15.00 GB described, PR 33, the scanned epoch 1.42 more; 15.07 and
     0.44 more since PR 34, the fused loss's forward rule keeping dh and
     dw where it kept its operands; 8 layers read 18.48)."""
@@ -453,15 +476,12 @@ def test_looped_step_fits_the_chip_and_holds_one_layer_body(
         loop_passes=4, exit_gate=True, exit_beta=0.05, remat=True,
         flash_block_q=1024, flash_block_k=1024, loss_chunk_rows=2048)
     _, lowered = _abstract_train_step(topo.devices[:1], 1, config=config)
-    assert _kernels(lowered) == ["flash_bwd_dkv", "flash_bwd_dq",
-                                 "flash_fwd", "rms_norm"]
+    assert _kernels(lowered) == ["flash_bwd_kwalk", "flash_fwd", "rms_norm"]
     compiled = lowered.compile()
     hlo = compiled.as_text()
     assert _forward_kernel_calls(hlo) == 1
-    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
-        assert sum("tpu_custom_call" in line and kernel in line
-                   for line in hlo.splitlines()
-                   if "custom-call(" in line) == 1, kernel
+    assert sum("tpu_custom_call" in line and "flash_bwd_kwalk" in line
+               for line in hlo.splitlines() if "custom-call(" in line) == 1
     assert _per_device_bytes(compiled) < 15.3e9
     names = set(re.findall(r'op_name="([^"]*)"', hlo))
     assert any(re.search(r"gpt/loop\b.*gpt/layers", n) for n in names)
